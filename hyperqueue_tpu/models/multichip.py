@@ -10,11 +10,13 @@ In the reference the solver IS the production scheduler
 model is the multi-device form of that seat, reached through the same
 reactor.schedule -> run_tick -> model.solve path as every other backend.
 
-Device handling: the mesh is built lazily on first solve from however many
-devices the process sees (all of them by default, or `n_devices`). With a
-single device the model degrades to the plain single-chip kernel — a
-single-chip deployment selecting `--scheduler=multichip` is valid and loses
-nothing.
+Device handling: the mesh is built from however many devices the process
+sees (all of them by default, or `n_devices`) — by the server at start, so a
+backend that cannot initialise stops the server there, or else on first
+solve.  With a single device the model runs the plain single-chip kernel: on
+an accelerator that is the device kernel, forced, exactly as
+`--scheduler=tpu` runs it; on the CPU backend (tests, virtual devices) it is
+the parent's host solve.
 
 Residency: the sharded solve inherits the device-resident tick state from
 the parent model — the (W, R) shards stay on their devices across ticks,
@@ -40,38 +42,34 @@ class MultichipModel(GreedyCutScanModel):
     _device_backend_name = "device-sharded"
 
     def __init__(self, n_devices: int | None = None, **kwargs):
-        # backend only matters for the single-device fallback, where the
-        # parent's "auto" (numpy on CPU hosts) is the right default; with a
-        # real mesh the sharded jax kernel is used unconditionally
+        # backend only matters with a single device (see get_mesh); with
+        # a real mesh the sharded jax kernel is used unconditionally
         super().__init__(**kwargs)
         self._requested_devices = n_devices
-        self._mesh = None  # built lazily: jax.devices() only at first solve
+        self._mesh = None  # built by get_mesh: the first jax.devices()
 
-    def _get_mesh(self):
+    def get_mesh(self):
+        """The worker mesh (False = single device), built on first call."""
         if self._mesh is None:
             import jax
 
             from hyperqueue_tpu.parallel.solve import make_worker_mesh
 
-            try:
-                available = len(jax.devices())
-            except RuntimeError:
-                # accelerator backend failed to initialize (e.g. unhealthy
-                # TPU relay): degrade to the single-chip host fallback
-                # instead of killing the scheduler loop
-                available = 1
-                logger.warning(
-                    "multichip scheduler: jax backend unavailable, "
-                    "falling back to the single-chip host solve",
-                    exc_info=True,
-                )
+            # a backend that fails to initialise raises out of here: the
+            # operator asked for devices, and one host solve under that
+            # name is not what they asked for
+            available = len(jax.devices())
             n = (
                 min(self._requested_devices, available)
                 if self._requested_devices
                 else available
             )
             if n <= 1:
-                self._mesh = False  # sentinel: single-chip fallback
+                self._mesh = False  # sentinel: single-chip kernel
+                if jax.default_backend() != "cpu":
+                    # one accelerator: its kernel, not the host-vs-device
+                    # cost model the parent's "auto" would run
+                    self._use_numpy = False
                 logger.info(
                     "multichip scheduler: 1 device visible, using the "
                     "single-chip kernel"
@@ -86,7 +84,7 @@ class MultichipModel(GreedyCutScanModel):
 
     def _worker_bucket(self, n_w: int) -> int:
         pw = _bucket(n_w, self.worker_floor)
-        mesh = self._get_mesh()
+        mesh = self.get_mesh()
         if mesh:
             d = mesh.devices.size
             pw = ((pw + d - 1) // d) * d  # shard_map needs W % D == 0
@@ -96,9 +94,9 @@ class MultichipModel(GreedyCutScanModel):
         # an operator who selected --scheduler=multichip asked for the
         # sharded device solve: run it whenever a mesh exists (the solver
         # watchdog still catches failures); without one, behave exactly
-        # like the single-chip model (adaptive on accelerators, host on
-        # CPU-only deployments)
-        if self._get_mesh():
+        # like the single-chip model (forced onto a lone accelerator by
+        # get_mesh, host on the CPU backend)
+        if self.get_mesh():
             return "device", "multichip-mesh"
         return super()._backend_decision(shape_key)
 
@@ -107,7 +105,7 @@ class MultichipModel(GreedyCutScanModel):
             from hyperqueue_tpu.parallel.resident import DeviceResidency
             from hyperqueue_tpu.parallel.solve import _mesh_shardings
 
-            mesh = self._get_mesh()
+            mesh = self.get_mesh()
             if mesh:
                 self._res = DeviceResidency(shardings=_mesh_shardings(mesh))
             else:
@@ -115,7 +113,7 @@ class MultichipModel(GreedyCutScanModel):
         return self._res
 
     def _kernel_dispatch(self, res, free_d, nt_d, life_d, total_d, prep):
-        mesh = self._get_mesh()
+        mesh = self.get_mesh()
         if not mesh:
             return super()._kernel_dispatch(
                 res, free_d, nt_d, life_d, total_d, prep
@@ -142,7 +140,7 @@ class MultichipModel(GreedyCutScanModel):
         )
 
     def _fresh_device_counts(self, prep):
-        mesh = self._get_mesh()
+        mesh = self.get_mesh()
         if not mesh:
             return super()._fresh_device_counts(prep)
         from hyperqueue_tpu.parallel.solve import (

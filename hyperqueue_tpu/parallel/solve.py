@@ -45,16 +45,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-# jax moved shard_map out of experimental (and renamed check_rep ->
-# check_vma) across the versions this repo must run on; resolve once here
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-    _SHARD_MAP_KW = {"check_vma": False}
-else:  # pragma: no cover - depends on installed jax
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    _SHARD_MAP_KW = {"check_rep": False}
-
 from hyperqueue_tpu.ops.assign import (
     _water_fill_classed,
     expand_onehots,
@@ -218,12 +208,12 @@ def _sharded_cut_scan_impl(
             gang_nodes=gn, gang_ok=go, group_onehot=goh, policy_mask=pm,
         )
 
-    return _shard_map(
+    return jax.shard_map(
         body,
         mesh=mesh,
         in_specs=tuple(in_specs),
         out_specs=(P(None, None, "w"), P("w", None), P("w")),
-        **_SHARD_MAP_KW,
+        check_vma=False,
     )(*args)
 
 

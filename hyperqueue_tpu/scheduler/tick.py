@@ -21,6 +21,7 @@ import numpy as np
 from hyperqueue_tpu.utils.constants import INF_TIME
 from hyperqueue_tpu.resources.map import ResourceIdMap, ResourceRqMap
 from hyperqueue_tpu.scheduler.queues import Priority, TaskQueues
+from hyperqueue_tpu.utils.metrics import REGISTRY
 
 MAX_CUTS_PER_QUEUE = 32
 # Node budget for the per-worker min-utilization branch-and-bound
@@ -30,6 +31,16 @@ MU_DFS_NODE_BUDGET = 50_000
 # Values above this get range-compressed before entering the kernel — the
 # kernel requires amounts to be float32-exact (ops/assign.MAX_KERNEL_AMOUNT).
 MAX_SAFE_AMOUNT = 2**23
+
+
+# counted per solve, not read off the last one: a run that was meant for the
+# device shows every tick that went to the host instead
+_SOLVES_BY_BACKEND = REGISTRY.counter(
+    "hq_solve_backend",
+    "dense solves run per backend (host-native/host-numpy/device-jax/"
+    "device-sharded)",
+    labels=("backend",), max_series=8,
+)
 
 
 @dataclass(slots=True)
@@ -637,6 +648,12 @@ def assemble_solve_inputs(workers, batches, rq_map, resource_map,
     }
 
 
+def _count_solve(model) -> None:
+    backend = getattr(model, "last_backend", None)
+    if backend:  # the MILP names none
+        _SOLVES_BY_BACKEND.labels(backend).inc()
+
+
 def _run_main_solve(queues, workers, rq_map, resource_map, model, batches,
                     cpu_floor=None, dense=None, phases=None, key_cache=None,
                     decision=None, pipeline=None, gang_ok=None,
@@ -657,6 +674,7 @@ def _run_main_solve(queues, workers, rq_map, resource_map, model, batches,
         from hyperqueue_tpu.scheduler.pipeline import PendingSolve
 
         handle = model.solve_async(**kwargs)
+        _count_solve(model)
         if phases is not None:
             phases["assemble"] = (
                 phases.get("assemble", 0.0) + (_t1 - _t0) * 1e3
@@ -683,6 +701,7 @@ def _run_main_solve(queues, workers, rq_map, resource_map, model, batches,
         return []
     counts = model.solve(**kwargs)
     _t2 = _time.perf_counter()
+    _count_solve(model)
     if decision is not None:
         # the solver's verdict for this tick's DecisionRecord
         # (scheduler/decision.py): a watchdog-wrapped model reports whether

@@ -1,8 +1,8 @@
 """Solver watchdog: the scheduling loop must survive a misbehaving solver.
 
 A single exception or hang inside the per-tick solve (JAX MILP, the jitted
-greedy kernel, or a wedged device relay) previously killed the scheduler
-loop — the server kept accepting submits but never scheduled again.
+greedy kernel, or a device that stops answering) previously killed the
+scheduler loop — the server kept accepting submits but never scheduled again.
 Dynamic schedulers must degrade gracefully rather than stop scheduling
 when the optimizer misbehaves (arXiv:1106.4985); long-running cluster
 workloads are exactly where component failure dominates (arXiv:2008.09213).

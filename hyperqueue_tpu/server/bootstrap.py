@@ -816,10 +816,38 @@ class Server:
         # lazy materialization needs the CURRENT job manager (restore may
         # swap it out on a snapshot fallback): bind a getter, not the object
         self.core.lazy.jobs_getter = lambda: self.jobs
+        if (
+            scheduler in ("auto", "tpu", "multichip")
+            and os.environ.get("JAX_PLATFORMS", "").strip() != "cpu"
+        ):
+            # this process is about to compile the solve.  One pinned to
+            # the CPU backend keeps no cache: "auto" solves in numpy there,
+            # and the tests' virtual-device programs are never read again
+            from hyperqueue_tpu.utils.jaxdev import configure_compile_cache
+
+            configure_compile_cache()
         if scheduler == "milp":
             base_model = MilpModel()
+        elif scheduler == "tpu":
+            # the chip, or no server: the device path is forced (no
+            # host-vs-device cost model), and a process that found no TPU
+            # must not run under this name on numpy
+            import jax
+
+            found = jax.default_backend()
+            if found != "tpu":
+                raise RuntimeError(
+                    "--scheduler tpu needs a TPU, but jax.default_backend() "
+                    f"is {found!r} (JAX_PLATFORMS="
+                    f"{os.environ.get('JAX_PLATFORMS')!r}); use --scheduler "
+                    "auto to let the server pick the host solve"
+                )
+            base_model = GreedyCutScanModel(backend="jax")
         elif scheduler == "multichip":
             base_model = MultichipModel()
+            # initialise the backend now: one that cannot come up stops
+            # the server here instead of at the first tick
+            base_model.get_mesh()
         elif scheduler == "greedy-numpy":
             # pinned host/numpy solve: no adaptive host/device selection,
             # so the backend (and the decision records naming it) is
@@ -2036,9 +2064,9 @@ class Server:
                 f"hq_tick_cache_{key}_total",
                 f"tick snapshot cache {key.replace('_', ' ')}",
             ).set_total(cache.get(key, 0))
-        # solve backend + device-resident state (parallel/resident.py):
-        # which backend the last solve ran, how many bytes the device path
-        # uploaded (full + delta), and how many rows were dirty last tick
+        # device-resident state (parallel/resident.py): how many bytes the
+        # device path uploaded (full + delta), and how many rows were dirty
+        # last tick (hq_solve_backend is counted per solve, scheduler/tick.py)
         resident = {}
         get_resident = getattr(self.model, "resident_stats", None)
         if get_resident is not None:
@@ -2046,15 +2074,6 @@ class Server:
                 resident = get_resident()
             except Exception:  # noqa: BLE001 - metrics must never break
                 resident = {}
-        backend_gauge = REGISTRY.gauge(
-            "hq_solve_backend",
-            "1 for the backend the last solve ran on "
-            "(host-native/host-numpy/device-jax/device-sharded)",
-            labels=("backend",), max_series=8,
-        )
-        backend_gauge.clear()
-        if resident.get("backend"):
-            backend_gauge.labels(resident["backend"]).set(1.0)
         if resident:
             REGISTRY.counter(
                 "hq_device_upload_bytes_total",
@@ -3359,6 +3378,9 @@ class Server:
             "solve_backend_reason": getattr(
                 self.model, "last_backend_reason", None
             ),
+            # where the counts of the last solve lived: {platform, kind,
+            # count} from the returned arrays, None after a host solve
+            "device": getattr(self.model, "last_device", None),
             "shape_allocations": getattr(
                 self.model, "shape_allocations", None
             ),

@@ -23,6 +23,7 @@ import asyncio
 import json
 import logging
 import sys
+from collections import Counter
 
 
 def main(argv=None) -> int:
@@ -147,6 +148,9 @@ def main(argv=None) -> int:
         return 1
 
     if args.as_json:
+        # which backend solved each tick, and how each solve ended: a run
+        # meant for the device shows here every tick the host took instead
+        solves = [d["solver"] for d in result.decisions if d.get("solver")]
         print(json.dumps({
             "seed": result.seed,
             "workload": result.workload,
@@ -158,6 +162,12 @@ def main(argv=None) -> int:
             ),
             "server_boots": result.server_boots,
             "audit": result.audit,
+            "solves_by_backend": dict(
+                Counter(str(v.get("backend")) for v in solves)
+            ),
+            "solves_by_status": dict(
+                Counter(str(v.get("status")) for v in solves)
+            ),
             "decision_digest": result.decision_digest,
             "journal_digest": result.journal_digest,
         }))

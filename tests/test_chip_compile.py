@@ -1,0 +1,144 @@
+"""The chip's compiler, asked here: the programs of the main path compile for
+a described (not attached) TPU v5e at the widths production runs them.
+
+Nothing executes, so these say nothing of results or times — only that the
+TPU compiler accepts each program and that it fits the chip.  The topology
+is described inside a fixture, never at import: only one process at a time
+may load the TPU library, and every xdist worker imports this file.
+"""
+
+import numpy as np
+import pytest
+
+W, R, B, V, M, G = 1024, 8, 256, 2, 4, 4
+W_SHARDED = 16384
+HBM_BYTES = 16 * 2**30  # one v5e chip
+
+
+@pytest.fixture(scope="module")
+def topo():
+    import os
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # noqa: BLE001 - any failure means "cannot ask"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def no_cache():
+    """A compile for a described chip is written to a persistent cache but
+    cannot be read back without the chip; keep these out of any cache."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", before)
+    compilation_cache.reset_cache()
+
+
+def _kernel_shapes(w, put, extras):
+    """ShapeDtypeStructs of the cut-scan arguments at (w, R, B, V); `put`
+    maps a sharding kind ("w2" (W, R) / "w1" (W,) / "rep" / "cm" (M, W))
+    to the sharding of that argument."""
+    import jax
+
+    def s(shape, kind, dtype=np.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=put(kind))
+
+    args = [
+        s((w, R), "w2"), s((w,), "w1"), s((w,), "w1"),    # free nt lifetime
+        s((B, V, R), "rep"), s((B,), "rep"), s((B, V), "rep"),
+        s((M, w), "cm"), s((B, V), "rep"),                # class_m order_ids
+    ]
+    kwargs = {}
+    if "all" in extras:
+        kwargs["total"] = s((w, R), "w2")
+        kwargs["all_mask"] = s((B, V, R), "rep")
+    if "gang" in extras:
+        kwargs["gang_nodes"] = s((B,), "rep")
+        kwargs["gang_ok"] = s((w,), "w1")
+        kwargs["group_onehot"] = s((w, G), "w2")
+    if "pmask" in extras:
+        kwargs["policy_mask"] = s((B, w), "cm")
+    return args, kwargs
+
+
+@pytest.mark.parametrize(
+    "extras", [(), ("all",), ("gang",), ("pmask",)],
+    ids=["flat", "all-mask", "gang", "policy-mask"],
+)
+def test_single_chip_kernel_compiles_for_v5e(topo, no_cache, extras):
+    import jax
+    from jax.sharding import SingleDeviceSharding
+
+    from hyperqueue_tpu.ops.assign import greedy_cut_scan_impl
+
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    args, kwargs = _kernel_shapes(W, lambda kind: one_chip, extras)
+    compiled = jax.jit(
+        greedy_cut_scan_impl, donate_argnums=(0, 1)
+    ).lower(*args, **kwargs).compile()
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < HBM_BYTES
+
+
+def test_resident_scatter_compiles_for_v5e(topo, no_cache):
+    import jax
+    from jax.sharding import SingleDeviceSharding
+
+    from hyperqueue_tpu.parallel.resident import (
+        _ROW_BUCKET_FLOOR,
+        _scatter_rows,
+    )
+
+    one_chip = SingleDeviceSharding(topo.devices[0])
+
+    def s(shape):
+        return jax.ShapeDtypeStruct(shape, np.int32, sharding=one_chip)
+
+    k = _ROW_BUCKET_FLOOR
+    fn = jax.jit(_scatter_rows, donate_argnums=(0,))
+    fn.lower(s((W, R)), s((k,)), s((k, R))).compile()  # free / total
+    fn.lower(s((W,)), s((k,)), s((k,))).compile()      # nt_free / lifetime
+
+
+def test_device_slicer_compiles_for_v5e(topo, no_cache):
+    import jax
+    from jax.sharding import SingleDeviceSharding
+
+    from hyperqueue_tpu.models.greedy import _device_slicer
+
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    counts = jax.ShapeDtypeStruct((B, V, W), np.int32, sharding=one_chip)
+    _device_slicer(200, 2, 1000).lower(counts).compile()  # live extents
+
+
+@pytest.mark.parametrize(
+    "extras", [(), ("gang",), ("all",)], ids=["flat", "gang", "all-mask"]
+)
+def test_sharded_kernel_compiles_for_four_v5e(topo, no_cache, extras):
+    import re
+
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from hyperqueue_tpu.parallel.solve import sharded_cut_scan_donate
+
+    mesh = Mesh(np.array(topo.devices[:4]), axis_names=("w",))
+    specs = {"w2": P("w", None), "w1": P("w"), "rep": P(),
+             "cm": P(None, "w")}
+    args, kwargs = _kernel_shapes(
+        W_SHARDED, lambda kind: NamedSharding(mesh, specs[kind]), extras
+    )
+    compiled = sharded_cut_scan_donate.lower(mesh, *args, **kwargs).compile()
+    mem = compiled.memory_analysis()  # bytes per device
+    assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < HBM_BYTES
+    assert re.search(r"all-gather|all-reduce", compiled.as_text())
