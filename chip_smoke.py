@@ -463,7 +463,7 @@ def _tick_line(model, before: dict, phases_ms: dict, **head) -> dict:
         "model_phases_ms": {
             k: round(v, 3) for k, v in model.last_phases.items()
         },
-        # the guard's fresh solve is inside solve_host_prep
+        # the guard's fresh solve lies in no phase (it follows device_sync)
         "guard_ms": round(model.guard_ms, 3),
         "uploaded_bytes":
             after["upload_bytes_total"] - before["upload_bytes_total"],

@@ -38,6 +38,7 @@ from hyperqueue_tpu.ops.assign import (
 from hyperqueue_tpu.utils.constants import INF_TIME
 from hyperqueue_tpu.utils import clock
 from hyperqueue_tpu.utils.jaxdev import device_block
+from hyperqueue_tpu.utils.trace import TRACER
 
 
 def _bucket(n: int, floor: int) -> int:
@@ -210,28 +211,28 @@ class _DeviceCounts:
     def result(self) -> np.ndarray:
         model = self._model
         prep = self._prep
-        t0 = time.perf_counter()
-        out = np.asarray(self._counts_dev)
-        if self._after is not None:
-            free_after, nt_after = self._after
-            self._res.apply_outputs(
-                np.asarray(free_after), np.asarray(nt_after)
-            )
-        t1 = time.perf_counter()
-        sync_ms = (t1 - t0) * 1e3
+        res = self._res
+        phases = prep["phases"]
+        with TRACER.phase(phases, "device_sync"):
+            # the wait for the kernel, then the counts' readback
+            with TRACER.phase(phases, "device_sync/counts"):
+                out = res.read_back(self._counts_dev)
+            if self._after is not None:
+                # the two state readbacks and the mirror's copy
+                with TRACER.phase(phases, "device_sync/state"):
+                    free_after, nt_after = self._after
+                    res.apply_outputs(
+                        res.read_back(free_after), res.read_back(nt_after)
+                    )
         # the cost the TICK pays: dispatch + readback wait.  Synchronous
-        # solves call result() immediately, so sync_ms contains the whole
-        # device execution; pipelined solves call it a tick later, when
-        # the execution already overlapped host work — charging the idle
-        # gap would wrongly bench the device in the cost model.
-        total_ms = prep["dispatch_ms"] + sync_ms
-        model._observe("device", prep["shape_key"], total_ms)
-        model.last_phases = {
-            "pad_ms": prep["pad_ms"],
-            "visit_ms": prep["visit_ms"],
-            "dispatch_ms": prep["dispatch_ms"],
-            "sync_ms": sync_ms,
-        }
+        # solves call result() immediately, so device_sync contains the
+        # whole device execution; pipelined solves call it a tick later,
+        # when the execution already overlapped host work — charging the
+        # idle gap would wrongly bench the device in the cost model.
+        model._observe(
+            "device", prep["shape_key"],
+            phases["solve_dispatch"] + phases["device_sync"],
+        )
         model._maybe_paranoid_check(prep, out)
         if not out.flags.c_contiguous:  # pragma: no cover - np.asarray copy
             out = np.ascontiguousarray(out)
@@ -284,8 +285,10 @@ class GreedyCutScanModel:
         # compilation on the jit path, so a steady-state tick must not
         # increment it (asserted by bench.py --smoke)
         self.shape_allocations = 0
-        # per-phase latency of the last solve() in ms (pad/visit/dispatch/
-        # sync) — consumed by the tick's phase breakdown
+        # the last solve's spans in ms, under the tick's phase keys
+        # (solve_host_prep, solve_dispatch, device_sync and their
+        # children), written through TRACER.phase where the work happens;
+        # the tick folds them into its own breakdown (tick.fold_model_phases)
         self.last_phases: dict = {}
         # device residency (parallel/resident.py), built on first device
         # solve; None until then
@@ -452,12 +455,14 @@ class GreedyCutScanModel:
         the caller can overlap host work with the device execution — the
         pipelined tick (scheduler/pipeline.py) maps the previous solve
         during exactly this window."""
-        prep = self._prepare(
-            free, nt_free, lifetime, needs, sizes, min_time, total, all_mask,
-            gang_nodes=gang_nodes, gang_ok=gang_ok, group_onehot=group_onehot,
-            affinity=affinity,
-        )
-        backend, reason = self._backend_decision(prep["shape_key"])
+        self.last_phases = phases = {}
+        with TRACER.phase(phases, "solve_host_prep"):
+            prep = self._prepare(
+                free, nt_free, lifetime, needs, sizes, min_time, total,
+                all_mask, phases, gang_nodes=gang_nodes, gang_ok=gang_ok,
+                group_onehot=group_onehot, affinity=affinity,
+            )
+            backend, reason = self._backend_decision(prep["shape_key"])
         self.last_backend_reason = reason
         self._solves_since_device += 1
         if backend == "host":
@@ -477,9 +482,8 @@ class GreedyCutScanModel:
 
     # -- preparation (shared by every backend) ----------------------------
     def _prepare(self, free, nt_free, lifetime, needs, sizes, min_time,
-                 total, all_mask, gang_nodes=None, gang_ok=None,
+                 total, all_mask, phases, gang_nodes=None, gang_ok=None,
                  group_onehot=None, affinity=None) -> dict:
-        _t0 = time.perf_counter()
         n_w, n_r = free.shape
         n_b, n_v, _ = needs.shape
 
@@ -584,21 +588,22 @@ class GreedyCutScanModel:
             if has_pmask:
                 pmask_p = np.zeros((pb, pw), dtype=np.int32)
                 pmask_p[:n_b, :n_w] = (affinity > 0).astype(np.int32)
-        _t1 = time.perf_counter()
 
-        scarcity = np.asarray(
-            scarcity_weights(free_p.astype(np.int64).sum(axis=0))
-        ).astype(np.float32)
-        class_m, order_ids = host_visit_classes(
-            free_p, needs_p, scarcity, all_mask=amask_p, affinity=aff_p
-        )
-        # bucket the mask-table dimension so steady-state ticks reuse the
-        # compiled program; padding rows are all-class-0 (never referenced)
-        pm = _bucket(class_m.shape[0], 4)
-        if pm > class_m.shape[0]:
-            pad = np.zeros((pm - class_m.shape[0], pw), dtype=np.int32)
-            class_m = np.concatenate([class_m, pad], axis=0)
-        _t2 = time.perf_counter()
+        # the visit classes; what precedes it in solve_host_prep is padding
+        with TRACER.phase(phases, "solve_host_prep/visit"):
+            scarcity = np.asarray(
+                scarcity_weights(free_p.astype(np.int64).sum(axis=0))
+            ).astype(np.float32)
+            class_m, order_ids = host_visit_classes(
+                free_p, needs_p, scarcity, all_mask=amask_p, affinity=aff_p
+            )
+            # bucket the mask-table dimension so steady-state ticks reuse
+            # the compiled program; padding rows are all-class-0 (never
+            # referenced)
+            pm = _bucket(class_m.shape[0], 4)
+            if pm > class_m.shape[0]:
+                pad = np.zeros((pm - class_m.shape[0], pw), dtype=np.int32)
+                class_m = np.concatenate([class_m, pad], axis=0)
 
         return {
             "free_p": free_p, "nt_p": nt_p, "life_p": life_p,
@@ -612,29 +617,24 @@ class GreedyCutScanModel:
                           has_pmask),
             "has_all": has_all, "has_gang": has_gang,
             "has_pmask": has_pmask,
-            "pad_ms": (_t1 - _t0) * 1e3,
-            "visit_ms": (_t2 - _t1) * 1e3,
-            "dispatch_ms": 0.0,
+            "phases": phases,
         }
 
     # -- host path ---------------------------------------------------------
     def _host_solve(self, prep) -> _ReadyCounts:
-        _t0 = time.perf_counter()
-        counts = self._host_counts(prep)
-        self.last_device = None
-        _t1 = time.perf_counter()
-        n_b, n_v, n_w = prep["extents"]
-        out = np.ascontiguousarray(
-            np.asarray(counts)[:n_b, :n_v, :n_w]
+        phases = prep["phases"]
+        with TRACER.phase(phases, "solve_dispatch"):
+            counts = self._host_counts(prep)
+            self.last_device = None
+        with TRACER.phase(phases, "device_sync"):
+            n_b, n_v, n_w = prep["extents"]
+            out = np.ascontiguousarray(
+                np.asarray(counts)[:n_b, :n_v, :n_w]
+            )
+        self._observe(
+            "host", prep["shape_key"],
+            phases["solve_dispatch"] + phases["device_sync"],
         )
-        _t2 = time.perf_counter()
-        self.last_phases = {
-            "pad_ms": prep["pad_ms"],
-            "visit_ms": prep["visit_ms"],
-            "dispatch_ms": (_t1 - _t0) * 1e3,
-            "sync_ms": (_t2 - _t1) * 1e3,
-        }
-        self._observe("host", prep["shape_key"], (_t2 - _t0) * 1e3)
         return _ReadyCounts(out)
 
     def _host_counts(self, prep):
@@ -704,18 +704,24 @@ class GreedyCutScanModel:
         return base
 
     def _device_solve(self, prep) -> _DeviceCounts:
-        _t0 = time.perf_counter()
-        res = self._residency()
-        free_d, nt_d, life_d, total_d = res.sync(
-            prep["free_p"], prep["nt_p"], prep["life_p"], prep["total_p"]
-        )
-        counts, free_after, nt_after = self._kernel_dispatch(
-            res, free_d, nt_d, life_d, total_d, prep
-        )
-        res.adopt_outputs(free_after, nt_after)
-        n_b, n_v, n_w = prep["extents"]
-        counts_dev = _device_slicer(n_b, n_v, n_w)(counts)
-        prep["dispatch_ms"] = (time.perf_counter() - _t0) * 1e3
+        phases = prep["phases"]
+        with TRACER.phase(phases, "solve_dispatch"):
+            res = self._residency()
+            # host time to bring the resident state up to date: the
+            # dirty-row scatter or a full upload
+            with TRACER.phase(phases, "solve_dispatch/upload"):
+                free_d, nt_d, life_d, total_d = res.sync(
+                    prep["free_p"], prep["nt_p"], prep["life_p"],
+                    prep["total_p"],
+                )
+            # placing the replicated inputs, enqueueing kernel and slicer
+            with TRACER.phase(phases, "solve_dispatch/launch"):
+                counts, free_after, nt_after = self._kernel_dispatch(
+                    res, free_d, nt_d, life_d, total_d, prep
+                )
+                res.adopt_outputs(free_after, nt_after)
+                n_b, n_v, n_w = prep["extents"]
+                counts_dev = _device_slicer(n_b, n_v, n_w)(counts)
         self.last_backend = self._device_backend_name
         self.last_device = device_block(counts_dev)
         self._resident_solves += 1

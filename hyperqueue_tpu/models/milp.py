@@ -37,6 +37,7 @@ import logging
 
 import numpy as np
 from hyperqueue_tpu.utils import clock
+from hyperqueue_tpu.utils.trace import TRACER
 
 logger = logging.getLogger(__name__)
 
@@ -54,7 +55,14 @@ class MilpModel:
         # must finish well under the worker-heartbeat reaper limit (~32 s)
         self.time_limit_secs = time_limit_secs
 
-    def solve(
+    def solve(self, *args, **kwargs) -> np.ndarray:
+        """`_solve`, timed: a MILP solve has no dispatch/readback split, so
+        the whole of it is the tick's `solve_dispatch` phase."""
+        self.last_phases = {}
+        with TRACER.phase(self.last_phases, "solve_dispatch"):
+            return self._solve(*args, **kwargs)
+
+    def _solve(
         self,
         free: np.ndarray,       # (W, R) int32
         nt_free: np.ndarray,    # (W,) int32

@@ -93,6 +93,8 @@ class DeviceResidency:
         self.upload_bytes_total = 0
         self.rep_cache_hits = 0
         self.invalidations = 0
+        self.readbacks_total = 0
+        self.readback_bytes_total = 0
 
     # -- placement helpers ------------------------------------------------
     def _put(self, arr, kind):
@@ -208,6 +210,14 @@ class DeviceResidency:
         self.nt_free = nt_after
         self._await_apply = True
 
+    def read_back(self, dev) -> np.ndarray:
+        """One device array on the host (waits for what computes it),
+        counted: the twin of the upload counters."""
+        host = np.asarray(dev)
+        self.readbacks_total += 1
+        self.readback_bytes_total += int(host.nbytes)
+        return host
+
     def apply_outputs(self, free_after_host, nt_after_host) -> None:
         """Re-synchronize the mirror with the donated outputs: the caller
         reads `free_after`/`nt_after` back alongside the counts (one round
@@ -266,4 +276,6 @@ class DeviceResidency:
             "upload_bytes_total": self.upload_bytes_total,
             "rep_cache_hits": self.rep_cache_hits,
             "invalidations": self.invalidations,
+            "readbacks_total": self.readbacks_total,
+            "readback_bytes_total": self.readback_bytes_total,
         }

@@ -33,6 +33,7 @@ from __future__ import annotations
 import time as _time
 from dataclasses import dataclass, field
 from hyperqueue_tpu.utils import clock
+from hyperqueue_tpu.utils.trace import TRACER
 
 
 @dataclass(slots=True)
@@ -89,20 +90,22 @@ class TickPipeline:
         The wait for the device result is timed separately
         (`pipeline_wait` phase): in steady state it is ~zero because the
         device ran during the inter-tick host work."""
-        from hyperqueue_tpu.scheduler.tick import _map_counts
+        from hyperqueue_tpu.scheduler.tick import (
+            _map_counts,
+            fold_model_phases,
+        )
 
         pending = self.pending
         if pending is None:
             return []
         self.pending = None
-        _t0 = _time.perf_counter()
-        counts = pending.handle.result()
+        with TRACER.phase(phases, "pipeline_wait") as wait:
+            counts = pending.handle.result()
         _t1 = _time.perf_counter()
-        self.last_wait_ms = (_t1 - _t0) * 1e3
-        if phases is not None:
-            phases["pipeline_wait"] = (
-                phases.get("pipeline_wait", 0.0) + self.last_wait_ms
-            )
+        self.last_wait_ms = wait.seconds * 1e3
+        # the wait's split (device_sync/counts, device_sync/state) is the
+        # model's; `pipeline_wait` is their parent in this tick
+        fold_model_phases(phases, model, prefix="device_sync/")
         if decision is not None:
             import numpy as np
 

@@ -22,6 +22,7 @@ from hyperqueue_tpu.utils.constants import INF_TIME
 from hyperqueue_tpu.resources.map import ResourceIdMap, ResourceRqMap
 from hyperqueue_tpu.scheduler.queues import Priority, TaskQueues
 from hyperqueue_tpu.utils.metrics import REGISTRY
+from hyperqueue_tpu.utils.trace import TRACER
 
 MAX_CUTS_PER_QUEUE = 32
 # Node budget for the per-worker min-utilization branch-and-bound
@@ -648,6 +649,20 @@ def assemble_solve_inputs(workers, batches, rq_map, resource_map,
     }
 
 
+def fold_model_phases(phases, model, prefix: str = "") -> None:
+    """Add the spans the model timed in its last solve (`last_phases`,
+    written through TRACER.phase where the work happens: solve_host_prep,
+    solve_dispatch, device_sync and their children) to the tick's
+    `phases`.  The pipelined tick that takes a result folds only what the
+    wait added (`prefix="device_sync/"`): the dispatching tick has folded
+    the rest."""
+    if phases is None:
+        return
+    for key, ms in (getattr(model, "last_phases", None) or {}).items():
+        if key.startswith(prefix):
+            phases[key] = phases.get(key, 0.0) + ms
+
+
 def _count_solve(model) -> None:
     backend = getattr(model, "last_backend", None)
     if backend:  # the MILP names none
@@ -658,13 +673,12 @@ def _run_main_solve(queues, workers, rq_map, resource_map, model, batches,
                     cpu_floor=None, dense=None, phases=None, key_cache=None,
                     decision=None, pipeline=None, gang_ok=None,
                     group_ids=None, policy=None):
-    _t0 = _time.perf_counter()
-    kwargs = assemble_solve_inputs(
-        workers, batches, rq_map, resource_map, cpu_floor=cpu_floor,
-        dense=dense, key_cache=key_cache, gang_ok=gang_ok,
-        group_ids=group_ids, policy=policy,
-    )
-    _t1 = _time.perf_counter()
+    with TRACER.phase(phases, "assemble"):
+        kwargs = assemble_solve_inputs(
+            workers, batches, rq_map, resource_map, cpu_floor=cpu_floor,
+            dense=dense, key_cache=key_cache, gang_ok=gang_ok,
+            group_ids=group_ids, policy=policy,
+        )
     if pipeline is not None and hasattr(model, "solve_async"):
         # pipelined dispatch: enqueue the solve and return WITHOUT mapping
         # — the caller maps this solve at the top of its next tick
@@ -675,14 +689,7 @@ def _run_main_solve(queues, workers, rq_map, resource_map, model, batches,
 
         handle = model.solve_async(**kwargs)
         _count_solve(model)
-        if phases is not None:
-            phases["assemble"] = (
-                phases.get("assemble", 0.0) + (_t1 - _t0) * 1e3
-            )
-            phases["solve_dispatch"] = (
-                phases.get("solve_dispatch", 0.0)
-                + (_time.perf_counter() - _t1) * 1e3
-            )
+        fold_model_phases(phases, model)
         if decision is not None:
             decision.setdefault("solver", {
                 "status": "pipelined",
@@ -699,9 +706,11 @@ def _run_main_solve(queues, workers, rq_map, resource_map, model, batches,
             backend_reason=getattr(model, "last_backend_reason", ""),
         ))
         return []
+    _t1 = _time.perf_counter()  # the decision record's own reading
     counts = model.solve(**kwargs)
     _t2 = _time.perf_counter()
     _count_solve(model)
+    fold_model_phases(phases, model)
     if decision is not None:
         # the solver's verdict for this tick's DecisionRecord
         # (scheduler/decision.py): a watchdog-wrapped model reports whether
@@ -721,22 +730,6 @@ def _run_main_solve(queues, workers, rq_map, resource_map, model, batches,
             "solve_ms": round((_t2 - _t1) * 1e3, 4),
             "objective": int(np.asarray(counts).sum()),
         }
-    if phases is not None:
-        phases["assemble"] = phases.get("assemble", 0.0) + (_t1 - _t0) * 1e3
-        solve_ms = (_t2 - _t1) * 1e3
-        # models that time their own dispatch/readback split report it
-        # (greedy/multichip last_phases); the remainder is host-side
-        # padding + visit-class prep inside solve()
-        model_phases = getattr(model, "last_phases", None) or {}
-        dispatch = model_phases.get("dispatch_ms", solve_ms)
-        sync = model_phases.get("sync_ms", 0.0)
-        phases["solve_dispatch"] = (
-            phases.get("solve_dispatch", 0.0) + dispatch
-        )
-        phases["device_sync"] = phases.get("device_sync", 0.0) + sync
-        phases["solve_host_prep"] = phases.get("solve_host_prep", 0.0) + max(
-            solve_ms - dispatch - sync, 0.0
-        )
 
     worker_ids = (
         dense.worker_ids if dense is not None
@@ -760,10 +753,9 @@ def _map_counts(queues, batches, worker_ids, counts,
     models/greedy._device_slicer), so the native nonzero fast path applies
     everywhere.
     """
-    _t2 = _time.perf_counter()
     assignments: list[Assignment] = []
     counts = np.asarray(counts)
-    try:
+    with TRACER.phase(phases, "mapping"):
         # one global nonzero over (B, V, W): row-major order preserves the
         # per-batch FIFO take semantics of the nested loop it replaces
         from hyperqueue_tpu.utils.native import native_nonzero
@@ -856,11 +848,6 @@ def _map_counts(queues, batches, worker_ids, counts,
                 [(task_id, worker_id, rq_id, vi) for task_id in task_ids]
             )
         return assignments
-    finally:
-        if phases is not None:
-            phases["mapping"] = phases.get("mapping", 0.0) + (
-                _time.perf_counter() - _t2
-            ) * 1e3
 
 
 def _solve_mu_workers(queues, mu_rows, rq_map, resource_map):

@@ -60,11 +60,16 @@ class DenseSnapshot:
 class TickPhaseStats:
     """Per-phase tick latency breakdown, recorded by the reactor.
 
-    Mirrors the phases of one schedule(): batches -> assemble ->
-    solve-dispatch -> device-sync -> mapping (plus gangs/prefill, traced
-    separately).  Surfaced through `hq server stats` and bench.py
-    --phases so a latency regression names its phase instead of one
-    opaque number.
+    One entry per key that `TRACER.phase` wrote into the tick's `phases`
+    dict during one schedule(), in order: gangs, batches, assemble,
+    solve_host_prep (child /visit), solve_dispatch (children /upload,
+    /launch), device_sync (children /counts, /state), pipeline_wait (the
+    pipelined tick's wait, parent of the device_sync children there),
+    mapping, prefill (children /fill, /displace, /rebalance), decide, and
+    total (the root).  A key with a `/` lies inside its parent in time
+    (span catalog: docs/observability.md).  Surfaced through `hq server
+    stats` and bench.py --phases so a latency regression names its phase
+    instead of one opaque number.
     """
 
     ticks: int = 0
@@ -96,19 +101,26 @@ class TickPhaseStats:
         return out
 
     def shares(self) -> dict:
-        """Phase -> fraction of total tick time (all phases sum to ~1.0).
+        """Phase -> fraction of the tick time the phases account for: the
+        top-level phases sum to 1.0 (`total` holds them all and a child
+        lies inside its parent, so neither is in the denominator); a
+        child's share is its part of the same whole.
 
         The regression-blame side of the profiling plane (ISSUE 19):
         bench smokes store these next to the profiler's per-plane CPU
         shares, and ``--regress`` diffs both against the prior-row
         median so a latency regression names the phase whose share grew
         rather than one opaque wall-clock number."""
-        total = sum(self.totals_ms.values())
+        total = sum(
+            t for name, t in self.totals_ms.items()
+            if name != "total" and "/" not in name
+        )
         if total <= 0:
             return {}
         return {
             name: round(t / total, 4)
             for name, t in sorted(self.totals_ms.items())
+            if name != "total"
         }
 
 

@@ -2398,17 +2398,17 @@ class Server:
             await self.comm.scheduling_event.wait()
             await asyncio.sleep(self.schedule_min_delay)
             self.comm.scheduling_event.clear()
-            t0 = time.perf_counter()
-            n = reactor.schedule(self.core, self.comm, self.events, self.model)
-            TRACER.record("scheduler/tick", time.perf_counter() - t0)
             # the tick runs synchronously on the loop: its duration IS the
             # solve plane's loop occupancy (stall watchdog included)
-            self.note_plane("solve", time.perf_counter() - t0)
+            with self.plane("solve") as held:
+                n = reactor.schedule(
+                    self.core, self.comm, self.events, self.model
+                )
             if n:
                 logger.debug(
                     "tick assigned %d tasks in %.2f ms",
                     n,
-                    (time.perf_counter() - t0) * 1e3,
+                    held.seconds * 1e3,
                     extra={"tick": self.core.tick_counter},
                 )
 
@@ -2436,7 +2436,7 @@ class Server:
             self._handoff_wake.clear()
             while plane.handoff:
                 items = plane.pop_batch(self.INGEST_DRAIN_BATCH)
-                t0 = time.perf_counter()
+                held = self.plane("ingest").__enter__()
                 acks: list = []
                 batch = None
 
@@ -2498,7 +2498,7 @@ class Server:
                             self.reply_visible(channel, response)
                 finally:
                     flush_chunks()
-                self.note_plane("ingest", time.perf_counter() - t0)
+                    held.__exit__(None, None, None)
                 plane.notify_drained()
                 # yield between batches: a sustained multi-client flood
                 # must round-robin with the scheduler tick and the worker
@@ -3168,21 +3168,19 @@ class Server:
             # fencing), the same crash semantics as before. With
             # --journal-plane reactor the inline group commit covers the
             # frame as it always did (ONE write + fsync per batch).
-            t0 = time.perf_counter()
             if self.jplane is not None:
-                for sub in subs:
-                    self._process_worker_message(worker, sub)
                 # in-loop completion processing (sans journal I/O) is its
                 # own lag plane now; `journal` measures handoff latency
                 # on the commit thread (see JournalPlane)
-                self.note_plane("completion", time.perf_counter() - t0)
-            else:
-                with self._journal_group_commit():
+                with self.plane("completion"):
                     for sub in subs:
                         self._process_worker_message(worker, sub)
+            else:
                 # frame processing + group commit hold the loop
                 # synchronously: the journal plane's loop occupancy
-                self.note_plane("journal", time.perf_counter() - t0)
+                with self.plane("journal"), self._journal_group_commit():
+                    for sub in subs:
+                        self._process_worker_message(worker, sub)
 
     def _process_worker_message(self, worker: Worker, msg: dict) -> None:
             op = msg.get("op")
@@ -3319,15 +3317,15 @@ class Server:
         handler = getattr(self, f"_client_{op.replace('-', '_')}", None)
         if handler is None:
             return {"op": "error", "message": f"unknown operation {op!r}"}
-        t0 = time.perf_counter()
-        try:
-            return await handler(msg)
-        except Exception as e:  # noqa: BLE001 - client errors must not kill the server
-            logger.exception("error handling client %r", op)
-            return {"op": "error", "message": str(e)}
-        finally:
-            if op not in self._RPC_LAG_EXEMPT:
-                self.note_plane("rpc", time.perf_counter() - t0)
+        with (
+            contextlib.nullcontext() if op in self._RPC_LAG_EXEMPT
+            else self.plane("rpc")
+        ):
+            try:
+                return await handler(msg)
+            except Exception as e:  # noqa: BLE001 - client errors must not kill the server
+                logger.exception("error handling client %r", op)
+                return {"op": "error", "message": str(e)}
 
     async def _client_server_info(self, msg: dict) -> dict:
         return {
@@ -5194,6 +5192,14 @@ class Server:
     # --- reactor lag + stall watchdog (ISSUE 8c) ----------------------
     STALL_CAPTURE_MIN_INTERVAL = 5.0
 
+    def plane(self, plane: str):
+        """`with self.plane("rpc"):` times one work class's hold of the
+        event loop through the tracer's one primitive: on exit
+        `note_plane` gets the seconds, and under a profiler session the
+        hold lies in the trace as `hq/plane/<plane>`."""
+        return TRACER.phase(None, plane, root="hq/plane",
+                            done=self.note_plane)
+
     def note_plane(self, plane: str, dt: float) -> None:
         """Record how long one work class held the event loop; past the
         stall budget, auto-capture a diagnosis dump."""
@@ -5284,7 +5290,9 @@ class Server:
         interval = 0.1
         while True:
             before = clock.monotonic()
-            await asyncio.sleep(interval)
+            # in a trace: the background every other plane's hold lies on
+            with TRACER.phase(None, "loop", root="hq/plane"):
+                await asyncio.sleep(interval)
             overshoot = clock.monotonic() - before - interval
             self.note_plane("loop", max(overshoot, 0.0))
 

@@ -10,7 +10,6 @@ batches via an "ask_for_scheduling" flag + wakeup, never reentrantly
 from __future__ import annotations
 
 import logging
-import time as _time
 from typing import Protocol
 
 from hyperqueue_tpu.ids import task_id_job, task_id_task
@@ -38,8 +37,11 @@ logger = logging.getLogger(__name__)
 # tick (not per task) so the cost is a handful of dict ops per schedule().
 _TICK_PHASE_SECONDS = REGISTRY.histogram(
     "hq_tick_phase_seconds",
-    "scheduler tick latency per phase (snapshot/batches/gangs/assemble/"
-    "solve/mapping/prefill/decide/total)",
+    "scheduler tick latency per phase, timed by TRACER.phase: gangs, "
+    "batches, assemble, solve_host_prep[/visit], "
+    "solve_dispatch[/upload|/launch], device_sync[/counts|/state], "
+    "pipeline_wait, mapping, prefill[/fill|/displace|/rebalance], decide, "
+    "total (a key with a / lies inside its parent)",
     labels=("phase",),
 )
 _TICKS_TOTAL = REGISTRY.counter(
@@ -781,6 +783,272 @@ def _apply_fused_gangs(
     return sn, n_gangs
 
 
+def _prefill_fill(core: Core, now: float, per_worker_msgs: dict,
+                  leftover_batches, policy_ctx, fused_gang_hold: set):
+    """Prefill pass 1, proactive filling: push extra top-priority tasks to
+    busy workers so short tasks pipeline without a server round-trip per
+    task (reference mapping.rs:159 process_proactive_filling, max
+    40/worker).  Returns (tasks prefilled, the leftover batches with what
+    it took subtracted)."""
+    if not core.queues.total_ready():
+        return 0, leftover_batches
+    prefilled = 0
+    budgets = {
+        w.worker_id: PREFILL_MAX - len(w.prefilled_tasks)
+        for w in core.workers.values()
+        if not w.mn_task
+        and not w.mn_reserved
+        and not w.draining
+        and w.worker_id not in fused_gang_hold
+        and (w.assigned_tasks or w.prefilled_tasks)
+        and len(w.prefilled_tasks) < PREFILL_MAX
+    }
+    # starvation guard (reference reservation vars, solver.rs:479-518):
+    # each request class with leftover ready tasks reserves ONE capable
+    # worker where strictly-lower-priority tasks may not prefill, so a
+    # big task eventually sees a fully drained worker instead of losing
+    # every race against streams of small tasks.
+    if leftover_batches is None:
+        leftover_batches = create_batches(core.queues)
+    if policy_ctx is not None and policy_ctx.boosts:
+        # the solve's boost-weighted order lives in run_tick's COPY of
+        # the batch list; prefill consumes the caller's list, so fold
+        # the same boost arithmetic here — under deep prefill budgets
+        # this order, not the solve's ~capacity-sized mapping, decides
+        # which job's backlog reaches the workers first
+        leftover_batches.sort(key=lambda b: (
+            b.priority[0],
+            b.priority[1]
+            + policy_ctx.boost_for_sched(b.priority[1]) * BLEVEL_STRIDE,
+        ), reverse=True)
+    reservations: dict[int, Priority_t] = {}
+    for batch in leftover_batches:
+        rqv = core.rq_map.get_variants(batch.rq_id)
+        for w in sorted(core.workers.values(), key=lambda w: w.worker_id):
+            if (
+                w.mn_task or w.mn_reserved or w.draining
+                or w.worker_id in reservations
+            ):
+                continue
+            if w.resources.is_capable_of_rqv(rqv):
+                reservations[w.worker_id] = batch.priority
+                break
+    # prefill in GLOBAL priority order (batches are priority-sorted), so
+    # high-priority classes claim worker budgets first; workers are fed
+    # least-backlog-first so a deep budget cannot pile onto one worker
+    # while its peers run dry between refills
+    workers_by_backlog = sorted(
+        core.workers.values(),
+        key=lambda w: (
+            len(w.prefilled_tasks) + len(w.assigned_tasks),
+            w.worker_id,
+        ),
+    )
+    for batch in leftover_batches:
+        queue = core.queues.queue(batch.rq_id)
+        rqv = core.rq_map.get_variants(batch.rq_id)
+        eligible: list[tuple[Worker, int]] = []
+        for worker in workers_by_backlog:
+            if budgets.get(worker.worker_id, 0) <= 0:
+                continue
+            blocking = reservations.get(worker.worker_id)
+            if blocking is not None and batch.priority < blocking:
+                continue
+            variant = next(
+                (
+                    i
+                    for i, v in enumerate(rqv.variants)
+                    if worker.resources.is_capable_of(v)
+                ),
+                None,
+            )
+            if variant is None:
+                continue
+            eligible.append((worker, variant))
+        if not eligible:
+            continue
+        # fair-share split across eligible workers (multiple passes so
+        # budget-capped workers' leftovers flow to the others); without
+        # this a deep budget lets the first worker swallow the batch
+        fair = max(-(-batch.size // len(eligible)), 1)
+        progress = True
+        while progress:
+            progress = False
+            for worker, variant in eligible:
+                budget = budgets.get(worker.worker_id, 0)
+                if budget <= 0:
+                    continue
+                taken = queue.take(batch.priority, min(budget, fair))
+                if not taken:
+                    break
+                progress = True
+                batch.size -= len(taken)  # keeps leftover sizes true
+                for task_id in taken:
+                    task = core.tasks[task_id]
+                    task.state = TaskState.ASSIGNED
+                    task.t_assigned = now
+                    task.assigned_worker = worker.worker_id
+                    task.assigned_variant = variant
+                    task.prefilled = True
+                    prefilled += 1
+                    worker.prefilled_tasks.add(task_id)
+                    budgets[worker.worker_id] -= 1
+                    per_worker_msgs.setdefault(
+                        worker.worker_id, []
+                    ).append(_compute_message(core, task, variant))
+    return prefilled, leftover_batches
+
+
+def _prefill_displace(core: Core, comm: Comm, per_worker_msgs: dict,
+                      leftover_batches):
+    """Prefill pass 2, displacement: strictly-higher-user-priority READY
+    work must not sit in the queues while lower-priority prefilled backlog
+    holds the workers that could run it.  Retract the lowest-priority
+    settled victims; once they answer, the next tick prefills in global
+    priority order (reference redirects the prefilled task on submit,
+    test_reactor.rs test_prefill_submit_high_priority).  Returns the
+    leftover batches."""
+    if not core.queues.total_ready():
+        return leftover_batches
+    # per-worker victim lists are built ONCE (ascending priority, with
+    # this tick's sends and in-flight retracts excluded), then consumed
+    # across the batch loop — not rebuilt per (batch x worker).  The
+    # common saturated case (all leftover and backlog at one user
+    # priority) exits on the first victim comparison per worker.
+    victim_lists: dict[int, list] = {}
+    for worker in core.workers.values():
+        if worker.mn_task or worker.mn_reserved:
+            continue
+        if not worker.prefilled_tasks:
+            continue
+        just_sent = {
+            m["id"] for m in per_worker_msgs.get(worker.worker_id, ())
+        }
+        victims = sorted(
+            (
+                core.tasks[tid]
+                for tid in worker.prefilled_tasks
+                if tid not in just_sent
+                and not core.tasks[tid].retract_pending
+            ),
+            key=lambda t: t.priority,
+        )
+        if victims:
+            victims.reverse()  # pop() consumes lowest-priority first
+            victim_lists[worker.worker_id] = victims
+    if victim_lists:
+        # leftover_batches already carries the post-solve post-prefill
+        # sizes (both phases decrement batch.size) — no third
+        # create_batches walk
+        if leftover_batches is None:
+            leftover_batches = create_batches(core.queues)
+        retract_by_worker: dict[int, list[tuple[int, int]]] = {}
+        # per-worker retract cap: one large leftover batch must not
+        # strip every lower-priority prefilled task from every capable
+        # worker in a single tick (far more than those workers could
+        # run) — that just churns retract/re-prefill under deep
+        # backlogs. Per displacing batch, a worker gives up at most
+        # 2× the batch tasks it could simultaneously RUN (the extra
+        # factor leaves backlog headroom), within a PREFILL_MAX
+        # overall budget.
+        retract_budget = {wid: PREFILL_MAX for wid in victim_lists}
+        for batch in leftover_batches:
+            if batch.size <= 0:
+                continue
+            rqv = core.rq_map.get_variants(batch.rq_id)
+            need = batch.size
+            for worker_id, victims in victim_lists.items():
+                if need <= 0:
+                    break
+                if not victims or retract_budget[worker_id] <= 0:
+                    continue
+                worker = core.workers[worker_id]
+                if not worker.resources.is_capable_of_rqv(rqv):
+                    continue
+                allowance = min(
+                    retract_budget[worker_id],
+                    2 * _rqv_fit_count(worker.resources, rqv),
+                )
+                while victims and need > 0 and allowance > 0:
+                    if victims[-1].priority[0] >= batch.priority[0]:
+                        break  # ascending: nothing lower remains
+                    victim = victims.pop()
+                    victim.retract_pending = True
+                    retract_by_worker.setdefault(
+                        worker_id, []
+                    ).append((victim.task_id, victim.instance_id))
+                    need -= 1
+                    allowance -= 1
+                    retract_budget[worker_id] -= 1
+        for wid, refs in retract_by_worker.items():
+            _RETRACTED_TOTAL.labels("displacement").inc(len(refs))
+            comm.send_retract(wid, refs)
+    return leftover_batches
+
+
+def _prefill_rebalance(core: Core, comm: Comm,
+                       per_worker_msgs: dict) -> None:
+    """Prefill pass 3, rebalance: steal prefilled backlog back from loaded
+    workers whenever idle capacity appears that the backlog could use — not
+    only when the queues are drained; under sustained arrivals the
+    remaining ready work may simply not fit the idle workers (reference
+    runs this check periodically on the worker, worker/rpc.rs:322;
+    RetractTasks / on_retract_response, reactor.rs:462)."""
+    idle = [
+        w for w in core.workers.values()
+        if w.is_idle()
+        and not w.mn_reserved
+        and not w.draining
+        and w.worker_id not in per_worker_msgs
+    ]
+    if idle:
+        donors = sorted(
+            (w for w in core.workers.values() if w.prefilled_tasks),
+            key=lambda w: -len(w.prefilled_tasks),
+        )
+        # per-class slot budget over CAPABLE idle workers only:
+        # retracting a class toward slots that cannot host it would
+        # churn the tasks straight back to the donor next tick
+        class_slots: dict[int, int] = {}
+
+        def slots_for(rq_id: int) -> int:
+            slots = class_slots.get(rq_id)
+            if slots is None:
+                rqv = core.rq_map.get_variants(rq_id)
+                slots = sum(
+                    w.nt_free
+                    for w in idle
+                    if w.resources.is_capable_of_rqv(rqv)
+                )
+                class_slots[rq_id] = slots
+            return slots
+
+        for donor in donors:
+            # tasks prefilled THIS tick have their compute message still
+            # queued behind us; a retract would outrun it and no-op
+            # (FIFO), so only settled, not-already-asked tasks qualify —
+            # oldest first, they are at the worker's queue tail risk
+            just_sent = {
+                m["id"] for m in per_worker_msgs.get(donor.worker_id, ())
+            }
+            victims = []
+            budget = len(donor.prefilled_tasks) // 2
+            for tid in sorted(donor.prefilled_tasks):
+                if len(victims) >= budget:
+                    break
+                task = core.tasks[tid]
+                if tid in just_sent or task.retract_pending:
+                    continue
+                if slots_for(task.rq_id) <= 0:
+                    continue
+                class_slots[task.rq_id] -= 1
+                task.retract_pending = True
+                victims.append((tid, task.instance_id))
+            if victims:
+                _RETRACTED_TOTAL.labels("rebalance").inc(len(victims))
+                comm.send_retract(donor.worker_id, victims)
+
+
 def schedule(
     core: Core, comm: Comm, events: EventSink, model, prefill: bool = True
 ) -> int:
@@ -791,14 +1059,55 @@ def schedule(
     mapping -> send). `prefill=False` disables proactive filling (used by
     deterministic scheduler tests).
     """
+    # per-phase latency breakdown of THIS tick (ms): every phase is timed
+    # through TRACER.phase (the span catalog is in docs/observability.md)
+    # and the dict feeds core.tick_stats (`hq server stats`),
+    # hq_tick_phase_seconds and the flight record below
+    phases: dict = {}
+    # the root carries the number this tick takes (`_tick` counts it on)
+    with TRACER.phase(phases, "total", tick=core.tick_counter + 1):
+        assigned, prefilled, record = _tick(
+            core, comm, model, prefill, phases
+        )
+    core.tick_stats.record(phases)
+    if core.policy is not None:
+        # fairness/prediction telemetry: one ledger fold + two dict reads
+        # per tick, surfaced as gauges and through `hq server stats`
+        jain = core.policy.observe_jain()
+        if jain is not None:
+            _POLICY_JAIN.set(jain)
+        if core.policy.predictor is not None:
+            _POLICY_HIT_RATE.set(core.policy.predictor.hit_rate())
+        _POLICY_BOOST_MAX.set(core.policy.last_boost_range[1])
+    _TICKS_TOTAL.inc()
+    if assigned:
+        _ASSIGNED_TOTAL.inc(assigned)
+    if prefilled:
+        _PREFILLED_TOTAL.inc(prefilled)
+    for name, ms in phases.items():
+        _TICK_PHASE_SECONDS.labels(name).observe(ms / 1e3)
+    if record is not None:
+        record["duration_ms"] = round(phases["total"], 4)
+        record["phases"] = {k: round(v, 4) for k, v in phases.items()}
+        core.flight.record_tick(record)
+    pipeline = core.tick_pipeline
+    if pipeline is not None and pipeline.pending is not None:
+        # a solve is in flight: without another event (submit, completion,
+        # worker change) no further tick would run and the pending solve
+        # would never be mapped — ask for one more pass.  The server's
+        # schedule_min_delay throttle paces the follow-up, which doubles as
+        # the window the device has to finish before the readback.
+        comm.ask_for_scheduling()
+    return assigned
+
+
+def _tick(core: Core, comm: Comm, model, prefill: bool, phases: dict):
+    """The phases of one tick, each under its TRACER.phase span; returns
+    (tasks assigned, tasks prefilled, the decision record or None)."""
     assigned = 0
     prefilled = 0
     gang_assigned = 0
     per_worker_msgs: dict[int, list[dict]] = {}
-    # per-phase latency breakdown of THIS tick (ms), recorded into
-    # core.tick_stats at the end and surfaced via `hq server stats`
-    phases: dict = {}
-    _t_tick = _time.perf_counter()
     # one wall-clock stamp per tick: every task assigned this tick shares it
     # (the timeline's resolution is the tick itself)
     now = clock.now()
@@ -819,7 +1128,6 @@ def schedule(
     # unless strictly-higher-priority sn work is still pending, which keeps
     # the reference's priority interleaving (the MILP schedules higher
     # classes first and only blocks lower ones, solver.rs:479-518). ---
-    _t_phase = _time.perf_counter()
     # fused mode (--scheduler greedy-fused): gangs become all-or-nothing
     # column groups INSIDE the dense solve instead of this host phase —
     # but only when the dense snapshot can serve the tick (tick_cache
@@ -831,153 +1139,155 @@ def schedule(
         if not (w.mn_task or w.mn_reserved or w.draining)
     )
     if core.mn_queue and not fused_tick:
-        top_sn = _top_sn_priority(core)
-        remaining_mn = []
-        for task_id in core.mn_queue:
-            task = core.tasks.get(task_id)
-            if task is None or task.is_done:
-                _clear_mn_reservations(core, task_id)
-                continue
-            rqv = core.rq_map.get_variants(task.rq_id)
-            req = rqv.variants[0]
-            n_nodes = req.n_nodes
-            groups: dict[str, list[Worker]] = {}
-            for w in core.workers.values():
-                if w.mn_task or w.mn_reserved not in (0, task_id):
-                    continue
-                if w.draining or not _mn_member_eligible(w, req):
-                    continue
-                groups.setdefault(w.group, []).append(w)
-            chosen: list[Worker] | None = None
-            for members in groups.values():
-                idle = [w for w in members if w.is_idle()]
-                if len(idle) >= n_nodes:
-                    # prefer the workers already drained for this gang so
-                    # other reservations lift as soon as possible
-                    idle.sort(
-                        key=lambda w: (w.mn_reserved != task_id, w.worker_id)
-                    )
-                    chosen = idle[:n_nodes]
-                    break
-            deferred_for_sn = False
-            if (
-                chosen is not None
-                and top_sn is not None
-                and top_sn[0] > task.priority[0]
-                and _sn_runnable_on(core, task.priority[0], chosen)
-            ):
-                # strictly-higher-priority single-node work can use these
-                # workers: it goes first this tick (the reference MILP
-                # blocks the gang the same way, solver.rs:479-518); the
-                # gang retries on what the sn solve leaves idle
-                chosen = None
-                deferred_for_sn = True
-            if chosen is None:
-                remaining_mn.append(task_id)
-                if record_decision:
-                    if deferred_for_sn:
-                        # the gang WAS placeable: the solver deferred it
-                        # behind higher-priority single-node work, which is
-                        # not a group shortfall
-                        reason = decision_mod.REASON_SOLVER_DEFERRED
-                        detail = (
-                            f"{n_nodes} idle same-group workers are "
-                            "available, but strictly-higher-priority "
-                            "single-node work goes first this tick"
-                        )
-                    else:
-                        best = max(groups.values(), key=len, default=None)
-                        n_idle = (
-                            sum(1 for w in best if w.is_idle())
-                            if best else 0
-                        )
-                        reason = decision_mod.REASON_GANG_INCOMPLETE
-                        detail = (
-                            f"needs {n_nodes} idle same-group workers; "
-                            f"largest eligible group has "
-                            f"{len(best) if best else 0} "
-                            f"({n_idle} idle)"
-                        )
-                    gang_unplaced.append({
-                        "rq_id": task.rq_id,
-                        "job": task_id_job(task_id),
-                        "task": task_id_task(task_id),
-                        "priority": task.priority[0],
-                        "count": 1,
-                        "reason": reason,
-                        "detail": detail,
-                    })
-                # user-priority comparison only: the scheduler component of
-                # the tuple is -job_id, and an older sn job must not
-                # strictly outrank a same-user-priority gang forever
-                if top_sn is not None and top_sn[0] > task.priority[0]:
-                    # higher-priority sn work outranks this gang; do not
-                    # hold workers hostage for it yet
+        with TRACER.phase(phases, "gangs"):
+            top_sn = _top_sn_priority(core)
+            remaining_mn = []
+            for task_id in core.mn_queue:
+                task = core.tasks.get(task_id)
+                if task is None or task.is_done:
                     _clear_mn_reservations(core, task_id)
                     continue
-                # reserve (and start draining) n_nodes eligible workers in
-                # the group closest to satisfying the gang
-                best = max(groups.values(), key=len, default=None)
-                if best is None or len(best) < n_nodes:
-                    # no group can currently host the gang at all; release
-                    # any stale reservations rather than wedging workers
-                    _clear_mn_reservations(core, task_id)
-                    continue
-                best.sort(
-                    key=lambda w: (
-                        not w.is_idle(),
-                        len(w.assigned_tasks) + len(w.prefilled_tasks),
-                        w.worker_id,
-                    )
-                )
-                target = {w.worker_id for w in best[:n_nodes]}
+                rqv = core.rq_map.get_variants(task.rq_id)
+                req = rqv.variants[0]
+                n_nodes = req.n_nodes
+                groups: dict[str, list[Worker]] = {}
                 for w in core.workers.values():
-                    if w.mn_reserved == task_id and w.worker_id not in target:
-                        w.mn_reserved = 0
-                        core.bump_membership()
-                for w in best[:n_nodes]:
-                    newly_reserved = w.mn_reserved != task_id
-                    if newly_reserved:
-                        core.bump_membership()
-                    w.mn_reserved = task_id
-                    if newly_reserved and w.prefilled_tasks:
-                        # steal the queued backlog back so the drain is
-                        # bounded by the currently-running tasks only (sent
-                        # once per reservation, not per tick); mark pending
-                        # or on_retract_response drops the answers
-                        refs = []
-                        for tid in sorted(w.prefilled_tasks):
-                            victim = core.tasks[tid]
-                            if victim.retract_pending:
-                                continue  # an earlier retract already covers it
-                            victim.retract_pending = True
-                            refs.append((tid, victim.instance_id))
-                        if refs:
-                            _RETRACTED_TOTAL.labels("gang-drain").inc(
-                                len(refs)
+                    if w.mn_task or w.mn_reserved not in (0, task_id):
+                        continue
+                    if w.draining or not _mn_member_eligible(w, req):
+                        continue
+                    groups.setdefault(w.group, []).append(w)
+                chosen: list[Worker] | None = None
+                for members in groups.values():
+                    idle = [w for w in members if w.is_idle()]
+                    if len(idle) >= n_nodes:
+                        # prefer the workers already drained for this gang so
+                        # other reservations lift as soon as possible
+                        idle.sort(key=lambda w: (
+                            w.mn_reserved != task_id, w.worker_id
+                        ))
+                        chosen = idle[:n_nodes]
+                        break
+                deferred_for_sn = False
+                if (
+                    chosen is not None
+                    and top_sn is not None
+                    and top_sn[0] > task.priority[0]
+                    and _sn_runnable_on(core, task.priority[0], chosen)
+                ):
+                    # strictly-higher-priority single-node work can use these
+                    # workers: it goes first this tick (the reference MILP
+                    # blocks the gang the same way, solver.rs:479-518); the
+                    # gang retries on what the sn solve leaves idle
+                    chosen = None
+                    deferred_for_sn = True
+                if chosen is None:
+                    remaining_mn.append(task_id)
+                    if record_decision:
+                        if deferred_for_sn:
+                            # the gang WAS placeable: the solver deferred it
+                            # behind higher-priority single-node work, which is
+                            # not a group shortfall
+                            reason = decision_mod.REASON_SOLVER_DEFERRED
+                            detail = (
+                                f"{n_nodes} idle same-group workers are "
+                                "available, but strictly-higher-priority "
+                                "single-node work goes first this tick"
                             )
-                            comm.send_retract(w.worker_id, refs)
-                continue
-            _clear_mn_reservations(core, task_id)
-            core.bump_membership()
-            for w in chosen:
-                w.mn_task = task_id
-            task.mn_workers = tuple(w.worker_id for w in chosen)
-            task.state = TaskState.ASSIGNED
-            task.t_assigned = now
-            root = chosen[0]
-            msg = _compute_message(core, task, variant=0)
-            msg["node_ids"] = list(task.mn_workers)
-            msg["node_hostnames"] = [
-                core.workers[wid].configuration.hostname
-                for wid in task.mn_workers
-            ]
-            per_worker_msgs.setdefault(root.worker_id, []).append(msg)
-            assigned += 1
-            gang_assigned += 1
-        core.mn_queue = remaining_mn
-        phases["gangs"] = (_time.perf_counter() - _t_phase) * 1e3
-        TRACER.record("scheduler/gangs", _time.perf_counter() - _t_phase)
+                        else:
+                            best = max(groups.values(), key=len, default=None)
+                            n_idle = (
+                                sum(1 for w in best if w.is_idle())
+                                if best else 0
+                            )
+                            reason = decision_mod.REASON_GANG_INCOMPLETE
+                            detail = (
+                                f"needs {n_nodes} idle same-group workers; "
+                                f"largest eligible group has "
+                                f"{len(best) if best else 0} "
+                                f"({n_idle} idle)"
+                            )
+                        gang_unplaced.append({
+                            "rq_id": task.rq_id,
+                            "job": task_id_job(task_id),
+                            "task": task_id_task(task_id),
+                            "priority": task.priority[0],
+                            "count": 1,
+                            "reason": reason,
+                            "detail": detail,
+                        })
+                    # user-priority comparison only: the scheduler component of
+                    # the tuple is -job_id, and an older sn job must not
+                    # strictly outrank a same-user-priority gang forever
+                    if top_sn is not None and top_sn[0] > task.priority[0]:
+                        # higher-priority sn work outranks this gang; do not
+                        # hold workers hostage for it yet
+                        _clear_mn_reservations(core, task_id)
+                        continue
+                    # reserve (and start draining) n_nodes eligible workers in
+                    # the group closest to satisfying the gang
+                    best = max(groups.values(), key=len, default=None)
+                    if best is None or len(best) < n_nodes:
+                        # no group can currently host the gang at all; release
+                        # any stale reservations rather than wedging workers
+                        _clear_mn_reservations(core, task_id)
+                        continue
+                    best.sort(
+                        key=lambda w: (
+                            not w.is_idle(),
+                            len(w.assigned_tasks) + len(w.prefilled_tasks),
+                            w.worker_id,
+                        )
+                    )
+                    target = {w.worker_id for w in best[:n_nodes]}
+                    for w in core.workers.values():
+                        if (
+                            w.mn_reserved == task_id
+                            and w.worker_id not in target
+                        ):
+                            w.mn_reserved = 0
+                            core.bump_membership()
+                    for w in best[:n_nodes]:
+                        newly_reserved = w.mn_reserved != task_id
+                        if newly_reserved:
+                            core.bump_membership()
+                        w.mn_reserved = task_id
+                        if newly_reserved and w.prefilled_tasks:
+                            # steal the queued backlog back so the drain is
+                            # bounded by the currently-running tasks only (sent
+                            # once per reservation, not per tick); mark pending
+                            # or on_retract_response drops the answers
+                            refs = []
+                            for tid in sorted(w.prefilled_tasks):
+                                victim = core.tasks[tid]
+                                if victim.retract_pending:
+                                    continue  # an earlier retract covers it
+                                victim.retract_pending = True
+                                refs.append((tid, victim.instance_id))
+                            if refs:
+                                _RETRACTED_TOTAL.labels("gang-drain").inc(
+                                    len(refs)
+                                )
+                                comm.send_retract(w.worker_id, refs)
+                    continue
+                _clear_mn_reservations(core, task_id)
+                core.bump_membership()
+                for w in chosen:
+                    w.mn_task = task_id
+                task.mn_workers = tuple(w.worker_id for w in chosen)
+                task.state = TaskState.ASSIGNED
+                task.t_assigned = now
+                root = chosen[0]
+                msg = _compute_message(core, task, variant=0)
+                msg["node_ids"] = list(task.mn_workers)
+                msg["node_hostnames"] = [
+                    core.workers[wid].configuration.hostname
+                    for wid in task.mn_workers
+                ]
+                per_worker_msgs.setdefault(root.worker_id, []).append(msg)
+                assigned += 1
+                gang_assigned += 1
+            core.mn_queue = remaining_mn
 
     # --- fused gangs: the head of the mn queue rides the dense solve as
     # all-or-nothing gang rows (scheduler/tick.py Batch.gang_nodes; kernel
@@ -986,26 +1296,26 @@ def schedule(
     # pipelined solve simply drops its gang and the next tick retries. ---
     fused_gang_batches: list[Batch] = []
     if fused_tick and core.mn_queue:
-        remaining_mn = []
-        for task_id in core.mn_queue:
-            task = core.tasks.get(task_id)
-            if task is None or task.is_done:
-                _clear_mn_reservations(core, task_id)
-                continue
-            remaining_mn.append(task_id)
-            if len(fused_gang_batches) < MAX_FUSED_GANG_ROWS:
-                # fused mode never reserves: lift any reservation left
-                # over from a host-phase tick so the workers rejoin the
-                # dense row set
-                _clear_mn_reservations(core, task_id)
-                rqv = core.rq_map.get_variants(task.rq_id)
-                fused_gang_batches.append(Batch(
-                    rq_id=task.rq_id, priority=task.priority, size=1,
-                    gang_task=task_id,
-                    gang_nodes=rqv.variants[0].n_nodes,
-                ))
-        core.mn_queue = remaining_mn
-        phases["gangs"] = (_time.perf_counter() - _t_phase) * 1e3
+        with TRACER.phase(phases, "gangs"):
+            remaining_mn = []
+            for task_id in core.mn_queue:
+                task = core.tasks.get(task_id)
+                if task is None or task.is_done:
+                    _clear_mn_reservations(core, task_id)
+                    continue
+                remaining_mn.append(task_id)
+                if len(fused_gang_batches) < MAX_FUSED_GANG_ROWS:
+                    # fused mode never reserves: lift any reservation left
+                    # over from a host-phase tick so the workers rejoin the
+                    # dense row set
+                    _clear_mn_reservations(core, task_id)
+                    rqv = core.rq_map.get_variants(task.rq_id)
+                    fused_gang_batches.append(Batch(
+                        rq_id=task.rq_id, priority=task.priority, size=1,
+                        gang_task=task_id,
+                        gang_nodes=rqv.variants[0].n_nodes,
+                    ))
+            core.mn_queue = remaining_mn
 
     # Soft drain for fused gangs: the kernel holds members WITHIN one
     # solve, but between ticks the prefill phase would keep piling backlog
@@ -1105,7 +1415,6 @@ def schedule(
     snapshot = core.tick_cache.sync(core)
     rows = core.worker_rows() if snapshot is None else None
     leftover_batches = None
-    _t_phase = _time.perf_counter()
     have_workers = (
         bool(snapshot.worker_ids) if snapshot is not None else bool(rows)
     )
@@ -1114,426 +1423,189 @@ def schedule(
     policy_ctx = None
     fairness_placed: tuple | None = None
     if have_workers and (core.queues.total_ready() or run_gangs_fused):
-        _t_batches = _time.perf_counter()
-        batches = create_batches(core.queues)
-        gang_ok = group_ids = None
-        if run_gangs_fused:
-            batches = batches + fused_gang_batches
-            # worker-side gang inputs, aligned to the snapshot rows: host
-            # idleness (prefilled backlog does not show in `free`, so the
-            # kernel cannot derive it) and the worker-group index map
-            gmap: dict[str, int] = {}
-            gang_ok = []
-            group_ids = []
-            for wid in snapshot.worker_ids:
-                w = core.workers[wid]
-                gang_ok.append(1 if w.is_idle() else 0)
-                group_ids.append(gmap.setdefault(w.group, len(gmap)))
-        phases["batches"] = (_time.perf_counter() - _t_batches) * 1e3
-        if core.policy is not None:
-            # weighted objective (--policy-file): resolve this tick's
-            # affinity rows + priority boosts against the tick's worker
-            # order — the dense snapshot's worker_ids when the cache
-            # served, else the row list order (run_tick only reorders
-            # workers on the mu path, which strips the rows itself and
-            # keeps the alignment-free boosts).
-            wids = (
-                snapshot.worker_ids if snapshot is not None
-                else [r.worker_id for r in rows]
-            )
-            policy_ctx = core.policy.tick_context(
-                core.workers, core.rq_map, core.resource_map,
-                wids, batches,
-            )
-        if snapshot is not None and paranoid_now:
-            from hyperqueue_tpu.scheduler.tick_cache import paranoid_check
+        with TRACER.phase(None, "solve"):
+            with TRACER.phase(phases, "batches"):
+                batches = create_batches(core.queues)
+                gang_ok = group_ids = None
+                if run_gangs_fused:
+                    batches = batches + fused_gang_batches
+                    # worker-side gang inputs, aligned to the snapshot rows:
+                    # host idleness (prefilled backlog does not show in `free`,
+                    # so the kernel cannot derive it) and the worker-group
+                    # index map
+                    gmap: dict[str, int] = {}
+                    gang_ok = []
+                    group_ids = []
+                    for wid in snapshot.worker_ids:
+                        w = core.workers[wid]
+                        gang_ok.append(1 if w.is_idle() else 0)
+                        group_ids.append(gmap.setdefault(w.group, len(gmap)))
+            if core.policy is not None:
+                # weighted objective (--policy-file): resolve this tick's
+                # affinity rows + priority boosts against the tick's worker
+                # order — the dense snapshot's worker_ids when the cache
+                # served, else the row list order (run_tick only reorders
+                # workers on the mu path, which strips the rows itself and
+                # keeps the alignment-free boosts).
+                wids = (
+                    snapshot.worker_ids if snapshot is not None
+                    else [r.worker_id for r in rows]
+                )
+                policy_ctx = core.policy.tick_context(
+                    core.workers, core.rq_map, core.resource_map,
+                    wids, batches,
+                )
+            if snapshot is not None and paranoid_now:
+                from hyperqueue_tpu.scheduler.tick_cache import paranoid_check
 
-            paranoid_check(
-                core, snapshot, batches, core.rq_map, core.resource_map,
-                gang_ok=gang_ok, group_ids=group_ids, policy=policy_ctx,
-            )
-        pipeline_this_tick = (
-            pipeline
-            if pipeline is not None and not paranoid_now
-            and snapshot is not None
-            else None
-        )
-        if (
-            pipeline_this_tick is not None
-            and pipeline_this_tick.idle_sig is not None
-            and pipeline_this_tick.idle_sig == (
-                core.membership_epoch, core.queues.version,
-                core.queues.total_ready(),
-            )
-            and core.tick_cache.rows_rewritten_last == 0
-        ):
-            # the last pipelined solve mapped NOTHING and no queue
-            # mutation, membership change or worker-row drift happened
-            # since it was dispatched: a re-solve would see bit-identical
-            # inputs and assign nothing again.  Skip the dispatch — with
-            # no pending solve the end-of-tick self-request stays off, so
-            # an unplaceable backlog costs one extra tick instead of
-            # spinning at the min-delay cadence until the next event.
-            assignments = []
-        else:
-            assignments = run_tick(
-                core.queues, rows, core.rq_map, core.resource_map, model,
-                batches=batches, dense=snapshot, phases=phases,
-                key_cache=core.tick_cache,
-                decision=decision_info if record_decision else None,
-                pipeline=pipeline_this_tick,
-                gang_ok=gang_ok, group_ids=group_ids, policy=policy_ctx,
+                paranoid_check(
+                    core, snapshot, batches, core.rq_map, core.resource_map,
+                    gang_ok=gang_ok, group_ids=group_ids, policy=policy_ctx,
+                )
+            pipeline_this_tick = (
+                pipeline
+                if pipeline is not None and not paranoid_now
+                and snapshot is not None
+                else None
             )
             if (
                 pipeline_this_tick is not None
-                and pipeline_this_tick.pending is not None
-            ):
-                # stamp the solve-input state so an EMPTY mapping next tick
-                # can prove a re-solve redundant (PendingSolve.state_sig)
-                pipeline_this_tick.pending.state_sig = (
+                and pipeline_this_tick.idle_sig is not None
+                and pipeline_this_tick.idle_sig == (
                     core.membership_epoch, core.queues.version,
                     core.queues.total_ready(),
                 )
-        if run_gangs_fused:
-            assignments, n_gangs = _apply_fused_gangs(
-                core, assignments, per_worker_msgs, now
-            )
-            assigned += n_gangs
-            gang_assigned += n_gangs
-        taken_by_batch: dict[tuple[int, Priority_t], int] = {}
-        for task_id, worker_id, rq_id, variant in assignments:
-            task = core.tasks[task_id]
-            worker = core.workers[worker_id]
-            task.state = TaskState.ASSIGNED
-            task.t_assigned = now
-            task.assigned_worker = worker_id
-            task.assigned_variant = variant
-            worker.assign(
-                task_id, core.variant_amounts(rq_id, variant, worker)
-            )
-            per_worker_msgs.setdefault(worker_id, []).append(
-                _compute_message(core, task, variant)
-            )
-            assigned += 1
-            key = (rq_id, task.priority)
-            taken_by_batch[key] = taken_by_batch.get(key, 0) + 1
-        leftover_batches = []
-        for batch in batches:
-            if batch.gang_nodes:
-                continue  # gang rows never feed prefill/displacement
-            batch.size -= taken_by_batch.get(
-                (batch.rq_id, batch.priority), 0
-            )
-            if batch.size > 0:
-                leftover_batches.append(batch)
-        if record_decision:
-            # per-job max b-level among the batches that PLACED work this
-            # tick: a same-job leftover with a shallower critical path was
-            # deliberately held behind deeper work (lookahead-held)
-            placed_blevel = {}
-            for (_rq, prio), _n in taken_by_batch.items():
-                if prio[1] <= -BLEVEL_STRIDE:
-                    j = decode_sched_job(prio[1])
-                    bl = decode_sched_blevel(prio[1])
-                    if bl > placed_blevel.get(j, -1):
-                        placed_blevel[j] = bl
-            if policy_ctx is not None and policy_ctx.boosts:
-                # lowest original priority among placed batches of
-                # fairness/prediction-boosted jobs: a leftover class whose
-                # own priority sits ABOVE it was overtaken by the boost
-                # (decision.build_unplaced_entries fairness-deferred)
-                for (_rq, prio), _n in taken_by_batch.items():
-                    if policy_ctx.boost_for_sched(prio[1]) > 0:
-                        t = tuple(prio)
-                        if fairness_placed is None or t < fairness_placed:
-                            fairness_placed = t
-            if run_gangs_fused:
-                still_waiting = set(core.mn_queue)
-                for gb in fused_gang_batches:
-                    if gb.gang_task not in still_waiting:
-                        continue
-                    per_group: dict[str, int] = {}
-                    for w in core.workers.values():
-                        if w.mn_task or w.draining:
-                            continue
-                        per_group[w.group] = per_group.get(w.group, 0) + 1
-                    feasible = (
-                        max(per_group.values(), default=0) >= gb.gang_nodes
-                    )
-                    reason = (
-                        decision_mod.REASON_GANG_GROUP_DEFERRED
-                        if feasible
-                        else decision_mod.REASON_GANG_INCOMPLETE
-                    )
-                    gang_unplaced.append({
-                        "rq_id": gb.rq_id,
-                        "job": task_id_job(gb.gang_task),
-                        "task": task_id_task(gb.gang_task),
-                        "priority": gb.priority[0],
-                        "count": 1,
-                        "reason": reason,
-                        "detail": (
-                            f"fused solve held {gb.gang_nodes} group "
-                            "members this tick (busy or taken by the "
-                            "scan)" if feasible else
-                            f"no group musters {gb.gang_nodes} eligible "
-                            "members"
-                        ),
-                    })
-        TRACER.record("scheduler/solve", _time.perf_counter() - _t_phase)
-
-    # --- proactive prefilling: push extra top-priority tasks to busy
-    # workers so short tasks pipeline without a server round-trip per task
-    # (reference mapping.rs:159 process_proactive_filling, max 40/worker) ---
-    _t_phase = _time.perf_counter()
-    if prefill and core.queues.total_ready():
-        budgets = {
-            w.worker_id: PREFILL_MAX - len(w.prefilled_tasks)
-            for w in core.workers.values()
-            if not w.mn_task
-            and not w.mn_reserved
-            and not w.draining
-            and w.worker_id not in fused_gang_hold
-            and (w.assigned_tasks or w.prefilled_tasks)
-            and len(w.prefilled_tasks) < PREFILL_MAX
-        }
-        # starvation guard (reference reservation vars, solver.rs:479-518):
-        # each request class with leftover ready tasks reserves ONE capable
-        # worker where strictly-lower-priority tasks may not prefill, so a
-        # big task eventually sees a fully drained worker instead of losing
-        # every race against streams of small tasks.
-        if leftover_batches is None:
-            leftover_batches = create_batches(core.queues)
-        if policy_ctx is not None and policy_ctx.boosts:
-            # the solve's boost-weighted order lives in run_tick's COPY of
-            # the batch list; prefill consumes the caller's list, so fold
-            # the same boost arithmetic here — under deep prefill budgets
-            # this order, not the solve's ~capacity-sized mapping, decides
-            # which job's backlog reaches the workers first
-            leftover_batches.sort(key=lambda b: (
-                b.priority[0],
-                b.priority[1]
-                + policy_ctx.boost_for_sched(b.priority[1]) * BLEVEL_STRIDE,
-            ), reverse=True)
-        reservations: dict[int, Priority_t] = {}
-        for batch in leftover_batches:
-            rqv = core.rq_map.get_variants(batch.rq_id)
-            for w in sorted(core.workers.values(), key=lambda w: w.worker_id):
-                if (
-                    w.mn_task or w.mn_reserved or w.draining
-                    or w.worker_id in reservations
-                ):
-                    continue
-                if w.resources.is_capable_of_rqv(rqv):
-                    reservations[w.worker_id] = batch.priority
-                    break
-        # prefill in GLOBAL priority order (batches are priority-sorted), so
-        # high-priority classes claim worker budgets first; workers are fed
-        # least-backlog-first so a deep budget cannot pile onto one worker
-        # while its peers run dry between refills
-        workers_by_backlog = sorted(
-            core.workers.values(),
-            key=lambda w: (
-                len(w.prefilled_tasks) + len(w.assigned_tasks),
-                w.worker_id,
-            ),
-        )
-        for batch in leftover_batches:
-            queue = core.queues.queue(batch.rq_id)
-            rqv = core.rq_map.get_variants(batch.rq_id)
-            eligible: list[tuple[Worker, int]] = []
-            for worker in workers_by_backlog:
-                if budgets.get(worker.worker_id, 0) <= 0:
-                    continue
-                blocking = reservations.get(worker.worker_id)
-                if blocking is not None and batch.priority < blocking:
-                    continue
-                variant = next(
-                    (
-                        i
-                        for i, v in enumerate(rqv.variants)
-                        if worker.resources.is_capable_of(v)
-                    ),
-                    None,
+                and core.tick_cache.rows_rewritten_last == 0
+            ):
+                # the last pipelined solve mapped NOTHING and no queue
+                # mutation, membership change or worker-row drift happened
+                # since it was dispatched: a re-solve would see bit-identical
+                # inputs and assign nothing again.  Skip the dispatch — with
+                # no pending solve the end-of-tick self-request stays off, so
+                # an unplaceable backlog costs one extra tick instead of
+                # spinning at the min-delay cadence until the next event.
+                assignments = []
+            else:
+                assignments = run_tick(
+                    core.queues, rows, core.rq_map, core.resource_map, model,
+                    batches=batches, dense=snapshot, phases=phases,
+                    key_cache=core.tick_cache,
+                    decision=decision_info if record_decision else None,
+                    pipeline=pipeline_this_tick,
+                    gang_ok=gang_ok, group_ids=group_ids, policy=policy_ctx,
                 )
-                if variant is None:
-                    continue
-                eligible.append((worker, variant))
-            if not eligible:
-                continue
-            # fair-share split across eligible workers (multiple passes so
-            # budget-capped workers' leftovers flow to the others); without
-            # this a deep budget lets the first worker swallow the batch
-            fair = max(-(-batch.size // len(eligible)), 1)
-            progress = True
-            while progress:
-                progress = False
-                for worker, variant in eligible:
-                    budget = budgets.get(worker.worker_id, 0)
-                    if budget <= 0:
-                        continue
-                    taken = queue.take(batch.priority, min(budget, fair))
-                    if not taken:
-                        break
-                    progress = True
-                    batch.size -= len(taken)  # keeps leftover sizes true
-                    for task_id in taken:
-                        task = core.tasks[task_id]
-                        task.state = TaskState.ASSIGNED
-                        task.t_assigned = now
-                        task.assigned_worker = worker.worker_id
-                        task.assigned_variant = variant
-                        task.prefilled = True
-                        prefilled += 1
-                        worker.prefilled_tasks.add(task_id)
-                        budgets[worker.worker_id] -= 1
-                        per_worker_msgs.setdefault(
-                            worker.worker_id, []
-                        ).append(_compute_message(core, task, variant))
-
-    # --- displacement: strictly-higher-user-priority READY work must not
-    # sit in the queues while lower-priority prefilled backlog holds the
-    # workers that could run it.  Retract the lowest-priority settled
-    # victims; once they answer, the next tick prefills in global priority
-    # order (reference redirects the prefilled task on submit,
-    # test_reactor.rs test_prefill_submit_high_priority) ---
-    if prefill and core.queues.total_ready():
-        # per-worker victim lists are built ONCE (ascending priority, with
-        # this tick's sends and in-flight retracts excluded), then consumed
-        # across the batch loop — not rebuilt per (batch x worker).  The
-        # common saturated case (all leftover and backlog at one user
-        # priority) exits on the first victim comparison per worker.
-        victim_lists: dict[int, list] = {}
-        for worker in core.workers.values():
-            if worker.mn_task or worker.mn_reserved:
-                continue
-            if not worker.prefilled_tasks:
-                continue
-            just_sent = {
-                m["id"] for m in per_worker_msgs.get(worker.worker_id, ())
-            }
-            victims = sorted(
-                (
-                    core.tasks[tid]
-                    for tid in worker.prefilled_tasks
-                    if tid not in just_sent
-                    and not core.tasks[tid].retract_pending
-                ),
-                key=lambda t: t.priority,
-            )
-            if victims:
-                victims.reverse()  # pop() consumes lowest-priority first
-                victim_lists[worker.worker_id] = victims
-        if victim_lists:
-            # leftover_batches already carries the post-solve post-prefill
-            # sizes (both phases decrement batch.size) — no third
-            # create_batches walk
-            if leftover_batches is None:
-                leftover_batches = create_batches(core.queues)
-            retract_by_worker: dict[int, list[tuple[int, int]]] = {}
-            # per-worker retract cap: one large leftover batch must not
-            # strip every lower-priority prefilled task from every capable
-            # worker in a single tick (far more than those workers could
-            # run) — that just churns retract/re-prefill under deep
-            # backlogs. Per displacing batch, a worker gives up at most
-            # 2× the batch tasks it could simultaneously RUN (the extra
-            # factor leaves backlog headroom), within a PREFILL_MAX
-            # overall budget.
-            retract_budget = {wid: PREFILL_MAX for wid in victim_lists}
-            for batch in leftover_batches:
-                if batch.size <= 0:
-                    continue
-                rqv = core.rq_map.get_variants(batch.rq_id)
-                need = batch.size
-                for worker_id, victims in victim_lists.items():
-                    if need <= 0:
-                        break
-                    if not victims or retract_budget[worker_id] <= 0:
-                        continue
-                    worker = core.workers[worker_id]
-                    if not worker.resources.is_capable_of_rqv(rqv):
-                        continue
-                    allowance = min(
-                        retract_budget[worker_id],
-                        2 * _rqv_fit_count(worker.resources, rqv),
+                if (
+                    pipeline_this_tick is not None
+                    and pipeline_this_tick.pending is not None
+                ):
+                    # stamp the solve-input state so an EMPTY mapping next tick
+                    # can prove a re-solve redundant (PendingSolve.state_sig)
+                    pipeline_this_tick.pending.state_sig = (
+                        core.membership_epoch, core.queues.version,
+                        core.queues.total_ready(),
                     )
-                    while victims and need > 0 and allowance > 0:
-                        if victims[-1].priority[0] >= batch.priority[0]:
-                            break  # ascending: nothing lower remains
-                        victim = victims.pop()
-                        victim.retract_pending = True
-                        retract_by_worker.setdefault(
-                            worker_id, []
-                        ).append((victim.task_id, victim.instance_id))
-                        need -= 1
-                        allowance -= 1
-                        retract_budget[worker_id] -= 1
-            for wid, refs in retract_by_worker.items():
-                _RETRACTED_TOTAL.labels("displacement").inc(len(refs))
-                comm.send_retract(wid, refs)
+            if run_gangs_fused:
+                assignments, n_gangs = _apply_fused_gangs(
+                    core, assignments, per_worker_msgs, now
+                )
+                assigned += n_gangs
+                gang_assigned += n_gangs
+            taken_by_batch: dict[tuple[int, Priority_t], int] = {}
+            for task_id, worker_id, rq_id, variant in assignments:
+                task = core.tasks[task_id]
+                worker = core.workers[worker_id]
+                task.state = TaskState.ASSIGNED
+                task.t_assigned = now
+                task.assigned_worker = worker_id
+                task.assigned_variant = variant
+                worker.assign(
+                    task_id, core.variant_amounts(rq_id, variant, worker)
+                )
+                per_worker_msgs.setdefault(worker_id, []).append(
+                    _compute_message(core, task, variant)
+                )
+                assigned += 1
+                key = (rq_id, task.priority)
+                taken_by_batch[key] = taken_by_batch.get(key, 0) + 1
+            leftover_batches = []
+            for batch in batches:
+                if batch.gang_nodes:
+                    continue  # gang rows never feed prefill/displacement
+                batch.size -= taken_by_batch.get(
+                    (batch.rq_id, batch.priority), 0
+                )
+                if batch.size > 0:
+                    leftover_batches.append(batch)
+            if record_decision:
+                # per-job max b-level among the batches that PLACED work this
+                # tick: a same-job leftover with a shallower critical path was
+                # deliberately held behind deeper work (lookahead-held)
+                placed_blevel = {}
+                for (_rq, prio), _n in taken_by_batch.items():
+                    if prio[1] <= -BLEVEL_STRIDE:
+                        j = decode_sched_job(prio[1])
+                        bl = decode_sched_blevel(prio[1])
+                        if bl > placed_blevel.get(j, -1):
+                            placed_blevel[j] = bl
+                if policy_ctx is not None and policy_ctx.boosts:
+                    # lowest original priority among placed batches of
+                    # fairness/prediction-boosted jobs: a leftover class whose
+                    # own priority sits ABOVE it was overtaken by the boost
+                    # (decision.build_unplaced_entries fairness-deferred)
+                    for (_rq, prio), _n in taken_by_batch.items():
+                        if policy_ctx.boost_for_sched(prio[1]) > 0:
+                            t = tuple(prio)
+                            if fairness_placed is None or t < fairness_placed:
+                                fairness_placed = t
+                if run_gangs_fused:
+                    still_waiting = set(core.mn_queue)
+                    for gb in fused_gang_batches:
+                        if gb.gang_task not in still_waiting:
+                            continue
+                        per_group: dict[str, int] = {}
+                        for w in core.workers.values():
+                            if w.mn_task or w.draining:
+                                continue
+                            per_group[w.group] = per_group.get(w.group, 0) + 1
+                        feasible = (
+                            max(per_group.values(), default=0) >= gb.gang_nodes
+                        )
+                        reason = (
+                            decision_mod.REASON_GANG_GROUP_DEFERRED
+                            if feasible
+                            else decision_mod.REASON_GANG_INCOMPLETE
+                        )
+                        gang_unplaced.append({
+                            "rq_id": gb.rq_id,
+                            "job": task_id_job(gb.gang_task),
+                            "task": task_id_task(gb.gang_task),
+                            "priority": gb.priority[0],
+                            "count": 1,
+                            "reason": reason,
+                            "detail": (
+                                f"fused solve held {gb.gang_nodes} group "
+                                "members this tick (busy or taken by the "
+                                "scan)" if feasible else
+                                f"no group musters {gb.gang_nodes} eligible "
+                                "members"
+                            ),
+                        })
 
-    # --- retract: steal prefilled backlog back from loaded workers
-    # whenever idle capacity appears that the backlog could use — not only
-    # when the queues are drained; under sustained arrivals the remaining
-    # ready work may simply not fit the idle workers (reference runs this
-    # check periodically on the worker, worker/rpc.rs:322; RetractTasks /
-    # on_retract_response, reactor.rs:462) ---
+    # --- prefill: three passes over the workers' backlog, each under its
+    # own span (fill, displacement, rebalance: the functions above) ---
     if prefill:
-        idle = [
-            w for w in core.workers.values()
-            if w.is_idle()
-            and not w.mn_reserved
-            and not w.draining
-            and w.worker_id not in per_worker_msgs
-        ]
-        if idle:
-            donors = sorted(
-                (w for w in core.workers.values() if w.prefilled_tasks),
-                key=lambda w: -len(w.prefilled_tasks),
-            )
-            # per-class slot budget over CAPABLE idle workers only:
-            # retracting a class toward slots that cannot host it would
-            # churn the tasks straight back to the donor next tick
-            class_slots: dict[int, int] = {}
-
-            def slots_for(rq_id: int) -> int:
-                slots = class_slots.get(rq_id)
-                if slots is None:
-                    rqv = core.rq_map.get_variants(rq_id)
-                    slots = sum(
-                        w.nt_free
-                        for w in idle
-                        if w.resources.is_capable_of_rqv(rqv)
-                    )
-                    class_slots[rq_id] = slots
-                return slots
-
-            for donor in donors:
-                # tasks prefilled THIS tick have their compute message still
-                # queued behind us; a retract would outrun it and no-op
-                # (FIFO), so only settled, not-already-asked tasks qualify —
-                # oldest first, they are at the worker's queue tail risk
-                just_sent = {
-                    m["id"] for m in per_worker_msgs.get(donor.worker_id, ())
-                }
-                victims = []
-                budget = len(donor.prefilled_tasks) // 2
-                for tid in sorted(donor.prefilled_tasks):
-                    if len(victims) >= budget:
-                        break
-                    task = core.tasks[tid]
-                    if tid in just_sent or task.retract_pending:
-                        continue
-                    if slots_for(task.rq_id) <= 0:
-                        continue
-                    class_slots[task.rq_id] -= 1
-                    task.retract_pending = True
-                    victims.append((tid, task.instance_id))
-                if victims:
-                    _RETRACTED_TOTAL.labels("rebalance").inc(len(victims))
-                    comm.send_retract(donor.worker_id, victims)
-        phases["prefill"] = (_time.perf_counter() - _t_phase) * 1e3
-        TRACER.record("scheduler/prefill", _time.perf_counter() - _t_phase)
+        with TRACER.phase(phases, "prefill"):
+            with TRACER.phase(phases, "prefill/fill"):
+                prefilled, leftover_batches = _prefill_fill(
+                    core, now, per_worker_msgs, leftover_batches,
+                    policy_ctx, fused_gang_hold,
+                )
+            with TRACER.phase(phases, "prefill/displace"):
+                leftover_batches = _prefill_displace(
+                    core, comm, per_worker_msgs, leftover_batches
+                )
+            with TRACER.phase(phases, "prefill/rebalance"):
+                _prefill_rebalance(core, comm, per_worker_msgs)
 
     for worker_id, msgs in per_worker_msgs.items():
         comm.send_compute(worker_id, msgs)
@@ -1545,87 +1617,57 @@ def schedule(
     # same place the <=5% budget is enforced. ---
     record = None
     if record_decision:
-        _t_phase = _time.perf_counter()
-        try:
-            # tick-local: only a solve that actually ran THIS tick can mark
-            # it degraded (a stale flag from a previous tick must not leak)
-            solver = decision_info.get("solver") or {"status": "idle"}
-            degraded = solver["status"] in ("fallback", "skipped")
-            unplaced = list(gang_unplaced)
-            ready_left = core.queues.total_ready()
-            if ready_left:
-                if leftover_batches is None:
-                    leftover_batches = create_batches(core.queues)
-                unplaced.extend(decision_mod.build_unplaced_entries(
-                    core, leftover_batches, {}, degraded=degraded,
-                    placed_blevel=placed_blevel,
-                    fairness_placed=fairness_placed,
-                ))
-            n_paused = 0
-            for job_id, held in core.paused_held.items():
-                if held:
-                    n_paused += len(held)
-                    unplaced.append({
-                        "rq_id": None, "job": job_id, "priority": None,
-                        "count": len(held),
-                        "reason": decision_mod.REASON_QUEUE_PAUSED,
-                    })
-            record = {
-                "tick": core.tick_counter,
-                "time": now,
-                "solver": solver,
-                "counts": {
-                    "workers": len(core.workers),
-                    "assigned": assigned - gang_assigned,
-                    "gang_assigned": gang_assigned,
-                    "prefilled": prefilled,
-                    "unplaced": sum(
-                        e["count"] for e in unplaced
-                        if e["reason"] != decision_mod.REASON_QUEUE_PAUSED
-                    ),
-                    "paused": n_paused,
-                    "ready_left": ready_left,
-                    "mn_waiting": len(core.mn_queue),
-                },
-                "unplaced": unplaced,
-            }
-        except Exception:  # noqa: BLE001 - explainability must never
-            # take the scheduling loop down with it
-            logger.exception("decision-record assembly failed; tick %d "
-                             "goes unrecorded", core.tick_counter)
-            record = None
-        phases["decide"] = (_time.perf_counter() - _t_phase) * 1e3
+        with TRACER.phase(phases, "decide"):
+            try:
+                # tick-local: only a solve that actually ran THIS tick can mark
+                # it degraded (a stale flag from a previous tick must not leak)
+                solver = decision_info.get("solver") or {"status": "idle"}
+                degraded = solver["status"] in ("fallback", "skipped")
+                unplaced = list(gang_unplaced)
+                ready_left = core.queues.total_ready()
+                if ready_left:
+                    if leftover_batches is None:
+                        leftover_batches = create_batches(core.queues)
+                    unplaced.extend(decision_mod.build_unplaced_entries(
+                        core, leftover_batches, {}, degraded=degraded,
+                        placed_blevel=placed_blevel,
+                        fairness_placed=fairness_placed,
+                    ))
+                n_paused = 0
+                for job_id, held in core.paused_held.items():
+                    if held:
+                        n_paused += len(held)
+                        unplaced.append({
+                            "rq_id": None, "job": job_id, "priority": None,
+                            "count": len(held),
+                            "reason": decision_mod.REASON_QUEUE_PAUSED,
+                        })
+                record = {
+                    "tick": core.tick_counter,
+                    "time": now,
+                    "solver": solver,
+                    "counts": {
+                        "workers": len(core.workers),
+                        "assigned": assigned - gang_assigned,
+                        "gang_assigned": gang_assigned,
+                        "prefilled": prefilled,
+                        "unplaced": sum(
+                            e["count"] for e in unplaced
+                            if e["reason"] != decision_mod.REASON_QUEUE_PAUSED
+                        ),
+                        "paused": n_paused,
+                        "ready_left": ready_left,
+                        "mn_waiting": len(core.mn_queue),
+                    },
+                    "unplaced": unplaced,
+                }
+            except Exception:  # noqa: BLE001 - explainability must never
+                # take the scheduling loop down with it
+                logger.exception("decision-record assembly failed; tick %d "
+                                 "goes unrecorded", core.tick_counter)
+                record = None
 
-    phases["total"] = (_time.perf_counter() - _t_tick) * 1e3
-    core.tick_stats.record(phases)
-    if core.policy is not None:
-        # fairness/prediction telemetry: one ledger fold + two dict reads
-        # per tick, surfaced as gauges and through `hq server stats`
-        jain = core.policy.observe_jain()
-        if jain is not None:
-            _POLICY_JAIN.set(jain)
-        if core.policy.predictor is not None:
-            _POLICY_HIT_RATE.set(core.policy.predictor.hit_rate())
-        _POLICY_BOOST_MAX.set(core.policy.last_boost_range[1])
-    _TICKS_TOTAL.inc()
-    if assigned:
-        _ASSIGNED_TOTAL.inc(assigned)
-    if prefilled:
-        _PREFILLED_TOTAL.inc(prefilled)
-    for name, ms in phases.items():
-        _TICK_PHASE_SECONDS.labels(name).observe(ms / 1e3)
-    if record is not None:
-        record["duration_ms"] = round(phases["total"], 4)
-        record["phases"] = {k: round(v, 4) for k, v in phases.items()}
-        core.flight.record_tick(record)
-    if pipeline is not None and pipeline.pending is not None:
-        # a solve is in flight: without another event (submit, completion,
-        # worker change) no further tick would run and the pending solve
-        # would never be mapped — ask for one more pass.  The server's
-        # schedule_min_delay throttle paces the follow-up, which doubles as
-        # the window the device has to finish before the readback.
-        comm.ask_for_scheduling()
-    return assigned
+    return assigned, prefilled, record
 
 
 def on_retract_response(

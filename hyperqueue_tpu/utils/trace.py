@@ -9,15 +9,20 @@ spans in-process, logs each span at DEBUG like the reference's events, and
 surfaces the aggregate through `hq server debug-dump` — enough to see
 which tick phase (gangs, solve, mapping, prefill) is hot without attaching
 a profiler.
+
+`TRACER.phase` is the one primitive the scheduling tick's phases are timed
+with: it knows every sink of a tick phase (the caller's `phases` dict,
+`hq_span_seconds`, the profiler's trace), so a phase is named once, where
+its work happens (span catalog: docs/observability.md).
 """
 
 from __future__ import annotations
 
 import logging
 import secrets
+import sys
 import time
 from collections import OrderedDict, deque
-from contextlib import contextmanager
 from dataclasses import dataclass, field
 
 from hyperqueue_tpu.utils.metrics import REGISTRY
@@ -49,14 +54,70 @@ class SpanStats:
         self.last_s = dt
 
 
+# tick phases that were TRACER spans before they were phases keep their
+# documented `hq_span_seconds` / debug-dump names (by trace name)
+_SPAN_OF_PHASE = {
+    "hq/tick": "scheduler/tick",
+    "hq/tick/gangs": "scheduler/gangs",
+    "hq/tick/solve": "scheduler/solve",
+    "hq/tick/prefill": "scheduler/prefill",
+}
+
+
+def _annotation(name: str, metadata: dict):
+    """A `jax.profiler.TraceAnnotation` (inert unless a profiler session
+    runs), or None in a process that has not imported JAX: a worker, a
+    client or a `--scheduler cpu` server must not load it for a span."""
+    cls = getattr(sys.modules.get("jax.profiler"), "TraceAnnotation", None)
+    return None if cls is None else cls(name, **metadata)
+
+
+class _Phase:
+    """One timed phase (`Tracer.phase`); `seconds` holds after exit."""
+
+    __slots__ = ("_tracer", "_phases", "_key", "_name", "_done",
+                 "_annotation", "_t0", "seconds")
+
+    def __init__(self, tracer, phases, key, name, done, metadata):
+        self._tracer = tracer
+        self._phases = phases
+        self._key = key
+        self._name = name
+        self._done = done
+        self._annotation = _annotation(name, metadata)
+        self.seconds = 0.0
+
+    def __enter__(self):
+        if self._annotation is not None:
+            self._annotation.__enter__()
+        # perf_counter, never utils/clock: that one is virtual under the
+        # simulator, and these readings stay out of journals and digests
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        self.seconds = dt = time.perf_counter() - self._t0
+        if self._annotation is not None:
+            self._annotation.__exit__(*exc)
+        phases = self._phases
+        if phases is not None:
+            phases[self._key] = phases.get(self._key, 0.0) + dt * 1e3
+        span = _SPAN_OF_PHASE.get(self._name)
+        if span is not None:
+            self._tracer.record(span, dt)
+        if self._done is not None:
+            self._done(self._key, dt)
+        return False
+
+
 @dataclass
 class Tracer:
     stats: dict[str, SpanStats] = field(default_factory=dict)
     recent: deque = field(default_factory=lambda: deque(maxlen=256))
 
     def record(self, name: str, dt: float) -> None:
-        """Record a measured duration directly (the `span` context manager
-        for blocks that a `with` would force to re-indent)."""
+        """Record a measured duration under a span name (`phase` does, for
+        the tick phases that have one)."""
         entry = self.stats.get(name)
         if entry is None:
             entry = self.stats[name] = SpanStats()
@@ -66,13 +127,22 @@ class Tracer:
         if logger.isEnabledFor(logging.DEBUG):
             logger.debug("span %s: %.3f ms", name, dt * 1000)
 
-    @contextmanager
-    def span(self, name: str):
-        t0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            self.record(name, time.perf_counter() - t0)
+    def phase(self, phases: dict | None, key: str, root: str = "hq/tick",
+              done=None, **metadata) -> _Phase:
+        """Time one phase: `with TRACER.phase(phases, "prefill/fill"): ...`.
+
+        On exit the milliseconds are added to `phases[key]` (None: no
+        dict) — `Core.tick_stats`, `hq_tick_phase_seconds` and the flight
+        record follow from the tick's dict; a phase that has a
+        `scheduler/<name>` span feeds `record`; `done(key, seconds)` is
+        called if given (the server's planes: LagTracker and the stall
+        watchdog).  Between enter and exit the block lies under
+        `<root>/<key>` (`<root>` itself for the tick's `total`) in a
+        profiler trace, on the device operations' clock, with `metadata`
+        as the event's stats.  A nested key (`a/b`) is opened inside its
+        parent's block."""
+        name = root if key == "total" else f"{root}/{key}"
+        return _Phase(self, phases, key, name, done, metadata)
 
     def snapshot(self, recent: int = 16) -> dict:
         """JSON-ready per-span statistics (+ the most recent spans, for
@@ -102,7 +172,6 @@ class Tracer:
 
 # process-wide tracer (one server or worker per process)
 TRACER = Tracer()
-span = TRACER.span
 
 
 # ----------------------------------------------------------------------
