@@ -9,6 +9,7 @@ offload possible; nothing here holds locks.
 
 from __future__ import annotations
 
+import math
 from dataclasses import dataclass, field
 
 from hyperqueue_tpu.ids import IdCounter
@@ -183,6 +184,7 @@ class Core:
             for task_id in worker.assigned_tasks:
                 task = self.tasks.get(task_id)
                 assert task is not None and task.assigned_worker == worker.worker_id
+            levels: dict[int, int] = {}
             for task_id in worker.prefilled_tasks:
                 task = self.tasks.get(task_id)
                 assert (
@@ -190,3 +192,11 @@ class Core:
                     and task.prefilled
                     and task.assigned_worker == worker.worker_id
                 ), task_id
+                level = task.priority[0]
+                levels[level] = levels.get(level, 0) + 1
+            # the per-level view the displacement pass trusts is a recount
+            held = worker.prefilled_tasks
+            assert (
+                held.level_counts() == levels
+                and held.lowest == min(levels, default=math.inf)
+            ), (worker.worker_id, held, levels)

@@ -10,6 +10,7 @@ batches via an "ask_for_scheduling" flag + wakeup, never reentrantly
 from __future__ import annotations
 
 import logging
+import math
 from typing import Protocol
 
 from hyperqueue_tpu.ids import task_id_job, task_id_task
@@ -59,6 +60,14 @@ _RETRACTED_TOTAL = REGISTRY.counter(
     "hq_scheduler_retracts_total",
     "prefilled tasks asked back from workers",
     labels=("reason",),
+)
+_DISPLACE_WORKERS = REGISTRY.counter(
+    "hq_prefill_displace_workers_total",
+    "workers per tick of the prefill displacement pass, while ready work "
+    "is queued: scanned = its prefilled tasks were read for victims, "
+    "skipped = passed over without reading a task (nothing prefilled "
+    "there lies below the highest queued user priority)",
+    labels=("outcome",),
 )
 _SOLVE_GANG_GROUPS = REGISTRY.counter(
     "hq_solve_gang_groups",
@@ -514,7 +523,7 @@ def on_task_running(
             # the prefilled task actually started: account its resources now
             worker = core.workers.get(task.assigned_worker)
             if worker is not None:
-                worker.prefilled_tasks.discard(task_id)
+                worker.prefilled_tasks.discard(task_id, task.priority[0])
                 worker.assign(
                     task_id,
                     core.variant_amounts(
@@ -645,7 +654,9 @@ def _release_task_resources(core: Core, task: Task) -> None:
     worker = core.workers.get(task.assigned_worker)
     if worker is not None:
         if task.prefilled:
-            worker.prefilled_tasks.discard(task.task_id)
+            worker.prefilled_tasks.discard(
+                task.task_id, task.priority[0]
+            )
             task.prefilled = False
             task.retract_pending = False
         elif task.task_id in worker.assigned_tasks:
@@ -891,7 +902,7 @@ def _prefill_fill(core: Core, now: float, per_worker_msgs: dict,
                     task.assigned_variant = variant
                     task.prefilled = True
                     prefilled += 1
-                    worker.prefilled_tasks.add(task_id)
+                    worker.prefilled_tasks.add(task_id, task.priority[0])
                     budgets[worker.worker_id] -= 1
                     per_worker_msgs.setdefault(
                         worker.worker_id, []
@@ -910,38 +921,53 @@ def _prefill_displace(core: Core, comm: Comm, per_worker_msgs: dict,
     leftover batches."""
     if not core.queues.total_ready():
         return leftover_batches
+    # leftover_batches already carries the post-solve post-prefill sizes
+    # (both phases decrement batch.size) — no third create_batches walk
+    if leftover_batches is None:
+        leftover_batches = create_batches(core.queues)
+    # the gate: only a worker holding something BELOW the highest user
+    # priority still queued can lose a task (the loop below breaks on the
+    # first victim at or above the batch's level), and each worker knows
+    # its lowest prefilled level without looking at a task.  Everyone
+    # else is passed over for one compare; under one priority level that
+    # is every worker, every tick.
+    top = max(
+        (b.priority[0] for b in leftover_batches if b.size > 0),
+        default=-math.inf,
+    )
     # per-worker victim lists are built ONCE (ascending priority, with
     # this tick's sends and in-flight retracts excluded), then consumed
-    # across the batch loop — not rebuilt per (batch x worker).  The
-    # common saturated case (all leftover and backlog at one user
-    # priority) exits on the first victim comparison per worker.
+    # across the batch loop — not rebuilt per (batch x worker).  Only
+    # tasks below `top` go in: the rest are the ones the break never
+    # passes, and leaving them out of a stable sort keeps the others'
+    # order.
     victim_lists: dict[int, list] = {}
-    for worker in core.workers.values():
+    scanned = 0
+    for worker in [
+        w for w in core.workers.values() if w.prefilled_tasks.lowest < top
+    ]:
         if worker.mn_task or worker.mn_reserved:
             continue
-        if not worker.prefilled_tasks:
-            continue
+        scanned += 1
         just_sent = {
             m["id"] for m in per_worker_msgs.get(worker.worker_id, ())
         }
         victims = sorted(
             (
-                core.tasks[tid]
+                task
                 for tid in worker.prefilled_tasks
                 if tid not in just_sent
-                and not core.tasks[tid].retract_pending
+                and (task := core.tasks[tid]).priority[0] < top
+                and not task.retract_pending
             ),
             key=lambda t: t.priority,
         )
         if victims:
             victims.reverse()  # pop() consumes lowest-priority first
             victim_lists[worker.worker_id] = victims
+    _DISPLACE_WORKERS.labels("scanned").inc(scanned)
+    _DISPLACE_WORKERS.labels("skipped").inc(len(core.workers) - scanned)
     if victim_lists:
-        # leftover_batches already carries the post-solve post-prefill
-        # sizes (both phases decrement batch.size) — no third
-        # create_batches walk
-        if leftover_batches is None:
-            leftover_batches = create_batches(core.queues)
         retract_by_worker: dict[int, list[tuple[int, int]]] = {}
         # per-worker retract cap: one large leftover batch must not
         # strip every lower-priority prefilled task from every capable
@@ -1694,7 +1720,7 @@ def on_retract_response(
         return  # it started racing; task_running accounting takes over
     worker = core.workers.get(task.assigned_worker)
     if worker is not None:
-        worker.prefilled_tasks.discard(task_id)
+        worker.prefilled_tasks.discard(task_id, task.priority[0])
     task.prefilled = False
     task.assigned_worker = 0
     task.increment_instance()

@@ -8,6 +8,7 @@ the WorkerRow the tick snapshot copies out (scheduler/tick.py).
 
 from __future__ import annotations
 
+import math
 from dataclasses import dataclass, field
 
 from hyperqueue_tpu.utils.constants import INF_TIME
@@ -95,6 +96,56 @@ class WorkerConfiguration:
         )
 
 
+class PrefilledTasks(set):
+    """The ids of the tasks prefilled on one worker, with how many of them
+    sit at each user-priority level (`task.priority[0]`, fixed at submit).
+
+    A set to every reader; `add` and `discard` are the only ways in and out
+    and take the task's level, so the per-level count cannot drift from the
+    ids (the set's other mutators refuse).  `lowest` is the lowest level
+    held, above every priority when nothing is held: the displacement pass
+    (reactor._prefill_displace) compares it before it looks at any task.
+    Core.sanity_check recounts."""
+
+    __slots__ = ("_levels", "lowest")
+
+    def __init__(self) -> None:
+        super().__init__()
+        self._levels: dict[int, int] = {}
+        self.lowest: float = math.inf
+
+    def add(self, task_id: int, level: int) -> None:
+        if task_id in self:
+            return
+        set.add(self, task_id)
+        self._levels[level] = self._levels.get(level, 0) + 1
+        if level < self.lowest:
+            self.lowest = level
+
+    def discard(self, task_id: int, level: int) -> None:
+        if task_id not in self:
+            return
+        set.discard(self, task_id)
+        left = self._levels[level] - 1
+        if left:
+            self._levels[level] = left
+        else:
+            del self._levels[level]
+            if level == self.lowest:
+                self.lowest = min(self._levels, default=math.inf)
+
+    def level_counts(self) -> dict[int, int]:
+        return dict(self._levels)
+
+    def _refuse(self, *args, **kwargs):
+        raise TypeError("PrefilledTasks changes through add and discard only")
+
+    update = remove = pop = clear = _refuse
+    difference_update = intersection_update = _refuse
+    symmetric_difference_update = _refuse
+    __ior__ = __iand__ = __isub__ = __ixor__ = _refuse
+
+
 @dataclass
 class Worker:
     worker_id: int
@@ -108,7 +159,7 @@ class Worker:
     assigned_tasks: set[int] = field(default_factory=set)
     # tasks pushed beyond current capacity (queue on the worker; no resource
     # accounting until they report running)
-    prefilled_tasks: set[int] = field(default_factory=set)
+    prefilled_tasks: PrefilledTasks = field(default_factory=PrefilledTasks)
     # multi-node: task id this worker is running a gang for (0 = none)
     mn_task: int = 0
     # multi-node: pending gang task this worker is DRAINING for (0 = none).

@@ -522,6 +522,9 @@ class Simulation:
         tick_shares = {}
         if server is not None:
             self._collect_decisions(server)
+            # the core's own invariant walk, per-worker prefill index
+            # included, on the state every fault of the run left behind
+            server.core.sanity_check()
             if server.core.policy is not None:
                 policy_stats = server.core.policy.stats()
             try:

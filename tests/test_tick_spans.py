@@ -17,7 +17,7 @@ from hyperqueue_tpu.scheduler.tick import create_batches, run_tick
 from hyperqueue_tpu.scheduler.tick_cache import TickPhaseStats
 from hyperqueue_tpu.utils.trace import TRACER
 
-from utils_env import TestEnv
+from utils_env import TestEnv, displace_workers
 
 ROOT = Path(__file__).resolve().parent.parent
 DEVICE_CHILDREN = ("solve_dispatch/upload", "solve_dispatch/launch",
@@ -127,6 +127,31 @@ def test_schedule_splits_prefill_into_its_three_passes():
     env.schedule(prefill=False)
     assert all(env.core.tick_stats.totals_ms[k] == totals[k]
                for k in passes + ("prefill",))
+
+
+@pytest.mark.parametrize("outranked", [False, True],
+                         ids=["pass-skips", "pass-scans"])
+def test_displace_span_is_recorded_whether_the_pass_skips_or_scans(outranked):
+    """ISSUE 26: the displacement pass passes over every worker when nothing
+    queued outranks the backlog; its span and its counter say so."""
+    env = _env("numpy", workers=2, tasks=30)
+    env.schedule(prefill=True)
+    # ready work the full workers cannot take: 3-cpu tasks on 2-cpu nodes
+    env.submit(n=5, rqv=env.rqv(cpus=3))
+    if outranked:
+        env.submit(n=1, rqv=env.rqv(cpus=3), priority=(4, 0), job=2)
+        env.submit(n=1, priority=(4, 0), job=3)
+    ticks = env.core.tick_stats.ticks
+    recorded = env.core.tick_stats.totals_ms.get("prefill/displace", 0.0)
+    before = displace_workers()
+    env.schedule(prefill=True)
+    last = env.core.tick_stats.last_ms
+    assert env.core.tick_stats.ticks == ticks + 1
+    assert 0.0 <= last["prefill/displace"] <= last["prefill"]
+    assert env.core.tick_stats.totals_ms["prefill/displace"] >= recorded
+    moved = {o: n - before[o] for o, n in displace_workers().items()}
+    assert moved == ({"skipped": 0, "scanned": 2} if outranked
+                     else {"skipped": 2, "scanned": 0})
 
 
 def test_pipelined_tick_records_the_wait_and_its_split_when_it_takes():

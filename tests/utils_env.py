@@ -86,6 +86,14 @@ class TestEvents:
         self.workers_lost.append((worker_id, reason))
 
 
+def displace_workers() -> dict:
+    """hq_prefill_displace_workers_total so far, by outcome."""
+    from hyperqueue_tpu.utils.metrics import REGISTRY
+
+    counter = REGISTRY.get("hq_prefill_displace_workers_total")
+    return {o: counter.labels(o).value for o in ("skipped", "scanned")}
+
+
 # Default scheduling model for TestEnv; test modules that parametrize over
 # backends (test_scheduler_golden.py) monkeypatch this so reactor-level
 # cases exercise the swapped model too.
