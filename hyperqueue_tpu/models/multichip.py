@@ -118,17 +118,25 @@ class MultichipModel(GreedyCutScanModel):
             return super()._kernel_dispatch(
                 res, free_d, nt_d, life_d, total_d, prep
             )
-        from hyperqueue_tpu.parallel.solve import sharded_cut_scan_donate
+        from hyperqueue_tpu.parallel.solve import (
+            pack_batch_table,
+            sharded_cut_scan_donate,
+        )
 
+        # the replicated per-batch inputs ride ONE cached put: they change
+        # together (with the batch order), and each replicated put is a
+        # round trip per device
+        table = pack_batch_table(
+            prep["needs_p"], prep["sizes_p"], prep["mt_p"],
+            prep["order_ids"], prep["amask_p"],
+        )
         return sharded_cut_scan_donate(
             mesh, free_d, nt_d, life_d,
-            res.place_cached("needs", prep["needs_p"]),
-            res.place_cached("sizes", prep["sizes_p"]),
-            res.place_cached("min_time", prep["mt_p"]),
+            res.place_cached("batch_table", table),
             res.place_cached("class_m", prep["class_m"], kind=3),
-            res.place_cached("order_ids", prep["order_ids"]),
+            extents=prep["needs_p"].shape,
+            has_all=prep["amask_p"] is not None,
             total=total_d,
-            all_mask=res.place_cached("all_mask", prep["amask_p"]),
             gang_nodes=res.place_cached("gang_nodes", prep["gang_p"]),
             gang_ok=res.place_cached("gang_ok", prep["gok_p"], kind=1),
             group_onehot=res.place_cached(

@@ -69,6 +69,10 @@ class DeviceResidency:
     def __init__(self, shardings=None, device=None):
         self._shardings = shardings
         self._device = device
+        # devices that hold the state: a replicated put crosses to each
+        self.mesh_devices = (
+            1 if shardings is None else int(shardings[2].mesh.devices.size)
+        )
         self.key = None            # (pw, pr, has_total) of the resident state
         self.free = None           # device (pw, pr) int32
         self.nt_free = None        # device (pw,) int32
@@ -97,6 +101,12 @@ class DeviceResidency:
         self.readback_bytes_total = 0
 
     # -- placement helpers ------------------------------------------------
+    def _put_bytes(self, nbytes: int, kind: int) -> int:
+        """Bytes that cross to the devices when `nbytes` are put with
+        sharding `kind`: a worker-sharded array (0, 1, 3) reaches each
+        device in part, a replicated one (2) reaches every device whole."""
+        return int(nbytes) * (self.mesh_devices if kind == 2 else 1)
+
     def _put(self, arr, kind):
         import jax
 
@@ -175,9 +185,12 @@ class DeviceResidency:
         if total_p is not None:
             self._m_total[rows] = total_p[rows]
         self.delta_uploads += 1
-        self.upload_bytes_total += int(
+        # the row indices and the rows are put replicated (the scatter
+        # runs under GSPMD): every device receives them whole
+        self.upload_bytes_total += self._put_bytes(
             k * (free_p.itemsize * pr * (2 if total_p is not None else 1)
-                 + nt_p.itemsize + life_p.itemsize + idx.itemsize)
+                 + nt_p.itemsize + life_p.itemsize + idx.itemsize),
+            2,
         )
         return self.free, self.nt_free, self.lifetime, self.total
 
@@ -263,13 +276,17 @@ class DeviceResidency:
             return cached[1]
         dev = self._put(arr, kind)
         self._rep_cache[name] = (arr.copy(), dev)
-        self.upload_bytes_total += int(arr.nbytes)
+        self.upload_bytes_total += self._put_bytes(arr.nbytes, kind)
         return dev
 
     # -- telemetry --------------------------------------------------------
     def stats(self) -> dict:
         return {
             "resident": bool(self._valid),
+            "mesh_devices": self.mesh_devices,
+            "rows_per_device": (
+                self.key[0] // self.mesh_devices if self.key else 0
+            ),
             "full_uploads": self.full_uploads,
             "delta_uploads": self.delta_uploads,
             "dirty_rows_last": self.dirty_rows_last,

@@ -40,6 +40,7 @@ module is its multi-device form, selected with `--scheduler=multichip`
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -50,6 +51,11 @@ from hyperqueue_tpu.ops.assign import (
     expand_onehots,
     scan_batches,
 )
+
+
+# the scopes of the two collectives, as they appear in a trace's op metadata
+WATER_FILL_GATHER = "hq_water_fill_gather"
+GANG_SELECT_GATHER = "hq_gang_select_gather"
 
 
 def make_worker_mesh(n_devices: int | None = None) -> Mesh:
@@ -71,7 +77,10 @@ def _sharded_water_fill_classed(cap, remaining, class_onehot, axis):
     """
     my_dev = jax.lax.axis_index(axis)
     per_class_local = jnp.sum(cap[:, None] * class_onehot, axis=0)  # (C,)
-    all_per_class = jax.lax.all_gather(per_class_local, axis)  # (D, C)
+    # named for the profiler: the trace's metadata then says which
+    # all-gather belongs to the water-fill (one per scan step)
+    with jax.named_scope(WATER_FILL_GATHER):
+        all_per_class = jax.lax.all_gather(per_class_local, axis)  # (D, C)
     per_class_global = jnp.sum(all_per_class, axis=0)  # (C,)
     n_dev = all_per_class.shape[0]
     lower_dev = jnp.sum(
@@ -97,7 +106,8 @@ def _sharded_gang_select(elig, group_onehot, n, axis):
     eligible members in global index order" selection exactly."""
     my_dev = jax.lax.axis_index(axis)
     per_group_local = jnp.sum(elig[:, None] * group_onehot, axis=0)  # (G,)
-    all_per_group = jax.lax.all_gather(per_group_local, axis)  # (D, G)
+    with jax.named_scope(GANG_SELECT_GATHER):
+        all_per_group = jax.lax.all_gather(per_group_local, axis)  # (D, G)
     per_group = jnp.sum(all_per_group, axis=0)  # (G,)
     feasible = per_group >= n
     any_feas = jnp.any(feasible)
@@ -239,12 +249,46 @@ def sharded_cut_scan(
     )
 
 
+def pack_batch_table(needs, sizes, min_time, order_ids, all_mask=None):
+    """The replicated per-batch inputs of one solve as ONE int32 vector
+    (host side, numpy).  They change together, whenever the batch order
+    does, and a replicated put costs a round trip per device whatever its
+    size: packed, a change of order costs the resident tick one put
+    instead of five (`sharded_cut_scan_donate` unpacks on the device)."""
+    import numpy as np
+
+    parts = [needs, sizes, min_time, order_ids]
+    if all_mask is not None:
+        parts.append(all_mask)
+    return np.concatenate(
+        [np.asarray(part, dtype=np.int32).ravel() for part in parts]
+    )
+
+
+def _unpack_batch_table(table, extents, has_all):
+    """(needs, sizes, min_time, order_ids, all_mask or None): static
+    slices of `pack_batch_table`'s vector, B/V/R from `extents`."""
+    n_b, n_v, n_r = extents
+    shapes = [(n_b, n_v, n_r), (n_b,), (n_b, n_v), (n_b, n_v)]
+    if has_all:
+        shapes.append((n_b, n_v, n_r))
+    parts, at = [], 0
+    for shape in shapes:
+        size = math.prod(shape)
+        parts.append(table[at:at + size].reshape(shape))
+        at += size
+    if not has_all:
+        parts.append(None)
+    return parts
+
+
 @functools.partial(
-    jax.jit, static_argnames=("mesh",), donate_argnums=(1, 2)
+    jax.jit, static_argnames=("mesh", "extents", "has_all"),
+    donate_argnums=(1, 2),
 )
 def sharded_cut_scan_donate(
-    mesh: Mesh, free, nt_free, lifetime, needs, sizes, min_time, class_m,
-    order_ids, total=None, all_mask=None,
+    mesh: Mesh, free, nt_free, lifetime, batch_table, class_m, extents,
+    has_all=False, total=None,
     gang_nodes=None, gang_ok=None, group_onehot=None, policy_mask=None,
 ):
     """`sharded_cut_scan` with `free`/`nt_free` DONATED: the input buffers
@@ -254,7 +298,14 @@ def sharded_cut_scan_donate(
     N's outputs become solve N+1's inputs without ever crossing the host
     boundary, so the per-tick host->device traffic is only the dirty-row
     delta. Callers MUST not touch the passed free/nt_free arrays again.
+
+    needs/sizes/min_time/order_ids/all_mask arrive as one replicated
+    vector (`pack_batch_table`; `extents` = their padded (B, V, R),
+    `has_all` says whether all_mask is in it).
     """
+    needs, sizes, min_time, order_ids, all_mask = _unpack_batch_table(
+        batch_table, extents, has_all
+    )
     return _sharded_cut_scan_impl(
         mesh, free, nt_free, lifetime, needs, sizes, min_time, class_m,
         order_ids, total=total, all_mask=all_mask,

@@ -42,6 +42,14 @@ _SOLVES_BY_BACKEND = REGISTRY.counter(
     "device-sharded)",
     labels=("backend",), max_series=8,
 )
+# live batches x variants of every dense solve: the steps the scan has to
+# take whatever its padding, and on the sharded path one water-fill
+# all-gather each
+_SCAN_STEPS = REGISTRY.counter(
+    "hq_solve_scan_steps_total",
+    "scan steps of the dense solves (live batches x variants; the sharded "
+    "solve runs one water-fill all-gather a step)",
+)
 
 
 @dataclass(slots=True)
@@ -663,10 +671,11 @@ def fold_model_phases(phases, model, prefix: str = "") -> None:
             phases[key] = phases.get(key, 0.0) + ms
 
 
-def _count_solve(model) -> None:
+def _count_solve(model, needs) -> None:
     backend = getattr(model, "last_backend", None)
     if backend:  # the MILP names none
         _SOLVES_BY_BACKEND.labels(backend).inc()
+        _SCAN_STEPS.inc(needs.shape[0] * needs.shape[1])
 
 
 def _run_main_solve(queues, workers, rq_map, resource_map, model, batches,
@@ -688,7 +697,7 @@ def _run_main_solve(queues, workers, rq_map, resource_map, model, batches,
         from hyperqueue_tpu.scheduler.pipeline import PendingSolve
 
         handle = model.solve_async(**kwargs)
-        _count_solve(model)
+        _count_solve(model, kwargs["needs"])
         fold_model_phases(phases, model)
         if decision is not None:
             decision.setdefault("solver", {
@@ -709,7 +718,7 @@ def _run_main_solve(queues, workers, rq_map, resource_map, model, batches,
     _t1 = _time.perf_counter()  # the decision record's own reading
     counts = model.solve(**kwargs)
     _t2 = _time.perf_counter()
-    _count_solve(model)
+    _count_solve(model, kwargs["needs"])
     fold_model_phases(phases, model)
     if decision is not None:
         # the solver's verdict for this tick's DecisionRecord

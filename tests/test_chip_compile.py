@@ -138,7 +138,50 @@ def test_sharded_kernel_compiles_for_four_v5e(topo, no_cache, extras):
     args, kwargs = _kernel_shapes(
         W_SHARDED, lambda kind: NamedSharding(mesh, specs[kind]), extras
     )
-    compiled = sharded_cut_scan_donate.lower(mesh, *args, **kwargs).compile()
+    # the resident solve takes needs/sizes/min_time/order_ids/all_mask as
+    # one replicated vector (parallel/solve.pack_batch_table)
+    import jax
+
+    free, nt_free, lifetime, needs, sizes, min_time, class_m, order_ids = args
+    packed = [needs, sizes, min_time, order_ids]
+    has_all = "all_mask" in kwargs
+    if has_all:
+        packed.append(kwargs.pop("all_mask"))
+    table = jax.ShapeDtypeStruct(
+        (sum(int(np.prod(a.shape)) for a in packed),), np.int32,
+        sharding=needs.sharding,
+    )
+    compiled = sharded_cut_scan_donate.lower(
+        mesh, free, nt_free, lifetime, table, class_m,
+        extents=needs.shape, has_all=has_all, **kwargs,
+    ).compile()
     mem = compiled.memory_analysis()  # bytes per device
     assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < HBM_BYTES
     assert re.search(r"all-gather|all-reduce", compiled.as_text())
+
+
+def test_sharded_scatter_and_slicer_compile_for_four_v5e(topo, no_cache):
+    """Around the sharded kernel at W = 16 384: the dirty-row scatter under
+    GSPMD at the two buckets a 16k cluster's churn meets (2 048 and 4 096
+    rows; indices and rows replicated, the state sharded) and the slicer of
+    the W-sharded counts."""
+    import jax
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from hyperqueue_tpu.models.greedy import _device_slicer
+    from hyperqueue_tpu.parallel.resident import _scatter_rows
+
+    mesh = Mesh(np.array(topo.devices[:4]), axis_names=("w",))
+    w2, w1, rep = (NamedSharding(mesh, spec)
+                   for spec in (P("w", None), P("w"), P()))
+
+    def s(shape, sharding):
+        return jax.ShapeDtypeStruct(shape, np.int32, sharding=sharding)
+
+    for k in (2048, 4096):
+        jax.jit(_scatter_rows, donate_argnums=(0,), out_shardings=w2).lower(
+            s((W_SHARDED, R), w2), s((k,), rep), s((k, R), rep)).compile()
+        jax.jit(_scatter_rows, donate_argnums=(0,), out_shardings=w1).lower(
+            s((W_SHARDED,), w1), s((k,), rep), s((k,), rep)).compile()
+    counts = s((B, V, W_SHARDED), NamedSharding(mesh, P(None, None, "w")))
+    _device_slicer(224, 2, W_SHARDED).lower(counts).compile()

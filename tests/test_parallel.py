@@ -411,3 +411,116 @@ def test_resident_paranoid_check_fires_on_corruption():
         model.solve(
             free=total.copy(), nt_free=nt_next, lifetime=lifetime, **batch,
         )
+
+
+@pytest.mark.parametrize(
+    "dirty,expect", [(2048, "delta"), (3000, "delta"), (4096, "delta"),
+                     (5000, "full")],
+    ids=["2048-rows", "3000-rows", "4096-rows", "over-half"],
+)
+def test_sharded_residency_large_delta_equals_fresh_upload(dirty, expect):
+    """The residency's large buckets, which a 1k-worker cluster never
+    leaves 512 to meet: a sharded `DeviceResidency` on 4 devices takes a
+    delta of thousands of dirty rows (buckets 2 048 and 4 096, with and
+    without padding) and one above FULL_UPLOAD_FRACTION, and then holds
+    what a fresh upload holds, row for row."""
+    from hyperqueue_tpu.parallel.resident import DeviceResidency
+    from hyperqueue_tpu.parallel.solve import _mesh_shardings
+
+    n_w, n_r = 8192, 4
+    rng = np.random.default_rng(dirty)
+    res = DeviceResidency(shardings=_mesh_shardings(make_worker_mesh(4)))
+    free = (rng.integers(0, 8, size=(n_w, n_r)) * U).astype(np.int32)
+    total = free + U
+    nt_free = rng.integers(0, 10, size=n_w).astype(np.int32)
+    lifetime = np.full(n_w, INF_TIME, dtype=np.int32)
+    res.sync(free, nt_free, lifetime, total)
+    stats = res.stats()
+    assert (stats["mesh_devices"], stats["rows_per_device"]) == (4, 2048)
+    uploaded = stats["upload_bytes_total"]
+    assert uploaded == free.nbytes * 2 + nt_free.nbytes * 2  # sharded: once
+
+    rows = rng.choice(n_w, size=dirty, replace=False)
+    free2, nt2, life2 = free.copy(), nt_free.copy(), lifetime.copy()
+    free2[rows] += U
+    nt2[rows[::2]] += 1
+    life2[rows[::3]] = 600
+    got = res.sync(free2, nt2, life2, total)
+    stats = res.stats()
+    assert stats["dirty_rows_last"] == (dirty if expect == "delta" else n_w)
+    assert stats["delta_uploads"] == (expect == "delta")
+    assert stats["full_uploads"] == 1 + (expect == "full")
+    if expect == "delta":
+        # indices and rows are put replicated: every device receives them
+        bucket = 2048 if dirty <= 2048 else 4096
+        row_bytes = 4 * (2 * n_r + 3)
+        assert stats["upload_bytes_total"] - uploaded == 4 * bucket * row_bytes
+    for dev, want in zip(got, (free2, nt2, life2, total)):
+        assert len(dev.sharding.device_set) == 4
+        np.testing.assert_array_equal(np.asarray(dev), want)
+    fresh = DeviceResidency(shardings=_mesh_shardings(make_worker_mesh(4)))
+    for dev, other in zip(got, fresh.sync(free2, nt2, life2, total)):
+        np.testing.assert_array_equal(np.asarray(dev), np.asarray(other))
+        assert dev.sharding == other.sharding
+
+
+@pytest.mark.parametrize("with_all", [False, True], ids=["plain", "all-mask"])
+def test_batch_table_round_trip(with_all):
+    from hyperqueue_tpu.parallel.solve import (
+        _unpack_batch_table,
+        pack_batch_table,
+    )
+
+    rng = np.random.default_rng(5)
+    n_b, n_v, n_r = 8, 2, 4
+    needs = rng.integers(0, 9, size=(n_b, n_v, n_r)).astype(np.int32)
+    sizes = rng.integers(0, 99, size=n_b).astype(np.int32)
+    min_time = rng.integers(0, 9, size=(n_b, n_v)).astype(np.int32)
+    order_ids = rng.integers(0, 4, size=(n_b, n_v)).astype(np.int32)
+    all_mask = (needs == 0).astype(np.int32) if with_all else None
+    table = pack_batch_table(needs, sizes, min_time, order_ids, all_mask)
+    assert table.dtype == np.int32 and table.ndim == 1
+    got = _unpack_batch_table(table, (n_b, n_v, n_r), with_all)
+    for have, want in zip(got, (needs, sizes, min_time, order_ids, all_mask)):
+        if want is None:
+            assert have is None
+        else:
+            np.testing.assert_array_equal(have, want)
+
+
+def test_changed_batch_order_costs_the_sharded_tick_one_put():
+    """The replicated per-batch inputs ride one cached put: a tick that
+    repeats the batch table uploads none of it, one that reorders the
+    batches uploads the table once to every device, and nothing else."""
+    rng = np.random.default_rng(3)
+    n_w, n_r = 16, 4
+    free, total, nt_free, lifetime = _random_workers(rng, n_w, n_r)
+    lifetime[:] = int(INF_TIME)
+    batch = _random_tick_batches(np.random.default_rng(1), n_r)
+    model = MultichipModel(n_devices=4)
+
+    def solve(b):
+        # the same worker state every time: nothing is dirty after the first
+        model.invalidate_resident()
+        return model.solve(free=free.copy(), nt_free=nt_free.copy(),
+                           lifetime=lifetime, **b)
+
+    first = solve(batch)
+    res = model._res
+    before = res.stats()
+    np.testing.assert_array_equal(solve(batch), first)
+    again = res.stats()
+    state_bytes = free.nbytes + nt_free.nbytes + lifetime.nbytes  # re-upload
+    assert again["upload_bytes_total"] - before["upload_bytes_total"] \
+        == state_bytes
+    assert again["rep_cache_hits"] - before["rep_cache_hits"] == 2
+
+    order = np.arange(len(batch["sizes"]))[::-1]
+    flipped = {k: (v[order] if k != "priorities" else v)
+               for k, v in batch.items()}
+    solve(flipped)
+    after = res.stats()
+    pb, pv, pr = 8, batch["needs"].shape[1], 4  # the padded extents
+    table_bytes = 4 * (pb * pv * pr + pb + 2 * pb * pv)
+    assert after["upload_bytes_total"] - again["upload_bytes_total"] \
+        == state_bytes + 4 * table_bytes
