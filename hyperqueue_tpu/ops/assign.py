@@ -120,6 +120,44 @@ def _variant_capacity(free, nt_free, need, time_ok, total=None, all_r=None):
     return jnp.maximum(cap, 0)
 
 
+def _exclusive_prefix_rows(x):
+    """Exclusive prefix sum along axis 0 of an int32 array ((W,) or (W, C)),
+    mod 2**32: bit for bit `np.cumsum(x, 0) - x` in int32, wrap-around
+    included, for every input.
+
+    Written as log-step shifted adds (Hillis-Steele): ceil(log2 W) times,
+    `y += y shifted down by d rows, zeros shifted in`, d = 1, 2, 4, ...;
+    after the last, row w holds the sum of rows 0..w, and taking `x` off
+    makes it exclusive. Nothing but int32 adds, so exactness needs no
+    argument and holds on every backend, and the only thing the trace
+    adapts to is the static row count (a (1,) input takes no step at all).
+
+    Why not `jnp.cumsum`: on a TPU it lowers to a `reduce-window`, which
+    costs 12.5 us a call inside the scan at 1 024 rows and the same at
+    4 096 — a fixed cost of that lowering, 86% of the whole kernel (PERF.md
+    section 6, PR 28). Ten or twelve fused shifted adds cost 0.9 / 1.6 us.
+    A two-level blocked triangular contraction on the MXU (8-bit limbs in
+    bfloat16 or 7-bit limbs in int8, exact as well) was measured beside it
+    and is 1 us a call slower at both widths: its flops are free, its limb
+    split, relayout and recombination are not (benchmarks/
+    prefix_microbench.py; the table is in PERF.md).
+    """
+    _load_jax()
+    n = x.shape[0]
+    y, d = x, 1
+    while d < n:
+        # rows d.. take rows 0..n-d; the negative high edge drops the rest
+        shift = [(d, -d, 0)] + [(0, 0, 0)] * (x.ndim - 1)
+        y = y + jax.lax.pad(y, jnp.int32(0), shift)
+        d *= 2
+    return y - x
+
+
+# the one way the jitted kernel takes a prefix, as `hq_solve_prefix_total`
+# labels it (scheduler/tick.py); the host twins keep `np.cumsum`
+PREFIX_FORMULATION = "shifted-adds"
+
+
 def _water_fill_classed(
     cap, remaining, class_onehot, per_class_total=None, same_class_before=0
 ):
@@ -129,9 +167,13 @@ def _water_fill_classed(
     class_onehot: (W, C) int32 0/1, class 0 visited first; within a class,
     workers are visited in index order. The prefix (capacity absorbed before
     worker w) = total capacity of strictly-lower classes + exclusive
-    index-order cumsum within w's own class — all elementwise ops + cumsums,
-    which TPUs execute in microseconds where a 1024-element permutation
-    gather costs ~140us.
+    index-order prefix sum within w's own class — all elementwise ops,
+    column sums and two exclusive prefixes, where a 1024-element
+    permutation gather costs ~140us. Both prefixes (over the 16 classes and
+    over the W workers of each class column) are `_exclusive_prefix_rows`:
+    log-step shifted adds in int32, exact, about a microsecond a call on a
+    TPU; as a `cumsum` (a `reduce-window` there) the one over the workers
+    was 12.5 us a call and most of the kernel.
 
     The multi-chip kernel runs this SAME function on each worker shard
     (parallel/solve.py): `per_class_total` (C,) is then the cluster-wide
@@ -146,10 +188,8 @@ def _water_fill_classed(
     per_class = jnp.sum(cap_c, axis=0)  # (C,)
     if per_class_total is None:
         per_class_total = per_class
-    class_before = (
-        jnp.cumsum(per_class_total) - per_class_total
-    )  # exclusive (C,)
-    within_excl = jnp.cumsum(cap_c, axis=0) - cap_c  # (W, C)
+    class_before = _exclusive_prefix_rows(per_class_total)  # (C,)
+    within_excl = _exclusive_prefix_rows(cap_c)  # (W, C)
     prefix = jnp.sum(
         (within_excl + (class_before + same_class_before)[None, :])
         * class_onehot,
@@ -180,7 +220,8 @@ def host_visit_classes(free0, needs, scarcity, all_mask=None, affinity=None):
     "unused resource" masks per tick are few (M << B*V). Instead of materializing
     permutations (arbitrary-permutation gathers cost ~140us per scan step on
     TPU), each worker gets a visit CLASS = dense rank of its waste score; the
-    kernel water-fills class-by-class with cumsums only.
+    kernel water-fills class-by-class with column sums and prefix sums only
+    (`_exclusive_prefix_rows`: shifted int32 adds, about a microsecond each).
 
     affinity (B, W) float, optional: per-(batch, worker) policy weight (the
     heterogeneity matrix `S` sliced per batch row). The visit key becomes the
@@ -266,7 +307,7 @@ def _gang_select_local(elig, group_onehot, n):
         axis=1,
     )
     sel = elig * col
-    prefix = jnp.cumsum(sel) - sel
+    prefix = _exclusive_prefix_rows(sel)
     take = sel * (prefix < n).astype(jnp.int32)
     return take, any_feas
 

@@ -15,7 +15,7 @@ each local worker's global water-fill prefix as
 
     prefix(w) = capacity of strictly-lower classes (cluster-wide)
               + capacity of w's class on lower-index devices
-              + exclusive local cumsum within w's class
+              + exclusive local prefix sum within w's class
 
 All three terms come from ONE all_gather of the per-device (C,)-vector of
 per-class capacity sums per variant step (C = N_VISIT_CLASSES = 16) — pure ICI
@@ -47,6 +47,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from hyperqueue_tpu.ops.assign import (
+    _exclusive_prefix_rows,
     _water_fill_classed,
     expand_onehots,
     scan_batches,
@@ -126,7 +127,7 @@ def _sharded_gang_select(elig, group_onehot, n, axis):
         jnp.where((jnp.arange(n_dev) < my_dev)[:, None], all_per_group, 0)
         * chosen_oh[None, :].astype(jnp.int32)
     )
-    prefix = jnp.cumsum(sel) - sel + lower
+    prefix = _exclusive_prefix_rows(sel) + lower
     take = sel * (prefix < n).astype(jnp.int32)
     return take, any_feas
 

@@ -18,6 +18,7 @@ from dataclasses import dataclass
 
 import numpy as np
 
+from hyperqueue_tpu.ops.assign import PREFIX_FORMULATION
 from hyperqueue_tpu.utils.constants import INF_TIME
 from hyperqueue_tpu.resources.map import ResourceIdMap, ResourceRqMap
 from hyperqueue_tpu.scheduler.queues import Priority, TaskQueues
@@ -49,6 +50,14 @@ _SCAN_STEPS = REGISTRY.counter(
     "hq_solve_scan_steps_total",
     "scan steps of the dense solves (live batches x variants; the sharded "
     "solve runs one water-fill all-gather a step)",
+)
+# a static fact of the program a solve ran, counted so that a run shows
+# which formulation its solves used
+_SOLVES_BY_PREFIX = REGISTRY.counter(
+    "hq_solve_prefix_total",
+    "dense solves by the formulation of the water-fill's prefix sum over "
+    "the workers (shifted-adds: the jitted kernel; cumsum: the host scans)",
+    labels=("impl",), max_series=4,
 )
 
 
@@ -676,6 +685,9 @@ def _count_solve(model, needs) -> None:
     if backend:  # the MILP names none
         _SOLVES_BY_BACKEND.labels(backend).inc()
         _SCAN_STEPS.inc(needs.shape[0] * needs.shape[1])
+        _SOLVES_BY_PREFIX.labels(
+            PREFIX_FORMULATION if backend.startswith("device") else "cumsum"
+        ).inc()
 
 
 def _run_main_solve(queues, workers, rq_map, resource_map, model, batches,
