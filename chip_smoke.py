@@ -478,13 +478,20 @@ def _tick_line(model, before: dict, phases_ms: dict, **head) -> dict:
 TICKS = 3  # per mode
 
 
-def width(n_workers: int, n_tasks: int, n_classes: int) -> dict:
-    """The production tick over production state at the full width, the
-    model forced to the device: TICKS ticks each solved synchronously
-    from a fresh upload, device-resident, and through the pipeline."""
+WIDTH_CELL = "hetero-1k.backlog-1m"  # BENCHMARK.json: the world `width` ticks over
+
+
+def width() -> dict:
+    """The production tick over the benchmark's 1k world (1 024 workers,
+    1 000 000 ready tasks, 64 classes: the state the cell WIDTH_CELL
+    measures), the model forced to the device: TICKS ticks each solved
+    synchronously from a fresh upload, device-resident, and through the
+    pipeline."""
     import jax
 
-    from bench import build_core_state
+    from chipbench import generate, manifest
+    from chipbench.drivers.tick import build_program_state
+    from hyperqueue_tpu.ids import task_id_task
     from hyperqueue_tpu.models.greedy import (
         GreedyCutScanModel,
         device_sync_ms,
@@ -499,10 +506,14 @@ def width(n_workers: int, n_tasks: int, n_classes: int) -> dict:
           **compiles.snapshot()})
 
     t0 = time.monotonic()
-    core, _rq_ids, priority_of = build_core_state(
-        n_workers=n_workers, n_tasks=n_tasks, n_classes=n_classes,
-    )
+    cell = manifest.cell(WIDTH_CELL)
+    world = generate.world(cell["config"], cell["traffic"], seed=42)
+    core, _rq_ids, _worker_ids = build_program_state(world, cell["config"])
     build_s = time.monotonic() - t0
+
+    def priority_of(task_id):
+        return (int(world.task_prio[task_id_task(task_id)]), 0)
+
     model = checked(GreedyCutScanModel, numpy_reference)(backend="jax")
     model.paranoid_resident = 1  # every resident solve vs a fresh upload
 
@@ -636,9 +647,10 @@ def width(n_workers: int, n_tasks: int, n_classes: int) -> dict:
     memory = device.memory_stats() or {}
     return {
         "phase": "width",
-        "workers": n_workers,
-        "ready_tasks": n_tasks,
-        "classes": n_classes,
+        "world": WIDTH_CELL,
+        "workers": len(core.workers),
+        "ready_tasks": len(world.task_class),
+        "classes": len(world.class_variants),
         "solves_bitwise_equal_to_numpy": model.solves_checked,
         "resident_vs_fresh_checks": model.paranoid_checks,
         "shape_key": model.shape_key,
@@ -663,10 +675,7 @@ def sharded(n_workers: int, n_tasks: int, n_devices: int) -> dict:
     from __graft_entry__ import ClusterState
     from hyperqueue_tpu.models.greedy import GreedyCutScanModel
     from hyperqueue_tpu.models.multichip import MultichipModel
-    from hyperqueue_tpu.parallel.solve import (
-        place_tick_inputs,
-        sharded_cut_scan,
-    )
+    from hyperqueue_tpu.parallel.solve import sharded_cut_scan_donate
     from hyperqueue_tpu.server import reactor
 
     compiles = CompileLog()
@@ -750,16 +759,11 @@ def sharded(n_workers: int, n_tasks: int, n_devices: int) -> dict:
     check("sharded: no array on the default device alone",
           set(spans.values()) == {n_devices}, spans)
 
-    # collectives of the program the last tick ran (the non-donating twin
-    # compiles to the same body)
-    prep = preps[-1]
-    text = sharded_cut_scan.lower(model._mesh, *place_tick_inputs(
-        model._mesh, prep["free_p"], prep["nt_p"], prep["life_p"],
-        prep["needs_p"], prep["sizes_p"], prep["mt_p"], prep["class_m"],
-        prep["order_ids"], total=prep["total_p"], all_mask=prep["amask_p"],
-        gang_nodes=prep["gang_p"], gang_ok=prep["gok_p"],
-        group_onehot=prep["goh_p"], policy_mask=prep["pmask_p"],
-    )).compile().as_text()
+    # collectives of the program the last tick ran
+    args, kwargs = model._fresh_program_args(preps[-1])
+    text = sharded_cut_scan_donate.lower(
+        model._mesh, *args, **kwargs
+    ).compile().as_text()
     collectives = {
         op: len(re.findall(rf"= \S+ {op}(?:-start)?\(", text))
         for op in ("all-gather", "all-reduce", "collective-permute",
@@ -825,7 +829,7 @@ def main() -> None:
           devices[0].platform == "tpu" and len(devices) >= chips,
           [str(d) for d in devices])
     if chips == 1:
-        emit(width(n_workers=1024, n_tasks=1_000_000, n_classes=128))
+        emit(width())
     else:
         emit(sharded(n_workers=16384, n_tasks=120_000, n_devices=chips))
     print(json.dumps({"ok": True, "device": {

@@ -367,8 +367,8 @@ class LocalHandler(QueueHandler):
     """Spawn real worker processes on the server's own host.
 
     The whole elasticity loop (demand query -> submit -> worker register ->
-    drain -> cancel) runs without PBS/Slurm — in CI, in `bench.py
-    --elasticity-smoke`, and on single-node deployments. Each "allocation"
+    drain -> cancel) runs without PBS/Slurm — in CI
+    (tests/test_elasticity.py) and on single-node deployments. Each "allocation"
     is one detached process group running `workers_per_alloc` workers; the
     allocation id is ``local-<pgid>``, so liveness/cancellation work by
     pid across server restarts (allocation-exact restore reconciles

@@ -264,8 +264,8 @@ class GreedyCutScanModel:
         self.variant_floor = variant_floor
         self.backend = backend
         # which path the last solve actually ran (host-native / host-numpy
-        # / device-jax / device-sharded); bench.py and the DecisionRecords
-        # report it, with last_backend_reason naming WHY it was chosen
+        # / device-jax / device-sharded); the DecisionRecords and `hq server
+        # stats` report it, with last_backend_reason naming WHY it was chosen
         self.last_backend: str | None = None
         self.last_backend_reason: str = ""
         # {platform, kind, count} of the devices holding the counts the
@@ -283,7 +283,7 @@ class GreedyCutScanModel:
         self._buffers: dict[tuple, dict] = {}
         # counts NEW bucket-shape allocations — each implies a fresh XLA
         # compilation on the jit path, so a steady-state tick must not
-        # increment it (asserted by bench.py --smoke)
+        # increment it (asserted by tests/test_tick_cache.py)
         self.shape_allocations = 0
         # the last solve's spans in ms, under the tick's phase keys
         # (solve_host_prep, solve_dispatch, device_sync and their
@@ -793,8 +793,8 @@ class GreedyCutScanModel:
         repeat; reusing the arrays avoids a full allocate+memset per call
         and keeps the jit cache keyed on stable shapes.  A new key means a
         new XLA compilation on the device path — counted in
-        `shape_allocations` so the smoke bench can assert steady-state
-        ticks trigger none.
+        `shape_allocations` so tests and the benchmark can assert that
+        steady-state ticks trigger none.
         """
         key = (pw, pb, pr, pv, has_all)
         buf = self._buffers.get(key)

@@ -147,22 +147,38 @@ class MultichipModel(GreedyCutScanModel):
             ),
         )
 
+    def _fresh_program_args(self, prep):
+        """(args, kwargs) of `sharded_cut_scan_donate` after the mesh, from
+        the padded host buffers alone: no residency, no placement cache."""
+        from hyperqueue_tpu.parallel.solve import pack_batch_table
+
+        args = (
+            prep["free_p"], prep["nt_p"], prep["life_p"],
+            pack_batch_table(
+                prep["needs_p"], prep["sizes_p"], prep["mt_p"],
+                prep["order_ids"], prep["amask_p"],
+            ),
+            prep["class_m"],
+        )
+        kwargs = dict(
+            extents=prep["needs_p"].shape,
+            has_all=prep["amask_p"] is not None,
+            total=prep["total_p"], gang_nodes=prep["gang_p"],
+            gang_ok=prep["gok_p"], group_onehot=prep["goh_p"],
+            policy_mask=prep["pmask_p"],
+        )
+        return args, kwargs
+
     def _fresh_device_counts(self, prep):
         mesh = self.get_mesh()
         if not mesh:
             return super()._fresh_device_counts(prep)
-        from hyperqueue_tpu.parallel.solve import (
-            place_tick_inputs,
-            sharded_cut_scan,
-        )
+        from hyperqueue_tpu.parallel.solve import sharded_cut_scan_donate
 
-        placed = place_tick_inputs(
-            mesh, prep["free_p"], prep["nt_p"], prep["life_p"],
-            prep["needs_p"], prep["sizes_p"], prep["mt_p"],
-            prep["class_m"], prep["order_ids"], total=prep["total_p"],
-            all_mask=prep["amask_p"], gang_nodes=prep["gang_p"],
-            gang_ok=prep["gok_p"], group_onehot=prep["goh_p"],
-            policy_mask=prep["pmask_p"],
+        # the resident tick's program; it consumes free and nt_free, so
+        # those go in as copies
+        (free, nt_free, *rest), kwargs = self._fresh_program_args(prep)
+        counts, _f, _n = sharded_cut_scan_donate(
+            mesh, free.copy(), nt_free.copy(), *rest, **kwargs
         )
-        counts, _f, _n = sharded_cut_scan(mesh, *placed)
         return counts

@@ -139,8 +139,8 @@ def _exclusive_prefix_rows(x):
     A two-level blocked triangular contraction on the MXU (8-bit limbs in
     bfloat16 or 7-bit limbs in int8, exact as well) was measured beside it
     and is 1 us a call slower at both widths: its flops are free, its limb
-    split, relayout and recombination are not (benchmarks/
-    prefix_microbench.py; the table is in PERF.md).
+    split, relayout and recombination are not (the table of every
+    formulation timed is in PERF.md section 6).
     """
     _load_jax()
     n = x.shape[0]
@@ -284,30 +284,37 @@ def expand_onehots(class_m, order_ids):
     return jax.lax.optimization_barrier(onehots)
 
 
-def _gang_select_local(elig, group_onehot, n):
+def _gang_select_local(
+    elig, group_onehot, n, per_group_total=None, same_group_before=0
+):
     """Pick the gang's worker set from one device's full worker view.
 
     elig (W,) int32 0/1, group_onehot (W, G) int32, n scalar gang size.
     Chooses the FIRST group with >= n eligible workers (else the group with
     the most, for holdback), then the n lowest-index eligible members.
-    Returns (take (W,) int32 0/1, any_feasible bool). The sharded kernel
-    plugs in a collective variant (parallel/solve.py) with the same
-    contract.
+    Returns (take (W,) int32 0/1, any_feasible bool).
+
+    Single-chip callers leave the last two arguments at their defaults.
+    The sharded kernel passes the cross-device terms (parallel/solve.py):
+    `per_group_total` (G,) is then the cluster-wide eligible count per
+    group (elig/group_onehot cover only this device's workers) and
+    `same_group_before` (G,) the same-group eligible count on lower-index
+    devices, so "the n lowest-index members" counts across the mesh.
     """
-    per_group = jnp.sum(elig[:, None] * group_onehot, axis=0)  # (G,)
-    feasible = per_group >= n
+    if per_group_total is None:
+        per_group_total = jnp.sum(elig[:, None] * group_onehot, axis=0)
+    feasible = per_group_total >= n
     any_feas = jnp.any(feasible)
     chosen = jnp.where(
-        any_feas, jnp.argmax(feasible), jnp.argmax(per_group)
+        any_feas, jnp.argmax(feasible), jnp.argmax(per_group_total)
     )
-    col = jnp.sum(
-        group_onehot
-        * (jnp.arange(group_onehot.shape[1], dtype=jnp.int32)
-           == chosen)[None, :],
-        axis=1,
+    chosen_oh = (
+        jnp.arange(group_onehot.shape[1], dtype=jnp.int32) == chosen
+    ).astype(jnp.int32)
+    sel = elig * jnp.sum(group_onehot * chosen_oh[None, :], axis=1)
+    prefix = _exclusive_prefix_rows(sel) + jnp.sum(
+        same_group_before * chosen_oh
     )
-    sel = elig * col
-    prefix = _exclusive_prefix_rows(sel)
     take = sel * (prefix < n).astype(jnp.int32)
     return take, any_feas
 

@@ -47,7 +47,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from hyperqueue_tpu.ops.assign import (
-    _exclusive_prefix_rows,
+    _gang_select_local,
     _water_fill_classed,
     expand_onehots,
     scan_batches,
@@ -98,38 +98,31 @@ def _sharded_water_fill_classed(cap, remaining, class_onehot, axis):
 
 
 def _sharded_gang_select(elig, group_onehot, n, axis):
-    """Collective form of ops.assign._gang_select_local: elig/group_onehot
-    are LOCAL worker shards; the per-group eligible counts are gathered
-    across devices (one (G,)-vector all_gather), the chosen group is a
-    replicated argmax, and each local take-prefix is shifted by the chosen
-    group's eligible count on lower-index devices — shard_map splits the
-    worker axis contiguously, so this reproduces the single-chip "first n
-    eligible members in global index order" selection exactly."""
+    """Gang selection with cluster-wide group counts.
+
+    elig (Wl,), group_onehot (Wl, G): LOCAL worker shards. The selection
+    itself IS ops.assign._gang_select_local — this wrapper only gathers the
+    per-group eligible counts across devices (one (G,)-vector all_gather)
+    and feeds them in as the global totals + lower-device same-group
+    offsets. shard_map splits the worker axis contiguously, so that is the
+    single-chip "first n eligible members in global index order".
+    """
     my_dev = jax.lax.axis_index(axis)
     per_group_local = jnp.sum(elig[:, None] * group_onehot, axis=0)  # (G,)
     with jax.named_scope(GANG_SELECT_GATHER):
         all_per_group = jax.lax.all_gather(per_group_local, axis)  # (D, G)
-    per_group = jnp.sum(all_per_group, axis=0)  # (G,)
-    feasible = per_group >= n
-    any_feas = jnp.any(feasible)
-    chosen = jnp.where(
-        any_feas, jnp.argmax(feasible), jnp.argmax(per_group)
-    )
-    chosen_oh = (
-        jnp.arange(group_onehot.shape[1], dtype=jnp.int32) == chosen
-    )
-    col = jnp.sum(group_onehot * chosen_oh[None, :].astype(jnp.int32),
-                  axis=1)
-    sel = elig * col
     n_dev = all_per_group.shape[0]
-    # sum(sel) on a device IS its per_group_local[chosen]
-    lower = jnp.sum(
-        jnp.where((jnp.arange(n_dev) < my_dev)[:, None], all_per_group, 0)
-        * chosen_oh[None, :].astype(jnp.int32)
+    lower_dev = jnp.sum(
+        jnp.where(
+            (jnp.arange(n_dev) < my_dev)[:, None], all_per_group, 0
+        ),
+        axis=0,
+    )  # (G,) same-group eligible workers on lower-index devices
+    return _gang_select_local(
+        elig, group_onehot, n,
+        per_group_total=jnp.sum(all_per_group, axis=0),
+        same_group_before=lower_dev,
     )
-    prefix = _exclusive_prefix_rows(sel) + lower
-    take = sel * (prefix < n).astype(jnp.int32)
-    return take, any_feas
 
 
 def _sharded_body(
@@ -228,28 +221,6 @@ def _sharded_cut_scan_impl(
     )(*args)
 
 
-@functools.partial(jax.jit, static_argnames=("mesh",))
-def sharded_cut_scan(
-    mesh: Mesh, free, nt_free, lifetime, needs, sizes, min_time, class_m,
-    order_ids, total=None, all_mask=None,
-    gang_nodes=None, gang_ok=None, group_onehot=None, policy_mask=None,
-):
-    """Worker-sharded variant of ops.assign.greedy_cut_scan — same inputs,
-    same outputs, identical semantics.
-
-    free/total (W, R), nt_free/lifetime/gang_ok (W,), class_m (M, W),
-    policy_mask (B, W) and group_onehot (W, G) sharded on axis "w";
-    needs/sizes/min_time/order_ids/all_mask/gang_nodes replicated. Returns
-    counts (B, V, W) sharded on W, plus free/nt_free after.
-    """
-    return _sharded_cut_scan_impl(
-        mesh, free, nt_free, lifetime, needs, sizes, min_time, class_m,
-        order_ids, total=total, all_mask=all_mask,
-        gang_nodes=gang_nodes, gang_ok=gang_ok, group_onehot=group_onehot,
-        policy_mask=policy_mask,
-    )
-
-
 def pack_batch_table(needs, sizes, min_time, order_ids, all_mask=None):
     """The replicated per-batch inputs of one solve as ONE int32 vector
     (host side, numpy).  They change together, whenever the batch order
@@ -292,13 +263,20 @@ def sharded_cut_scan_donate(
     has_all=False, total=None,
     gang_nodes=None, gang_ok=None, group_onehot=None, policy_mask=None,
 ):
-    """`sharded_cut_scan` with `free`/`nt_free` DONATED: the input buffers
-    are consumed and their storage reused for `free_after`/`nt_after`.
+    """Worker-sharded variant of ops.assign.greedy_cut_scan: identical
+    semantics, `free`/`nt_free` DONATED (the input buffers are consumed
+    and their storage reused for `free_after`/`nt_after`).
+
+    free/total (W, R), nt_free/lifetime/gang_ok (W,), class_m (M, W),
+    policy_mask (B, W) and group_onehot (W, G) sharded on axis "w";
+    batch_table/gang_nodes replicated. Returns counts (B, V, W) sharded on
+    W, plus free/nt_free after.
 
     This is the device-resident tick's solve (parallel/resident.py): solve
     N's outputs become solve N+1's inputs without ever crossing the host
     boundary, so the per-tick host->device traffic is only the dirty-row
-    delta. Callers MUST not touch the passed free/nt_free arrays again.
+    delta. Callers MUST not touch the passed free/nt_free arrays again;
+    one that wants to keep its inputs passes copies.
 
     needs/sizes/min_time/order_ids/all_mask arrive as one replicated
     vector (`pack_batch_table`; `extents` = their padded (B, V, R),
@@ -329,39 +307,3 @@ def _mesh_shardings(mesh: Mesh):
         NamedSharding(mesh, P()),
         NamedSharding(mesh, P(None, "w")),
     )
-
-
-def place_tick_inputs(mesh: Mesh, free, nt_free, lifetime, needs, sizes,
-                      min_time, class_m, order_ids, total=None,
-                      all_mask=None, gang_nodes=None, gang_ok=None,
-                      group_onehot=None, policy_mask=None):
-    """Device-put the tick tensors with the proper shardings."""
-    w2, w1, rep, cm = _mesh_shardings(mesh)
-    out = (
-        jax.device_put(free, w2),
-        jax.device_put(nt_free, w1),
-        jax.device_put(lifetime, w1),
-        jax.device_put(needs, rep),
-        jax.device_put(sizes, rep),
-        jax.device_put(min_time, rep),
-        jax.device_put(class_m, cm),
-        jax.device_put(order_ids, rep),
-    )
-    has_gang = gang_nodes is not None
-    has_pmask = policy_mask is not None
-    if total is not None or all_mask is not None or has_gang or has_pmask:
-        out = out + (
-            None if total is None else jax.device_put(total, w2),
-            None if all_mask is None else jax.device_put(all_mask, rep),
-        )
-    if has_gang:
-        out = out + (
-            jax.device_put(gang_nodes, rep),
-            jax.device_put(gang_ok, w1),
-            jax.device_put(group_onehot, w2),
-        )
-    elif has_pmask:
-        out = out + (None, None, None)
-    if has_pmask:
-        out = out + (jax.device_put(policy_mask, cm),)
-    return out

@@ -11,7 +11,7 @@ docs/observability.md catalog, so a code cannot ship undocumented.
 Classification runs once per tick over the LEFTOVER batches only (classes
 the solve did not drain), never per task: tasks of one request class share
 one reason, so the cost is O(leftover classes x workers) against the ≤5%
-tick-budget guard (ISSUE 4 acceptance; watched by ``bench.py --phases``).
+tick-budget guard (ISSUE 4 acceptance; the `decide` phase of every tick).
 """
 
 from __future__ import annotations

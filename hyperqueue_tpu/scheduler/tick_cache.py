@@ -18,7 +18,7 @@ applies dirty-tracking deltas instead of rebuilding:
 - membership changes (connect/disconnect, gang reservation flips) and
   resource-map widening are structural: the row map is rebuilt and the
   `full_rebuilds` counter increments — steady-state ticks must keep it
-  at zero (pinned by bench.py --smoke and tests/test_tick_cache.py).
+  at zero (pinned by tests/test_tick_cache.py).
 
 Correctness contract: an incremental assemble must be BIT-IDENTICAL to a
 from-scratch assemble of the same state.  `paranoid_check` runs both
@@ -68,8 +68,8 @@ class TickPhaseStats:
     mapping, prefill (children /fill, /displace, /rebalance), decide, and
     total (the root).  A key with a `/` lies inside its parent in time
     (span catalog: docs/observability.md).  Surfaced through `hq server
-    stats` and bench.py --phases so a latency regression names its phase
-    instead of one opaque number.
+    stats` and the benchmark's `tick_phases_ms` so a latency regression
+    names its phase instead of one opaque number.
     """
 
     ticks: int = 0
@@ -106,11 +106,11 @@ class TickPhaseStats:
         lies inside its parent, so neither is in the denominator); a
         child's share is its part of the same whole.
 
-        The regression-blame side of the profiling plane (ISSUE 19):
-        bench smokes store these next to the profiler's per-plane CPU
-        shares, and ``--regress`` diffs both against the prior-row
-        median so a latency regression names the phase whose share grew
-        rather than one opaque wall-clock number."""
+        The per-phase half of the profiling plane's attribution (ISSUE
+        19): `hq server stats` and the simulator's results carry these
+        next to the profiler's per-plane CPU shares, so a latency
+        regression names the phase whose share grew rather than one
+        opaque wall-clock number."""
         total = sum(
             t for name, t in self.totals_ms.items()
             if name != "total" and "/" not in name

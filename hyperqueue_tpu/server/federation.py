@@ -483,7 +483,7 @@ class FederationCoordinator:
         except Exception:  # noqa: BLE001 - recovery must not kill the loop
             logger.exception("migration recovery failed")
         # HQ_REBALANCE_INTERVAL decouples the rebalancer's tick from the
-        # sampling interval: bench.py --reshard-smoke drives it fast and
+        # sampling interval: tests/test_migration.py drives it fast and
         # deterministically instead of sleeping for the sampler's cadence
         try:
             interval = float(

@@ -83,7 +83,7 @@ class SimResult:
     # with a --policy-file (None on the flat objective)
     policy: dict | None = None
     # TickStats.shares() of the final incarnation — the per-phase half of
-    # the PR 19 profile-blame summary (bench.py profile_summary)
+    # the PR 19 per-plane / per-phase attribution
     tick_shares: dict = field(repr=False, default_factory=dict)
 
     @property

@@ -1,7 +1,7 @@
 """What the solve needs to know about the JAX runtime it runs on.
 
 Two facts, kept out of the models so that every process that is about to
-touch JAX for the solve (server start, chip_smoke.py, bench.py) says them
+touch JAX for the solve (server start, chip_smoke.py) says them
 the same way: where compiled programs are kept between processes, and
 which device an array the solve returned actually lives on.
 """

@@ -534,7 +534,7 @@ def scrape(host: str, port: int, timeout: float = 5.0) -> str:
 def parse_exposition(text: str) -> dict:
     """Parse Prometheus text format into {name: {type, samples}} where
     samples is {(sample_name, frozenset(labels.items())): value}. Used by
-    the golden/e2e tests and `bench.py --metrics` — a real parser would be
+    the golden/e2e tests and the fleet proxy — a real parser would be
     a dependency; this handles exactly what `render` emits."""
     out: dict = {}
     types: dict[str, str] = {}
@@ -720,7 +720,7 @@ def histogram_summary(parsed: dict, name: str) -> dict:
     """Per-label-set {count, sum, mean, p50~, p95~, max_bucket} summary of a
     parsed histogram — percentile estimates from the cumulative bucket
     counts (upper bucket edge of the quantile's bucket). Feeds
-    `bench.py --metrics` and `hq job timeline`-adjacent tooling."""
+    the tests' scrape diffs and `hq job timeline`-adjacent tooling."""
     entry = parsed.get(name)
     if not entry or entry["type"] != "histogram":
         return {}

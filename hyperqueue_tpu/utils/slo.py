@@ -17,7 +17,7 @@ promptly once it stops). Two severities ship by default:
 - ``ticket`` — 6x burn over 6 h + 30 m (budget gone in ~5 days)
 
 All windows scale by ``HQ_SLO_WINDOW_SCALE`` so the simulator (virtual
-clock) and the bench smoke can compress hours into seconds without
+clock) and an ad-hoc drive can compress hours into seconds without
 touching the math. Evaluation is O(specs x rules) per tick and reads
 only cumulative counters, so it is cheap enough to run everywhere the
 registry lives: server reactor loop, standby watcher, simulator.
@@ -147,8 +147,8 @@ def alert_names(specs=DEFAULT_SPECS, rules=DEFAULT_RULES) -> list[str]:
 
 
 def window_scale() -> float:
-    """HQ_SLO_WINDOW_SCALE compresses every alert window (sim/bench:
-    hours become seconds without changing the burn-rate math)."""
+    """HQ_SLO_WINDOW_SCALE compresses every alert window (sim, ad-hoc
+    drives: hours become seconds without changing the burn-rate math)."""
     try:
         scale = float(os.environ.get("HQ_SLO_WINDOW_SCALE", "") or 1.0)
     except ValueError:

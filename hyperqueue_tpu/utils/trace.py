@@ -207,7 +207,7 @@ def new_trace_id() -> str:
 
 
 # span names, in causal order, for a task launched on a real worker; the
-# trace-smoke gate asserts a completed trace contains REQUIRED_HOPS
+# tests/test_trace.py asserts a completed trace contains REQUIRED_HOPS
 SPAN_ORDER = (
     "client/submit",   # client send -> server receive (client-stamped)
     "server/submit",   # receive -> tasks built + journal commit

@@ -310,6 +310,36 @@ def test_fleet_feed_proxy_and_stitched_trace_across_failover(tmp_path):
         assert job_id % 2 == 0  # (job_id - 1) % 2 == 1 -> shard 1
         wait_until(lambda: marker.exists(), message="task started")
 
+        # --- feed completeness: an array on shard 1 (its only worker is
+        # the borrowed one): every task-finished event reaches the feed
+        # exactly once, under the label of the shard that owns the job
+        n_array = 10
+        os.environ["HQ_SHARD"] = "1"
+        try:
+            array_out = env.command([
+                "submit", "--array", f"0-{n_array - 1}", "--", "true",
+            ])
+        finally:
+            os.environ.pop("HQ_SHARD", None)
+        array_job = int(array_out.split("job ID: ")[1].split()[0])
+        env.command(["job", "wait", str(array_job)], timeout=60)
+
+        def finished_seen() -> dict:
+            seen: dict = {}
+            for frame in list(frames):
+                if frame.get("op") != "events":
+                    continue
+                for rec in frame["records"]:
+                    if rec.get("event") == "task-finished" \
+                            and rec["job"] == array_job:
+                        key = (rec["shard"], rec["task"])
+                        seen[key] = seen.get(key, 0) + 1
+            return seen
+
+        wait_until(lambda: len(finished_seen()) >= n_array,
+                   message="fleet feed saw every task-finished event")
+        assert finished_seen() == {(1, t): 1 for t in range(n_array)}
+
         # --- metrics proxy: one scrape covers both shards -------------
         port = start_fleet_proxy(env.server_dir)
         text = scrape("127.0.0.1", port)

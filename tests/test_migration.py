@@ -9,7 +9,8 @@ Three tiers:
   destination, and driver each killed at every protocol phase), the
   SIGSTOP'd-source fence, O(chunks) lazy-job moves, and online N -> N+1
   — all on one virtual clock under the always-on invariant monitor;
-- one real-process end-to-end: live migration under a pinned HQ_SHARD
+- real-process end-to-ends: the rebalancer moving a job off a hot shard,
+  and live migration under a pinned HQ_SHARD
   session, including a chunked submit stream that follows the job to
   its new shard mid-stream.
 """
@@ -546,6 +547,55 @@ def test_e2e_migration_with_pinned_session(tmp_path):
         omap = OwnershipStore(env.server_dir).load()
         assert omap.shard_for_job(job_id) == 1
         assert not omap.in_flight()
+
+
+def test_e2e_rebalancer_moves_a_job_off_the_hot_shard(tmp_path):
+    """Real processes, the standby's `--rebalance` loop: every job lands
+    pinned on shard 0 while shard 1's worker is held busy by a small
+    pinned warm-up (so lending has no idle donor and the backlog can only
+    even out by migrating jobs).  The rebalancer must commit at least one
+    live migration to shard 1, and every submitted task must still finish
+    exactly once."""
+    with HqEnv(tmp_path) as env:
+        env.start_shard(0, 2, "--lease-timeout", "2")
+        env.start_shard(1, 2, "--lease-timeout", "2")
+        env.start_worker("--shard", "0", cpus=2)
+        env.start_worker("--shard", "1", cpus=2)
+        env.wait_workers(2)
+
+        def submit(shard, *args):
+            os.environ["HQ_SHARD"] = str(shard)
+            try:
+                out = env.command(["submit", *args])
+            finally:
+                os.environ.pop("HQ_SHARD", None)
+            return int(out.split("job ID: ")[1].split()[0])
+
+        warm = submit(1, "--array", "0-5", "--", "sleep", "1")
+        hot = [submit(0, "--array", "0-11", "--", "sleep", "1")
+               for _ in range(2)]
+        # the control loop on a fast fixed cadence, not the sampler's
+        env.start_standby("--lease-timeout", "2",
+                          "--coordinator-interval", "0.25", "--rebalance",
+                          env_extra={"HQ_REBALANCE_INTERVAL": "0.25"})
+        store = OwnershipStore(env.server_dir)
+        wait_until(lambda: store.load().assignments, timeout=60,
+                   message="the rebalancer committed a migration")
+
+        env.command(["job", "wait", "all"], timeout=120)
+        jobs = json.loads(env.command(
+            ["job", "list", "--all", "--output-mode", "json"]
+        ))
+        finished = {j["id"]: j["counters"]["finished"] for j in jobs}
+        assert finished == {warm: 6, hot[0]: 12, hot[1]: 12}
+        for job_id in hot:
+            ids = sorted(t["id"] for t in _job_info(env, job_id)["tasks"])
+            assert ids == list(range(12))       # exactly once, no gaps
+        omap = store.load()
+        moved = [j for j in hot if omap.shard_for_job(j) == 1]
+        assert moved and not omap.in_flight()
+        assert any(v for v in omap.verdicts)    # the verdicts are logged
+        assert "federation:" in env.command(["fleet", "status"])
 
 
 @pytest.mark.slow

@@ -34,8 +34,8 @@ from hyperqueue_tpu.ops.assign import (
 )
 from hyperqueue_tpu.parallel.solve import (
     make_worker_mesh,
-    place_tick_inputs,
-    sharded_cut_scan,
+    pack_batch_table,
+    sharded_cut_scan_donate,
 )
 from hyperqueue_tpu.utils.constants import INF_TIME
 
@@ -63,6 +63,18 @@ def _random_instance(rng, n_w, n_r, n_b, n_v, with_lifetimes=False):
     return free, nt_free, lifetime, needs, sizes, min_time
 
 
+def _sharded_solve(mesh, free, nt_free, lifetime, needs, sizes, min_time,
+                   class_m, order_ids):
+    """The resident tick's program (the one jitted sharded entry), as
+    `MultichipModel` calls it: per-batch inputs packed into one table,
+    free/nt_free as copies because the program consumes them."""
+    return sharded_cut_scan_donate(
+        mesh, free.copy(), nt_free.copy(), lifetime,
+        pack_batch_table(needs, sizes, min_time, order_ids), class_m,
+        extents=needs.shape,
+    )
+
+
 def _both_solves(free, nt_free, lifetime, needs, sizes, min_time):
     scarcity = np.asarray(
         scarcity_weights(free.astype(np.int64).sum(axis=0))
@@ -71,12 +83,10 @@ def _both_solves(free, nt_free, lifetime, needs, sizes, min_time):
     single, free_s, nt_s = greedy_cut_scan(
         free, nt_free, lifetime, needs, sizes, min_time, class_m, order_ids
     )
-    mesh = make_worker_mesh(8)
-    placed = place_tick_inputs(
-        mesh, free, nt_free, lifetime, needs, sizes, min_time, class_m,
-        order_ids,
+    sharded, free_d, nt_d = _sharded_solve(
+        make_worker_mesh(8), free, nt_free, lifetime, needs, sizes, min_time,
+        class_m, order_ids,
     )
-    sharded, free_d, nt_d = sharded_cut_scan(mesh, *placed)
     return (
         np.asarray(single), np.asarray(sharded),
         np.asarray(free_s), np.asarray(free_d),
@@ -184,6 +194,54 @@ def test_multichip_model_matches_greedy_model(seed):
         needs=needs, sizes=sizes, min_time=min_time,
     )
     np.testing.assert_array_equal(greedy.solve(**kwargs), multi.solve(**kwargs))
+
+
+def test_resident_sharded_ticks_equal_the_host_solve():
+    """Evolving ticks through ONE resident MultichipModel on the 8-device
+    mesh against the single-chip host solve of the same inputs: counts
+    bitwise equal every tick, with the delta-upload path engaged (one
+    worker completes everything between ticks) and the fresh-solve guard
+    armed on every solve."""
+    rng = np.random.default_rng(42)
+    n_w, n_r, n_b, n_v = 64, 4, 16, 2
+    free0 = (rng.integers(1, 9, size=(n_w, n_r)) * 4 * U).astype(np.int32)
+    nt0 = rng.integers(4, 17, size=n_w).astype(np.int32)
+    lifetime = np.full(n_w, INF_TIME, dtype=np.int32)
+    needs = (rng.integers(0, 3, size=(n_b, n_v, n_r)) * (U // 2)).astype(
+        np.int32
+    )
+    needs[:, 0, 0] = np.maximum(needs[:, 0, 0], U)
+    sizes = rng.integers(5, 12, size=n_b).astype(np.int32)
+    min_time = np.zeros((n_b, n_v), dtype=np.int32)
+    multi = MultichipModel()
+    multi.paranoid_resident = 1
+    host = GreedyCutScanModel(backend="numpy")
+    free, nt_free = free0.copy(), nt0.copy()
+    placed = 0
+    for tick in range(5):
+        kwargs = dict(lifetime=lifetime, needs=needs, sizes=sizes,
+                      min_time=min_time)
+        sharded = multi.solve(free=free.copy(), nt_free=nt_free.copy(),
+                              **kwargs)
+        single = host.solve(free=free.copy(), nt_free=nt_free.copy(),
+                            **kwargs)
+        np.testing.assert_array_equal(
+            sharded, single, err_msg=f"sharded diverged at tick {tick}"
+        )
+        placed += int(sharded.sum())
+        used = np.einsum(
+            "bvw,bvr->wr", sharded.astype(np.int64), needs.astype(np.int64)
+        )
+        free = (free - used).astype(np.int32)
+        nt_free = (nt_free - sharded.sum(axis=(0, 1))).astype(np.int32)
+        free[tick] = free0[tick]
+        nt_free[tick] = nt0[tick]
+    assert placed > 0
+    assert multi.last_backend == "device-sharded"
+    assert multi.get_mesh().devices.size == 8
+    stats = multi.resident_stats()
+    assert stats["full_uploads"] == 1 and stats["delta_uploads"] >= 1
+    assert multi.paranoid_checks == 5
 
 
 def test_multichip_model_single_device_fallback():

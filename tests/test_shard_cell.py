@@ -170,11 +170,15 @@ def test_the_gathers_carry_their_names_in_the_compiled_program():
     ).astype(np.float32)
     class_m, order_ids = host_visit_classes(free, needs, scarcity)
     mesh = solve.make_worker_mesh(4)
-    placed = solve.place_tick_inputs(
+    # the program `shard-16k.backlog` runs, as MultichipModel calls it
+    text = solve.sharded_cut_scan_donate.lower(
         mesh, free, np.full(n_w, 4, np.int32),
-        np.full(n_w, 2**31 - 1, np.int32), needs, np.full(n_b, 5, np.int32),
-        np.zeros((n_b, n_v), np.int32), class_m, order_ids)
-    text = solve.sharded_cut_scan.lower(mesh, *placed).compile().as_text()
+        np.full(n_w, 2**31 - 1, np.int32),
+        solve.pack_batch_table(
+            needs, np.full(n_b, 5, np.int32),
+            np.zeros((n_b, n_v), np.int32), order_ids),
+        class_m, extents=needs.shape,
+    ).compile().as_text()
     gathers = [line for line in text.splitlines()
                if " all-gather(" in line and "metadata" in line]
     assert gathers and all(solve.WATER_FILL_GATHER in g for g in gathers)

@@ -117,6 +117,30 @@ def test_dag_and_gang_workloads_complete():
     assert res.audit["finished"] == 63
 
 
+@pytest.mark.parametrize(
+    "name,kwargs,workers,seed",
+    [("gang", dict(n_gangs=8, gang_size=4, filler_tasks=600), 8, 11),
+     ("dag", dict(layers=12, width=30), 8, 5)],
+    ids=["gang-heavy", "stress-dag"],
+)
+def test_fused_scheduler_never_loses_to_host_greedy(name, kwargs, workers,
+                                                    seed):
+    """The same seeded workload under `greedy-numpy` and under the fused
+    gang/lookahead scheduler: in virtual time the fused makespan does not
+    exceed the host baseline, no task is lost, and every gang starts once,
+    atomically (the monitor's gang-atomicity invariant runs throughout)."""
+    wl = build(name, seed=seed, **kwargs)
+    base = run_scenario(wl, seed=seed, n_workers=workers,
+                        scheduler="greedy-numpy")
+    fused = run_scenario(wl, seed=seed, n_workers=workers,
+                         scheduler="greedy-fused")
+    for res in (base, fused):
+        assert res.audit["finished"] == wl.n_tasks
+        assert not res.violations
+        assert res.audit.get("gang_starts", 0) == kwargs.get("n_gangs", 0)
+    assert fused.makespan <= base.makespan + 1e-6
+
+
 # --- determinism regression (satellite) -------------------------------
 def test_same_seed_bit_identical_digests():
     faults = FaultSchedule(seed=5, events=[
@@ -194,16 +218,29 @@ def test_kill9_mid_chunked_submit_exactly_once():
 
 
 # --- seeded fault soak -------------------------------------------------
-def test_fault_soak_invariants_green():
-    wl = build("uniform", seed=13, n_tasks=400, dur_ms=1000)
-    names = [f"w{i}" for i in range(12)]
+@pytest.mark.parametrize(
+    "name,kwargs,workers,seed,rate,outlives_the_kill",
+    [("uniform", dict(n_tasks=400, dur_ms=1000), 12, 13, 0.05, True),
+     ("dag", dict(layers=8, width=16), 8, 7, 0.03, False),
+     ("gang", dict(n_gangs=6, gang_size=3, filler_tasks=300), 12, 7, 0.03,
+      True),
+     ("tail", dict(n_tasks=800), 12, 7, 0.03, True)],
+    ids=["uniform", "deep-dag", "gang-heavy", "straggler-tail"],
+)
+def test_fault_soak_invariants_green(name, kwargs, workers, seed, rate,
+                                     outlives_the_kill):
+    """Each workload shape under a generated fault schedule with one
+    server kill -9: nothing lost, every invariant green, and the server
+    restored where the run is long enough to meet the kill."""
+    wl = build(name, seed=seed, **kwargs)
+    names = [f"w{i}" for i in range(workers)]
     faults = FaultSchedule.generate(
-        13, horizon=40.0, worker_names=names, rate=0.05, server_kills=1,
+        seed, horizon=40.0, worker_names=names, rate=rate, server_kills=1,
     )
-    res = run_scenario(wl, seed=13, n_workers=12, faults=faults)
-    assert res.audit["finished"] == 400
+    res = run_scenario(wl, seed=seed, n_workers=workers, faults=faults)
+    assert res.audit["finished"] == wl.n_tasks
     assert not res.violations
-    assert res.server_boots >= 2
+    assert res.server_boots >= (2 if outlives_the_kill else 1)
 
 
 @pytest.mark.slow
@@ -281,6 +318,25 @@ def test_replay_same_scheduler_reproduces_makespan(tmp_path):
     # same recorded workload + same scheduler + same seed = the same run
     assert cmp_result.makespan_a == pytest.approx(cmp_result.makespan_b)
     assert cmp_result.assigned_a == cmp_result.assigned_b
+
+
+def test_replay_compares_host_and_fused_on_a_gang_journal(tmp_path):
+    """A recorded gang run rebuilt from its journal and replayed under
+    `greedy-numpy` and `greedy-fused`: both place every task of the
+    recording."""
+    from hyperqueue_tpu.sim.replay import replay_compare
+
+    wl = build("gang", seed=3, n_gangs=4, gang_size=3, filler_tasks=150)
+    recorded = run_scenario(wl, seed=3, n_workers=9,
+                            server_dir=tmp_path / "rec")
+    assert recorded.audit["finished"] == wl.n_tasks
+    cmp_result = replay_compare(
+        tmp_path / "rec" / "journal.bin", "greedy-numpy", "greedy-fused",
+        seed=3, n_workers=9,
+    )
+    assert cmp_result.makespan_a > 0 and cmp_result.makespan_b > 0
+    assert cmp_result.assigned_a == cmp_result.assigned_b > 0
+    assert "makespan" in cmp_result.summary()
 
 
 # --- metrics hygiene (satellite) ----------------------------------------

@@ -265,3 +265,48 @@ def test_server_readyz_flips_on_journal_death_and_lease_loss(tmp_path):
     server.slo._firing.clear()
     ok, _ = server._probe_readyz()
     assert ok
+
+
+def test_server_alerts_rpc_pages_on_slow_ticks_and_resolves(tmp_path):
+    """The server's own engine over the default catalog, read through the
+    `hq alerts` RPC: ticks over the 250 ms objective in the series the
+    tick itself feeds (`hq_tick_phase_seconds{phase="total"}`) fire
+    `tick-latency` at page severity, readiness follows, and good ticks
+    resolve it."""
+    from hyperqueue_tpu.server.reactor import _TICK_PHASE_SECONDS
+
+    server = _server(tmp_path)
+    ticks = _TICK_PHASE_SECONDS.labels("total")
+
+    def alerts():
+        return asyncio.run(server._client_alerts({"op": "alerts"}))
+
+    try:
+        for _ in range(10):
+            ticks.observe(0.4)
+        server.slo.evaluate(now=0.0)        # the baseline sample
+        assert alerts()["firing"] == []
+        for _ in range(10):
+            ticks.observe(0.4)
+        server.slo.evaluate(now=10.0)
+        out = alerts()
+        assert out["op"] == "alerts"
+        firing = {a["alert"]: a for a in out["firing"]}
+        assert firing["tick-latency:page"]["state"] == "firing"
+        assert firing["tick-latency:page"]["severity"] == "page"
+        assert server._alert_badge()["worst"] == "page"
+        ok, detail = server._probe_readyz()
+        assert not ok and "tick-latency:page" in detail["checks"]["slo"]
+
+        for _ in range(500):
+            ticks.observe(0.01)
+        server.slo.evaluate(now=400.0)      # past the 5 m short window
+        out = alerts()
+        assert not [a for a in out["firing"] if a["slo"] == "tick-latency"
+                    and a["severity"] == "page"]
+        assert [t["state"] for t in out["recent"]
+                if t["alert"] == "tick-latency:page"] == [
+            "firing", "resolved"
+        ]
+    finally:
+        server.slo.reset()

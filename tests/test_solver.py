@@ -444,3 +444,45 @@ def test_affinity_reorders_water_fill():
         affinity=np.asarray([[1.0, 3.0, 2.0]], dtype=np.float32),
     )
     assert counts[0, 0].tolist() == [0, 4, 2]
+
+
+@pytest.mark.parametrize("kernel", ["jitted", "numpy"])
+@pytest.mark.parametrize("units", [21, 39, 42, 63])
+def test_capacity_quotient_is_exact_where_float32_is_not(units, kernel):
+    """The kernel takes free // need as a float32 multiply by the
+    reciprocal plus an integer correction.  For needs of 21, 39, 42 and 63
+    units (10 000 to the unit, as `hetero-1k`'s 21-cpu class on 42-core
+    nodes) the float32 product alone falls short of k at exactly k * need
+    free: a worker with k * need free takes k tasks, one with a fraction
+    less takes k - 1."""
+    from hyperqueue_tpu.ops.assign import (
+        greedy_cut_scan,
+        greedy_cut_scan_numpy,
+        host_visit_classes,
+        scarcity_weights,
+    )
+
+    need = units * U
+    ks = [1, 2, 3, 7, 13]
+    assert max(ks) * need < 2**23  # the kernel's float32-exact range
+    free = np.asarray(
+        [[k * need - short] for k in ks for short in (0, 1)], dtype=np.int32
+    )
+    n_w = len(free)
+    needs = np.asarray([[[need]]], dtype=np.int32)
+    scarcity = np.asarray(
+        scarcity_weights(free.astype(np.int64).sum(axis=0))
+    ).astype(np.float32)
+    class_m, order_ids = host_visit_classes(free, needs, scarcity)
+    solve = greedy_cut_scan if kernel == "jitted" else greedy_cut_scan_numpy
+    counts, free_after, _nt = solve(
+        free.copy(), np.full(n_w, 64, dtype=np.int32),
+        np.full(n_w, INF, dtype=np.int32), needs,
+        np.asarray([1000], dtype=np.int32), np.zeros((1, 1), dtype=np.int32),
+        class_m, order_ids,
+    )
+    want = [k - short for k in ks for short in (0, 1)]
+    assert np.asarray(counts)[0, 0].tolist() == want
+    assert np.asarray(free_after)[:, 0].tolist() == [
+        int(f) - n * need for f, n in zip(free[:, 0], want)
+    ]

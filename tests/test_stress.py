@@ -1,7 +1,7 @@
 """Soak: concurrent clients + worker churn against one server.
 
 A fast race-shaker (reference stresses this shape via
-benchmarks/experiment-scalability-stress.py and tests killing workers):
+its experiment-scalability-stress.py benchmark and tests killing workers):
 many interleaved submits from parallel client processes while workers die
 and rejoin mid-flight; every job must still converge, with crash retries
 absorbing the churn.

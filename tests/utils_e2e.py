@@ -133,8 +133,7 @@ def _env_base() -> dict:
 def start_fleet_proxy(root: Path, host: str = "127.0.0.1",
                       timeout: float = 10.0) -> int:
     """Run the fleet metrics proxy on an ephemeral port in a daemon
-    thread; returns the bound port (shared by tests/test_fleet.py and
-    bench.py --fleet-smoke). Raises RuntimeError — carrying the proxy's
+    thread; returns the bound port. Raises RuntimeError — carrying the proxy's
     own startup error when there is one — if it fails to bind."""
     import asyncio
     import threading
