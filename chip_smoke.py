@@ -384,13 +384,18 @@ def checked(model_cls, reference):
     (a pipelined solve's counts arrive a tick after its inputs)."""
     import numpy as np
 
+    from hyperqueue_tpu.ops.answer import dense_of_cells
+
     class Handle:
         def __init__(self, inner, record):
             self.inner, self.record = inner, record
 
-        def result(self):
-            self.record["got"] = got = self.inner.result()
+        def cells(self):
+            self.record["got"] = got = self.inner.cells()
             return got
+
+        def result(self):
+            return dense_of_cells(self.cells())
 
     class Checked(model_cls):
         solves_checked = 0
@@ -402,9 +407,9 @@ def checked(model_cls, reference):
             self.shape_key = prep["shape_key"]
             return Handle(super()._device_solve(prep), record)
 
-        def _maybe_paranoid_check(self, prep, out):
+        def _maybe_paranoid_check(self, prep, cells):
             t = time.perf_counter()
-            super()._maybe_paranoid_check(prep, out)
+            super()._maybe_paranoid_check(prep, cells)
             self.guard_ms = (time.perf_counter() - t) * 1e3
 
         def verify(self):
@@ -418,6 +423,8 @@ def checked(model_cls, reference):
                 got, want = record["got"], record["want"]
                 if got is None:
                     continue  # in flight: its counts come next tick
+                # the cells the mapping read, whatever form they crossed in
+                got = dense_of_cells(got)
                 if not np.array_equal(got, want):
                     diff = np.argwhere(got != want)
                     at = tuple(diff[0])
@@ -450,8 +457,83 @@ def _uploads(model) -> dict:
     return {
         key: stats.get(key, 0)
         for key in ("upload_bytes_total", "full_uploads", "delta_uploads",
-                    "dirty_rows_last")
+                    "dirty_rows_last", "readbacks_total",
+                    "readback_bytes_total", "answers_total",
+                    "answers_compact", "answers_dense_small",
+                    "answers_overflow")
     }
+
+
+def check_answers(where: str, before: dict, after: dict, solves: int) -> dict:
+    """One readback a solve, and one more for each answer whose compact
+    form overflowed (the dense fallback); returns the counters' deltas."""
+    moved = {key: after[key] - before[key] for key in after
+             if key.startswith(("answers_", "readback"))}
+    check(f"{where}: one packed answer a solve",
+          moved["answers_total"] == solves
+          and moved["readbacks_total"]
+          == solves + moved["answers_overflow"], moved)
+    return moved
+
+
+def check_overflow_is_exact(where: str, mesh=None) -> dict:
+    """A solve forced over K: synthetic padded counts with exactly K cells
+    on one device unpack to numpy's cells; with K + 1 there (the total
+    still under the whole buffer's capacity on a mesh) the buffer says it
+    overflowed and the dense fallback's slice gives the same cells."""
+    import jax
+    import numpy as np
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from hyperqueue_tpu.ops import answer
+
+    devices = 1 if mesh is None else int(mesh.devices.size)
+    pb, pv, pw, pr = 16, 2, 512 * devices, 4
+    extents = (13, 2, pw - 3)
+    layout = answer.layout_for(extents, (pb, pv, pw, pr), devices)
+    k = layout.capacity
+    rng = np.random.default_rng(30)
+
+    def put(arr, spec):
+        if mesh is None:
+            return jax.device_put(arr)
+        return jax.device_put(arr, NamedSharding(mesh, spec))
+
+    free = rng.integers(0, 99, (pw, pr)).astype(np.int32)
+    nt = rng.integers(0, 9, pw).astype(np.int32)
+    seen = {}
+    for n_cells in (k, k + 1):
+        counts = np.zeros((pb, pv, pw), np.int32)
+        # all on device 0's live columns, spread over the live rows
+        cols = min(k, extents[2])
+        at = rng.choice(extents[0] * pv * cols, n_cells, replace=False)
+        counts[at // (pv * cols), at // cols % pv, at % cols] = rng.integers(
+            1, 2**30, n_cells
+        )
+        live = np.ascontiguousarray(
+            counts[:extents[0], :extents[1], :extents[2]]
+        )
+        want = answer.cells_of_dense(live)
+        counts_d = put(counts, P(None, None, "w"))
+        buf = np.asarray(answer.pack_answer(
+            counts_d, put(free, P("w", None)), put(nt, P("w")), layout, mesh
+        ))
+        cells, free_after, nt_after = answer.unpack_answer(buf, layout)
+        check(f"{where}: the state part of the buffer is the state",
+              np.array_equal(free_after, free)
+              and np.array_equal(nt_after, nt), n_cells)
+        if n_cells > k:
+            check(f"{where}: K + 1 cells on one device overflow",
+                  cells is None, buf[:, 0].tolist())
+            cells = answer.cells_of_dense(np.asarray(
+                answer.live_slicer(*extents)(counts_d)
+            ))
+        check(f"{where}: {n_cells} cells (K = {k}) cross exactly",
+              cells is not None
+              and np.array_equal(cells.flat, want.flat)
+              and np.array_equal(cells.vals, want.vals), n_cells)
+        seen[n_cells] = int(want.flat.size)
+    return {"K": k, "devices": devices, "cells": seen}
 
 
 def _tick_line(model, before: dict, phases_ms: dict, **head) -> dict:
@@ -581,6 +663,9 @@ def width() -> dict:
         after = line("sync", i, out, phases, before)
         check("width: sync tick is a full upload",
               after["full_uploads"] - before["full_uploads"] == 1, after)
+        # filling 1 024 empty workers may set more cells than K = 1 024:
+        # then the dense fallback, compared with numpy like every solve
+        check_answers("width: sync", before, after, 1)
         release(out)
         requeue(out)
         model.invalidate_resident()
@@ -603,6 +688,10 @@ def width() -> dict:
                   after["delta_uploads"] - before["delta_uploads"] == 1
                   and after["full_uploads"] == before["full_uploads"],
                   (before, after))
+            moved = check_answers("width: resident", before, after, 1)
+            check("width: a steady tick's answer crosses compact, once",
+                  moved["answers_compact"] == 1
+                  and moved["readbacks_total"] == 1, moved)
         churn()
 
     # -- dispatched through the pipeline: tick k maps solve k-1
@@ -643,10 +732,12 @@ def width() -> dict:
           model.paranoid_checks == 3 * TICKS, model.paranoid_checks)
     check("width: solved on the device",
           model.last_backend == "device-jax", model.last_backend)
+    forced = check_overflow_is_exact("width")
     device = jax.devices()[0]
     memory = device.memory_stats() or {}
     return {
         "phase": "width",
+        "forced_over_K": forced,
         "world": WIDTH_CELL,
         "workers": len(core.workers),
         "ready_tasks": len(world.task_class),
@@ -712,6 +803,7 @@ def sharded(n_workers: int, n_tasks: int, n_devices: int) -> dict:
     model.paranoid_resident = 1
 
     tick_ms = []
+    solves = 0
     for i in range(TICKS):
         before = _uploads(model)
         t = time.perf_counter()
@@ -719,10 +811,13 @@ def sharded(n_workers: int, n_tasks: int, n_devices: int) -> dict:
         tick_ms.append((time.perf_counter() - t) * 1e3)
         model.verify()
         core.sanity_check()
-        _tick_line(model, before, mode="sharded", tick=i, assigned=assigned,
-                   tick_ms=round(tick_ms[-1], 3),
-                   phases_ms=core.tick_stats.last_ms, **compiles.snapshot())
+        after = _tick_line(
+            model, before, mode="sharded", tick=i, assigned=assigned,
+            tick_ms=round(tick_ms[-1], 3),
+            phases_ms=core.tick_stats.last_ms, **compiles.snapshot())
         check("sharded: tick assigned work", assigned > 0, assigned)
+        check_answers("sharded", before, after, len(shard_log) - solves)
+        solves = len(shard_log)
         # completions and a new wave of submits before the next tick
         state.finish_some(256)
         state.submit_wave(n_tasks // 5)
@@ -771,8 +866,13 @@ def sharded(n_workers: int, n_tasks: int, n_devices: int) -> dict:
     }
     check("sharded: program has a collective", sum(collectives.values()) > 0,
           collectives)
+    answers = model.resident_stats()
+    check("sharded: an answer crossed compact",
+          answers["answers_compact"] > 0, answers)
+    forced = check_overflow_is_exact("sharded", model._mesh)
     return {
         "phase": "sharded",
+        "forced_over_K": forced,
         "workers": n_workers,
         "worker_bucket": model._worker_bucket(n_workers),
         "ready_tasks_first_tick": n_tasks,

@@ -14,8 +14,10 @@ need rows which `_variant_capacity` masks off.
 Device path (new in the device-resident tick): the padded state stays
 RESIDENT on the accelerator (parallel/resident.py) — per-tick uploads are
 only the dirty-row delta, the solve donates its buffers so free_after/nt_after
-of solve N feed solve N+1 on-device, and the padded counts are sliced to the
-live (B, V, W) extents ON the device before readback.  Backend choice is a
+of solve N feed solve N+1 on-device, and the answer crosses to the host ONCE
+and compact: a packing program behind the kernel (ops/answer.py) writes the
+nonzero cells of the counts, `free_after` and `nt_after` into one buffer, so
+a solve costs one device-to-host round trip.  Backend choice is a
 per-solve cost model over measured host and device times with a periodically
 re-probed sync latency, so one slow probe does not disable the device path
 for the life of the process.
@@ -23,12 +25,20 @@ for the life of the process.
 
 from __future__ import annotations
 
-import functools
 import threading
 import time
 
 import numpy as np
 
+from hyperqueue_tpu.ops.answer import (
+    SolveCells,
+    cells_of_dense,
+    dense_of_cells,
+    layout_for,
+    live_slicer,
+    pack_answer,
+    unpack_answer,
+)
 from hyperqueue_tpu.ops.assign import (
     greedy_cut_scan,
     greedy_cut_scan_numpy,
@@ -163,25 +173,9 @@ class ResidentParanoidError(AssertionError):
     hide the bug and destroy the evidence via resident invalidation)."""
 
 
-@functools.lru_cache(maxsize=64)
-def _device_slicer(n_b: int, n_v: int, n_w: int):
-    """Jitted padded->live slicer: the device path trims the padded
-    (PB, PV, PW) counts to the live extents ON the device, so the host
-    readback never copies (or receives) the padded volume and the
-    resulting numpy array is C-contiguous (scheduler/tick.py relies on
-    that to use the native nonzero on both backends).  Compiled once per
-    distinct extent triple — live extents repeat in steady state."""
-    import jax
-
-    @jax.jit
-    def slice_live(c):
-        return c[:n_b, :n_v, :n_w]
-
-    return slice_live
-
-
 class _ReadyCounts:
-    """Solve handle whose result is already materialized (host paths)."""
+    """Solve handle whose result is already materialized (host paths):
+    dense counts, and their cells by one nonzero pass when asked."""
 
     __slots__ = ("_counts",)
 
@@ -191,52 +185,75 @@ class _ReadyCounts:
     def result(self) -> np.ndarray:
         return self._counts
 
+    def cells(self) -> SolveCells:
+        return cells_of_dense(self._counts)
+
 
 class _DeviceCounts:
-    """In-flight device solve: `result()` materializes the (device-sliced)
-    counts, re-synchronizes the residency mirror from the donated outputs,
-    and feeds the cost model.  The dispatch is asynchronous — between
-    construction and `result()` the device executes while the host does
-    other tick work (the pipelined tick exploits exactly this window)."""
+    """In-flight device solve.  `cells()` waits for kernel and packer,
+    makes the solve's ONE readback (the packed answer, ops/answer.py),
+    unpacks the cells for the mapping, re-synchronizes the residency
+    mirror from the state part of the same buffer, and feeds the cost
+    model; `result()` is the dense (B, V, W) counts, built from the cells
+    for callers that want the array.  Where the buffer's compact form
+    overflowed, the dense live slice of the counts (kept on the device
+    until here) is read instead: the same cells, counted as `overflow`.
+    The dispatch is asynchronous — between construction and `cells()` the
+    device executes while the host does other tick work (the pipelined
+    tick exploits exactly this window)."""
 
-    __slots__ = ("_model", "_res", "_counts_dev", "_after", "_prep")
+    __slots__ = ("_model", "_res", "_packed", "_counts_dev", "_layout",
+                 "_prep", "_cells")
 
-    def __init__(self, model, res, counts_dev, after, prep):
+    def __init__(self, model, res, packed, counts_dev, layout, prep):
         self._model = model
         self._res = res
-        self._counts_dev = counts_dev
-        self._after = after  # (free_after, nt_after) device arrays
+        self._packed = packed          # (D, L) device buffer
+        self._counts_dev = counts_dev  # padded counts, for the fallback
+        self._layout = layout
         self._prep = prep
+        self._cells = None
 
     def result(self) -> np.ndarray:
+        return dense_of_cells(self.cells())
+
+    def cells(self) -> SolveCells:
+        if self._cells is not None:
+            return self._cells
         model = self._model
         prep = self._prep
         res = self._res
         phases = prep["phases"]
         with TRACER.phase(phases, "device_sync"):
-            # the wait for the kernel, then the counts' readback
+            # the wait for kernel and packer, then the one readback (and
+            # the dense one behind it where the compact form overflowed)
             with TRACER.phase(phases, "device_sync/counts"):
-                out = res.read_back(self._counts_dev)
-            if self._after is not None:
-                # the two state readbacks and the mirror's copy
-                with TRACER.phase(phases, "device_sync/state"):
-                    free_after, nt_after = self._after
-                    res.apply_outputs(
-                        res.read_back(free_after), res.read_back(nt_after)
+                buf = res.read_back(self._packed)
+                cells, free_after, nt_after = unpack_answer(
+                    buf, self._layout
+                )
+                if cells is None:
+                    dense = res.read_back(
+                        live_slicer(*self._layout.extents)(self._counts_dev)
                     )
+                    cells = cells_of_dense(dense, form="overflow")
+                res.count_answer(cells.form)
+            # the state part of the same buffer, copied into the mirror
+            with TRACER.phase(phases, "device_sync/state"):
+                res.apply_outputs(free_after, nt_after)
+        self._cells = cells
+        self._packed = self._counts_dev = None
         # the cost the TICK pays: dispatch + readback wait.  Synchronous
-        # solves call result() immediately, so device_sync contains the
-        # whole device execution; pipelined solves call it a tick later,
-        # when the execution already overlapped host work — charging the
-        # idle gap would wrongly bench the device in the cost model.
+        # solves ask at once, so device_sync contains the whole device
+        # execution; pipelined solves ask a tick later, when the execution
+        # already overlapped host work — charging the idle gap would
+        # wrongly bench the device in the cost model.
         model._observe(
             "device", prep["shape_key"],
             phases["solve_dispatch"] + phases["device_sync"],
         )
-        model._maybe_paranoid_check(prep, out)
-        if not out.flags.c_contiguous:  # pragma: no cover - np.asarray copy
-            out = np.ascontiguousarray(out)
-        return out
+        model._maybe_paranoid_check(prep, cells)
+        return cells
 
 
 class GreedyCutScanModel:
@@ -444,17 +461,28 @@ class GreedyCutScanModel:
             group_onehot=group_onehot, affinity=affinity,
         ).result()
 
-    def solve_async(
+    def solve_cells(self, *args, **kwargs) -> SolveCells:
+        """`solve`, answered with the nonzero cells of the counts
+        (ops/answer.SolveCells) and not the dense array: what the tick's
+        mapping reads.  A device solve's cells come straight from its
+        packed readback; the dense counts are never built."""
+        return self._dispatch(*args, **kwargs).cells()
+
+    def solve_async(self, *args, **kwargs):
+        """Dispatch one solve (`solve`'s arguments); returns a handle whose
+        `.cells()` yields the nonzero cells of the unpadded counts and
+        whose `.result()` the dense counts.  Host backends compute eagerly
+        (the handle is just a box); the device backend returns with the
+        program ENQUEUED, so the caller can overlap host work with the
+        device execution — the pipelined tick (scheduler/pipeline.py) maps
+        the previous solve during exactly this window."""
+        return self._dispatch(*args, **kwargs)
+
+    def _dispatch(
         self, free, nt_free, lifetime, needs, sizes, min_time,
         priorities=None, total=None, all_mask=None, weights=None,
         gang_nodes=None, gang_ok=None, group_onehot=None, affinity=None,
     ):
-        """Dispatch one solve; returns a handle whose `.result()` yields the
-        unpadded counts.  Host backends compute eagerly (the handle is just
-        a box); the device backend returns with the program ENQUEUED, so
-        the caller can overlap host work with the device execution — the
-        pipelined tick (scheduler/pipeline.py) maps the previous solve
-        during exactly this window."""
         self.last_phases = phases = {}
         with TRACER.phase(phases, "solve_host_prep"):
             prep = self._prepare(
@@ -714,20 +742,23 @@ class GreedyCutScanModel:
                     prep["free_p"], prep["nt_p"], prep["life_p"],
                     prep["total_p"],
                 )
-            # placing the replicated inputs, enqueueing kernel and slicer
+            # placing the replicated inputs, enqueueing kernel and packer
             with TRACER.phase(phases, "solve_dispatch/launch"):
                 counts, free_after, nt_after = self._kernel_dispatch(
                     res, free_d, nt_d, life_d, total_d, prep
                 )
                 res.adopt_outputs(free_after, nt_after)
-                n_b, n_v, n_w = prep["extents"]
-                counts_dev = _device_slicer(n_b, n_v, n_w)(counts)
+                layout = layout_for(
+                    prep["extents"], counts.shape + free_after.shape[1:],
+                    res.mesh_devices,
+                )
+                packed = pack_answer(
+                    counts, free_after, nt_after, layout, mesh=res.mesh
+                )
         self.last_backend = self._device_backend_name
-        self.last_device = device_block(counts_dev)
+        self.last_device = device_block(packed)
         self._resident_solves += 1
-        return _DeviceCounts(
-            self, res, counts_dev, (free_after, nt_after), prep
-        )
+        return _DeviceCounts(self, res, packed, counts, layout, prep)
 
     def _kernel_dispatch(self, res, free_d, nt_d, life_d, total_d, prep):
         """Enqueue the jitted kernel on the resident buffers (donating
@@ -748,10 +779,12 @@ class GreedyCutScanModel:
             policy_mask=res.place_cached("policy_mask", prep["pmask_p"]),
         )
 
-    def _maybe_paranoid_check(self, prep, out: np.ndarray) -> None:
+    def _maybe_paranoid_check(self, prep, cells: SolveCells) -> None:
         """Resident-vs-fresh bit-exactness guard: re-run the SAME padded
-        inputs through a fresh full-upload device solve and assert count
-        equality.  The padded buffers are untouched between dispatch and
+        inputs through a fresh full-upload device solve and assert that
+        its cells (read back dense and found on the host: a path that
+        shares nothing with the packed answer) equal the resident
+        solve's.  The padded buffers are untouched between dispatch and
         result (the pipeline maps a pending solve before preparing the
         next), so the comparison is exact by construction."""
         if (
@@ -760,10 +793,14 @@ class GreedyCutScanModel:
         ):
             return
         self.paranoid_checks += 1
-        fresh = self._fresh_device_counts(prep)
         n_b, n_v, n_w = prep["extents"]
-        fresh = np.asarray(fresh)[:n_b, :n_v, :n_w]
-        if not np.array_equal(out, fresh):
+        fresh = cells_of_dense(np.ascontiguousarray(
+            np.asarray(self._fresh_device_counts(prep))[:n_b, :n_v, :n_w]
+        ))
+        if not (
+            np.array_equal(cells.flat, fresh.flat)
+            and np.array_equal(cells.vals, fresh.vals)
+        ):
             raise ResidentParanoidError(
                 "paranoid-resident: device-resident counts diverge from a "
                 "fresh full-upload solve of the same padded inputs"

@@ -19,9 +19,11 @@ across ticks and makes each solve pay only for what changed:
 - the solve runs with `free`/`nt_free` DONATED (ops/assign.greedy_cut_scan
   and parallel/solve.sharded_cut_scan_donate), so `free_after`/`nt_after`
   of solve N become the resident inputs of solve N+1 with zero host
-  traffic; the mirror is re-synchronized from a readback of the (small)
-  `free_after`/`nt_after` arrays that rides the same device round trip as
-  the counts (`apply_outputs`);
+  traffic; the mirror is re-synchronized from the (small) `free_after`/
+  `nt_after` arrays, which ride the same device round trip as the answer:
+  the packing program (ops/answer.py) puts them behind the solve's cells in
+  the ONE buffer a solve reads back, and `apply_outputs` takes them from
+  there;
 - small replicated inputs (needs / sizes / min_time / class_m / order_ids)
   are placement-cached by content: a steady-state tick that repeats the
   same batch layout re-uses the device buffers outright.
@@ -69,9 +71,11 @@ class DeviceResidency:
     def __init__(self, shardings=None, device=None):
         self._shardings = shardings
         self._device = device
-        # devices that hold the state: a replicated put crosses to each
+        # the mesh the state is sharded over (None: one device) and the
+        # devices that hold it: a replicated put crosses to each
+        self.mesh = None if shardings is None else shardings[2].mesh
         self.mesh_devices = (
-            1 if shardings is None else int(shardings[2].mesh.devices.size)
+            1 if self.mesh is None else int(self.mesh.devices.size)
         )
         self.key = None            # (pw, pr, has_total) of the resident state
         self.free = None           # device (pw, pr) int32
@@ -99,6 +103,10 @@ class DeviceResidency:
         self.invalidations = 0
         self.readbacks_total = 0
         self.readback_bytes_total = 0
+        # the form each solve's answer crossed in (ops/answer.py): chosen
+        # by the extents (compact / dense-small), or the dense fallback a
+        # compact buffer that overflowed forces (`overflow`)
+        self.answers = {"compact": 0, "dense-small": 0, "overflow": 0}
 
     # -- placement helpers ------------------------------------------------
     def _put_bytes(self, nbytes: int, kind: int) -> int:
@@ -231,11 +239,15 @@ class DeviceResidency:
         self.readback_bytes_total += int(host.nbytes)
         return host
 
+    def count_answer(self, form: str) -> None:
+        self.answers[form] += 1
+
     def apply_outputs(self, free_after_host, nt_after_host) -> None:
         """Re-synchronize the mirror with the donated outputs: the caller
-        reads `free_after`/`nt_after` back alongside the counts (one round
-        trip) and hands the host arrays here.  Copied because jax readbacks
-        can be non-writable views and the mirror must accept row scatters.
+        reads `free_after`/`nt_after` back inside the solve's packed answer
+        (one round trip) and hands the host arrays here.  Copied because
+        they are views into that readback, which can be non-writable, and
+        the mirror must accept row scatters.
 
         This is exact for EVERY kernel feature (including ALL-policy pool
         zeroing) because the mirror is literally the device's output."""
@@ -295,4 +307,8 @@ class DeviceResidency:
             "invalidations": self.invalidations,
             "readbacks_total": self.readbacks_total,
             "readback_bytes_total": self.readback_bytes_total,
+            "answers_total": sum(self.answers.values()),
+            "answers_compact": self.answers["compact"],
+            "answers_dense_small": self.answers["dense-small"],
+            "answers_overflow": self.answers["overflow"],
         }

@@ -40,7 +40,7 @@ from hyperqueue_tpu.utils.trace import TRACER
 class PendingSolve:
     """One dispatched-but-unmapped solve."""
 
-    handle: object            # .result() -> unpadded counts (B, V, W)
+    handle: object            # .cells() -> the solve's nonzero count cells
     batches: list             # solve-ordered batches at dispatch time
     worker_ids: list          # row -> worker_id at dispatch time
     queues: object            # TaskQueues to pop from at map time
@@ -90,6 +90,7 @@ class TickPipeline:
         The wait for the device result is timed separately
         (`pipeline_wait` phase): in steady state it is ~zero because the
         device ran during the inter-tick host work."""
+        from hyperqueue_tpu.ops.answer import handle_cells
         from hyperqueue_tpu.scheduler.tick import (
             _map_counts,
             fold_model_phases,
@@ -100,15 +101,13 @@ class TickPipeline:
             return []
         self.pending = None
         with TRACER.phase(phases, "pipeline_wait") as wait:
-            counts = pending.handle.result()
+            cells = handle_cells(pending.handle)
         _t1 = _time.perf_counter()
         self.last_wait_ms = wait.seconds * 1e3
         # the wait's split (device_sync/counts, device_sync/state) is the
         # model's; `pipeline_wait` is their parent in this tick
         fold_model_phases(phases, model, prefix="device_sync/")
         if decision is not None:
-            import numpy as np
-
             if model is not None and getattr(
                 model, "last_solve_skipped", False
             ):
@@ -134,10 +133,10 @@ class TickPipeline:
                 # renders the solve where it actually EXECUTED
                 "dispatched_at_wall": pending.dispatched_wall,
                 "mapped_at_wall": clock.now(),
-                "objective": int(np.asarray(counts).sum()),
+                "objective": int(cells.vals.sum()),
             }
         assignments = _map_counts(
-            pending.queues, pending.batches, pending.worker_ids, counts,
+            pending.queues, pending.batches, pending.worker_ids, cells,
             phases=phases,
         )
         self.mapped += 1

@@ -18,6 +18,7 @@ from dataclasses import dataclass
 
 import numpy as np
 
+from hyperqueue_tpu.ops.answer import SolveCells, model_cells
 from hyperqueue_tpu.ops.assign import PREFIX_FORMULATION
 from hyperqueue_tpu.utils.constants import INF_TIME
 from hyperqueue_tpu.resources.map import ResourceIdMap, ResourceRqMap
@@ -58,6 +59,17 @@ _SOLVES_BY_PREFIX = REGISTRY.counter(
     "dense solves by the formulation of the water-fill's prefix sum over "
     "the workers (shifted-adds: the jitted kernel; cumsum: the host scans)",
     labels=("impl",), max_series=4,
+)
+
+
+# how each mapped answer reached the host (ops/answer.py): a run shows
+# whether its device solves crossed compact, and how often one overflowed
+_SOLVE_ANSWERS = REGISTRY.counter(
+    "hq_solve_answer_total",
+    "dense solves by the form their answer reached the host in (compact / "
+    "dense-small: the device's packed readback; overflow: its dense "
+    "fallback; host: a host solve's own nonzero)",
+    labels=("form",), max_series=4,
 )
 
 
@@ -728,7 +740,7 @@ def _run_main_solve(queues, workers, rq_map, resource_map, model, batches,
         ))
         return []
     _t1 = _time.perf_counter()  # the decision record's own reading
-    counts = model.solve(**kwargs)
+    cells = model_cells(model, kwargs)
     _t2 = _time.perf_counter()
     _count_solve(model, kwargs["needs"])
     fold_model_phases(phases, model)
@@ -749,56 +761,39 @@ def _run_main_solve(queues, workers, rq_map, resource_map, model, batches,
             "backend": getattr(model, "last_backend", None),
             "backend_reason": getattr(model, "last_backend_reason", ""),
             "solve_ms": round((_t2 - _t1) * 1e3, 4),
-            "objective": int(np.asarray(counts).sum()),
+            "objective": int(cells.vals.sum()),
         }
 
     worker_ids = (
         dense.worker_ids if dense is not None
         else [w.worker_id for w in workers]
     )
-    return _map_counts(queues, batches, worker_ids, counts, phases=phases)
+    return _map_counts(queues, batches, worker_ids, cells, phases=phases)
 
 
-def _map_counts(queues, batches, worker_ids, counts,
+def _map_counts(queues, batches, worker_ids, cells: SolveCells,
                 phases=None) -> list[Assignment]:
-    """Pop the solver's counts out of the queues as Assignment tuples.
+    """Pop the solver's nonzero count cells out of the queues as Assignment
+    tuples.
 
     The one mapping path for the synchronous tick AND the pipelined tick
-    (scheduler/pipeline.TickPipeline.take_result): `batches`/`worker_ids`
-    are the solve-time snapshot, `queues` is live — a cell whose tasks
-    were canceled (or stolen by prefill) while a pipelined solve was in
-    flight simply pops fewer ids than the count, which is safe.
+    (scheduler/pipeline.TickPipeline.take_result), for every backend:
+    `batches`/`worker_ids` are the solve-time snapshot, `queues` is live — a
+    cell whose tasks were canceled (or stolen by prefill) while a pipelined
+    solve was in flight simply pops fewer ids than the count, which is safe.
 
-    Both backends hand over C-contiguous int32 counts (the device path
-    slices the padded volume ON the device before readback —
-    models/greedy._device_slicer), so the native nonzero fast path applies
-    everywhere.
+    The cells arrive in row-major (b, v, w) order (ops/answer.py: a device
+    solve's from its packed readback, a host solve's from one nonzero pass
+    over its dense counts), which preserves the per-batch FIFO take
+    semantics of the nested loop this replaces.
     """
     assignments: list[Assignment] = []
-    counts = np.asarray(counts)
+    _SOLVE_ANSWERS.labels(cells.form).inc()
     with TRACER.phase(phases, "mapping"):
-        # one global nonzero over (B, V, W): row-major order preserves the
-        # per-batch FIFO take semantics of the nested loop it replaces
-        from hyperqueue_tpu.utils.native import native_nonzero
-
-        # both backends return contiguous int32 (host: padded-contiguous
-        # native output; device: sliced on device before readback), so this
-        # fast path is the common case on every backend now
-        nz = (
-            native_nonzero(counts)
-            if counts.dtype == np.int32 and counts.flags.c_contiguous
-            else None
-        )
-        if nz is not None:
-            flat, vals = nz
-            if flat.size == 0:
-                return assignments
-            bs, vs, ws = np.unravel_index(flat, counts.shape)
-        else:
-            bs, vs, ws = np.nonzero(counts)
-            if bs.size == 0:
-                return assignments
-            vals = counts[bs, vs, ws]
+        vals = cells.vals
+        if vals.size == 0:
+            return assignments
+        bs, vs, ws = np.unravel_index(cells.flat, cells.shape)
 
         if any(b.gang_nodes for b in batches):
             # gang cells never touch the queues — the gang task lives in

@@ -34,6 +34,11 @@ import time
 
 import numpy as np
 
+from hyperqueue_tpu.ops.answer import (
+    cells_of_dense,
+    handle_cells,
+    model_cells,
+)
 from hyperqueue_tpu.utils import chaos
 
 logger = logging.getLogger("hq.watchdog")
@@ -193,6 +198,15 @@ class SolverWatchdog:
 
     # --- solve ----------------------------------------------------------
     def solve(self, **kwargs) -> np.ndarray:
+        return self._guarded_solve(kwargs, cells=False)
+
+    def solve_cells(self, **kwargs):
+        """`solve`, answered with the nonzero cells (ops/answer.SolveCells)
+        the tick's mapping reads: the primary's own `solve_cells` where it
+        has one, else the nonzero of the dense counts."""
+        return self._guarded_solve(kwargs, cells=True)
+
+    def _guarded_solve(self, kwargs, cells: bool):
         self.last_solve_degraded = False
         self.last_solve_skipped = False
         # not armed (benched, or a stranded solve still runs) falls through
@@ -205,7 +219,7 @@ class SolverWatchdog:
                     "re-arming the primary solver (stranded solve drained)"
                 )
             try:
-                result = self._run_primary(kwargs)
+                result = self._run_primary(kwargs, cells)
                 self._last_ran = self.model
                 return result
             except SolveTimeout as e:
@@ -215,7 +229,8 @@ class SolverWatchdog:
                 self._raise_if_paranoid(e)
                 self.failures += 1
                 self._degrade(e)
-        return self._run_fallback(kwargs)
+        dense = self._run_fallback(kwargs)
+        return cells_of_dense(dense) if cells else dense
 
     @staticmethod
     def _raise_if_paranoid(error: BaseException) -> None:
@@ -247,12 +262,14 @@ class SolverWatchdog:
             exc_info=not isinstance(error, SolveTimeout),
         )
 
-    def _run_primary(self, kwargs):
+    def _run_primary(self, kwargs, cells: bool = False):
         def call():
             if chaos.ACTIVE:
                 # poisoned-solve injection runs INSIDE the guarded call so
                 # a "hang" exercises the deadline machinery, not the loop
                 chaos.fire("solve")
+            if cells:
+                return model_cells(self.model, kwargs)
             return self.model.solve(**kwargs)
 
         return self._run_deadlined(call)
@@ -373,16 +390,19 @@ class _ReadyHandle:
     def result(self):
         return self._counts
 
+    def cells(self):
+        return cells_of_dense(self._counts)
+
 
 class _WatchdogHandle:
     """Deadline + exception guard around a primary model's pending solve.
 
-    `result()` materializes the inner handle on the watchdog thread with
-    the solve deadline; a timeout or exception degrades the watchdog
-    (bench + resident-state invalidation, exactly like a synchronous
-    failure) and re-solves the SAME dispatched snapshot on the host
-    fallback — the captured kwargs are the assemble output of that tick,
-    which stays untouched until the pipeline maps this handle."""
+    `cells()` / `result()` materialize the inner handle on the watchdog
+    thread with the solve deadline; a timeout or exception degrades the
+    watchdog (bench + resident-state invalidation, exactly like a
+    synchronous failure) and re-solves the SAME dispatched snapshot on the
+    host fallback — the captured kwargs are the assemble output of that
+    tick, which stays untouched until the pipeline maps this handle."""
 
     __slots__ = ("_wd", "_inner", "_kwargs")
 
@@ -392,10 +412,15 @@ class _WatchdogHandle:
         self._kwargs = kwargs
 
     def result(self):
+        return self._guarded(self._inner.result, cells=False)
+
+    def cells(self):
+        return self._guarded(lambda: handle_cells(self._inner), cells=True)
+
+    def _guarded(self, materialize, cells: bool):
         wd = self._wd
-        inner = self._inner
         try:
-            out = wd._run_deadlined(inner.result)
+            out = wd._run_deadlined(materialize)
             wd._last_ran = wd.model
             return out
         except SolveTimeout as e:
@@ -405,4 +430,5 @@ class _WatchdogHandle:
             wd._raise_if_paranoid(e)
             wd.failures += 1
             wd._degrade(e)
-        return wd._run_fallback(self._kwargs)
+        dense = wd._run_fallback(self._kwargs)
+        return cells_of_dense(dense) if cells else dense

@@ -124,14 +124,81 @@ def test_resident_scatter_compiles_for_v5e(topo, no_cache):
 
 
 def test_device_slicer_compiles_for_v5e(topo, no_cache):
+    """The overflow fallback's slicer (ops/answer.live_slicer)."""
     import jax
     from jax.sharding import SingleDeviceSharding
 
-    from hyperqueue_tpu.models.greedy import _device_slicer
+    from hyperqueue_tpu.ops.answer import live_slicer
 
     one_chip = SingleDeviceSharding(topo.devices[0])
     counts = jax.ShapeDtypeStruct((B, V, W), np.int32, sharding=one_chip)
-    _device_slicer(200, 2, 1000).lower(counts).compile()  # live extents
+    live_slicer(200, 2, 1000).lower(counts).compile()  # live extents
+
+
+COLLECTIVES = r"all-gather|all-reduce|collective-permute|all-to-all"
+
+
+def _packer_shapes(w, put):
+    """ShapeDtypeStructs of the packer's arguments, the kernel's three
+    outputs at (B, V, w, R); `put` as in `_kernel_shapes`."""
+    import jax
+
+    def s(shape, kind):
+        return jax.ShapeDtypeStruct(shape, np.int32, sharding=put(kind))
+
+    return s((B, V, w), "counts"), s((w, R), "w2"), s((w,), "w1")
+
+
+@pytest.mark.parametrize(
+    "extents", [(200, 2, 1000), (1, 1, 1000)], ids=["compact", "dense-small"]
+)
+def test_answer_packer_compiles_for_v5e(topo, no_cache, extents):
+    """The packing program behind the single-chip kernel, in both forms
+    the extents choose: no `reduce-window` (its prefixes are shifted adds
+    too), and the compact form one program a padded shape, whatever the
+    live extents."""
+    import re
+
+    from jax.sharding import SingleDeviceSharding
+
+    from hyperqueue_tpu.ops.answer import _packer, layout_for
+
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    args = _packer_shapes(W, lambda kind: one_chip)
+    layout = layout_for(extents, (B, V, W, R))
+    assert (layout.rows is None) == (extents[0] > 1)
+    compiled = _packer().lower(*args, mesh=None, rows=layout.rows).compile()
+    text = compiled.as_text()
+    assert not re.search(COLLECTIVES, text)
+    assert text.count("reduce-window") == 0
+    if layout.rows is None:
+        assert layout_for((123, 2, 777), (B, V, W, R)).rows is None
+
+
+def test_answer_packer_on_four_v5e_adds_no_collective(topo, no_cache):
+    """On the mesh every chip compacts its own W-shard: the program the
+    chip's compiler makes of the packer holds no collective (left to
+    GSPMD, a compaction would all-gather 29 MB of counts) and, like the
+    kernels since PR 28, no `reduce-window`; its output is one (4, L)
+    buffer, a row a chip."""
+    import re
+
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from hyperqueue_tpu.ops.answer import _packer, layout_for
+
+    mesh = Mesh(np.array(topo.devices[:4]), axis_names=("w",))
+    specs = {"counts": P(None, None, "w"), "w2": P("w", None), "w1": P("w")}
+    args = _packer_shapes(
+        W_SHARDED, lambda kind: NamedSharding(mesh, specs[kind])
+    )
+    for extents in ((224, 2, W_SHARDED), (1, 1, W_SHARDED)):
+        layout = layout_for(extents, (B, V, W_SHARDED, R), devices=4)
+        lowered = _packer().lower(*args, mesh=mesh, rows=layout.rows)
+        assert lowered.out_info.shape == (4, layout.length)
+        text = lowered.compile().as_text()
+        assert not re.search(COLLECTIVES, text)
+        assert text.count("reduce-window") == 0
 
 
 @pytest.mark.parametrize(
@@ -176,12 +243,12 @@ def test_sharded_kernel_compiles_for_four_v5e(topo, no_cache, extras):
 def test_sharded_scatter_and_slicer_compile_for_four_v5e(topo, no_cache):
     """Around the sharded kernel at W = 16 384: the dirty-row scatter under
     GSPMD at the two buckets a 16k cluster's churn meets (2 048 and 4 096
-    rows; indices and rows replicated, the state sharded) and the slicer of
-    the W-sharded counts."""
+    rows; indices and rows replicated, the state sharded) and the overflow
+    fallback's slicer of the W-sharded counts."""
     import jax
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-    from hyperqueue_tpu.models.greedy import _device_slicer
+    from hyperqueue_tpu.ops.answer import live_slicer
     from hyperqueue_tpu.parallel.resident import _scatter_rows
 
     mesh = Mesh(np.array(topo.devices[:4]), axis_names=("w",))
@@ -197,4 +264,4 @@ def test_sharded_scatter_and_slicer_compile_for_four_v5e(topo, no_cache):
         jax.jit(_scatter_rows, donate_argnums=(0,), out_shardings=w1).lower(
             s((W_SHARDED,), w1), s((k,), rep), s((k,), rep)).compile()
     counts = s((B, V, W_SHARDED), NamedSharding(mesh, P(None, None, "w")))
-    _device_slicer(224, 2, W_SHARDED).lower(counts).compile()
+    live_slicer(224, 2, W_SHARDED).lower(counts).compile()

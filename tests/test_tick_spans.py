@@ -270,12 +270,16 @@ def test_readback_counters_grow_by_what_each_device_solve_reads_back():
     n_b = len(create_batches(env.core.queues))
     _run_tick(env)
     after = env.model.resident_stats()
-    # three arrays a solve: the counts, sliced on the device to the live
-    # (B, V=1, W) int32, and the padded free_after and nt_after
+    # one buffer a solve (ops/answer.py), here in its dense-small form (one
+    # batch): the live (B, V=1) rows of the counts at the padded width,
+    # then the padded free_after and nt_after
     mirror = env.model._res
-    assert after["readbacks_total"] - before["readbacks_total"] == 3
+    assert after["readbacks_total"] - before["readbacks_total"] == 1
+    assert after["answers_total"] - before["answers_total"] == 1
+    assert (after["answers_dense_small"]
+            - before["answers_dense_small"]) == 1
     assert after["readback_bytes_total"] - before["readback_bytes_total"] == (
-        n_b * 1 * len(env.core.workers) * 4
+        n_b * 1 * mirror._m_nt.size * 4
         + mirror._m_free.nbytes + mirror._m_nt.nbytes
     )
 
