@@ -1,6 +1,6 @@
 """chip_smoke.py — the quickest proof that the system still starts on the chip.
 
-    python chip_smoke.py            one chip: served -> cluster -> width
+    python chip_smoke.py            one chip: served -> cluster -> width -> fused
     python chip_smoke.py --chips 4  the sharded solve over four chips, and
                                     what it is compared with; nothing else
 
@@ -15,7 +15,9 @@ A chip belongs to one process at a time, so this process does not import
 JAX until every child that needs the chip has exited: `served` (a real
 `hq server start --scheduler tpu` over TCP) and `cluster` (the simulator
 CLI driving the real Server under 1 024 workers) each own the chip as a
-child; `width` then runs here.  There is no CPU mode: without a TPU the
+child; `width` then runs here, and `fused` (a core and a model as
+`Server(scheduler="tpu")` builds them: a multi-node task rides the device
+solve as a gang row).  There is no CPU mode: without a TPU the
 first child refuses to start and so does this script.  Tests rehearse the
 phase functions on the CPU with the scheduler passed in.
 """
@@ -756,6 +758,70 @@ def width() -> dict:
     }
 
 
+# ---------------------------------------------------------------- fused
+def fused(scheduler: str, n_workers: int, n_tasks: int) -> dict:
+    """A core and a model as `Server(scheduler=...)` builds them, under
+    `reactor.schedule`: the server's multi-node tasks ride the device solve
+    as gang rows (`core.fused_solve`), beside the single-node classes."""
+    from __graft_entry__ import ClusterState
+    from hyperqueue_tpu.server import reactor
+    from hyperqueue_tpu.server.bootstrap import Server
+    from hyperqueue_tpu.utils.metrics import REGISTRY
+
+    with tempfile.TemporaryDirectory(prefix="hq-smoke-fused-") as tmp:
+        server = Server(server_dir=Path(tmp), scheduler=scheduler)
+    core, model = server.core, server.model
+    check("fused: the server's scheduler runs the fused tick",
+          core.fused_solve is True, scheduler)
+    state = ClusterState(n_workers, n_tasks, core=core)
+    started = REGISTRY.get("hq_solve_gang_groups").labels()
+    rows = REGISTRY.get("hq_solve_gang_rows_total").labels()
+    before = (started.value, rows.value)
+    ticks = []
+    gang_ms: dict = {}
+    for i in range(TICKS):
+        t = time.perf_counter()
+        assigned = reactor.schedule(core, state.comm, state.events, model,
+                                    prefill=True)
+        core.sanity_check()
+        # what this tick added to the gang phases (none once the gang runs)
+        totals = {k: v for k, v in core.tick_stats.totals_ms.items()
+                  if k.startswith("gangs")}
+        ticks.append({
+            "tick": i, "assigned": assigned,
+            "tick_ms": round((time.perf_counter() - t) * 1e3, 3),
+            "backend": model.last_backend,
+            "gang_phases_ms": {k: round(v - gang_ms.get(k, 0.0), 4)
+                               for k, v in totals.items()
+                               if v > gang_ms.get(k, 0.0)},
+        })
+        gang_ms = totals
+        check("fused: tick assigned work", assigned > 0, assigned)
+        state.finish_some(256)
+        state.submit_wave(n_tasks // 5)
+    check("fused: gang placed on one group by the device solve",
+          state.gang_placed(), state.gang.mn_workers)
+    check("fused: one gang row sent, one gang started",
+          (started.value - before[0], rows.value - before[1]) == (1, 1),
+          (started.value - before[0], rows.value - before[1]))
+    check("fused: the gang's phases were timed",
+          {"gangs", "gangs/rows", "gangs/inputs", "gangs/apply"}
+          <= set(ticks[0]["gang_phases_ms"]), ticks[0])
+    running = sum(len(w.assigned_tasks) for w in core.workers.values())
+    check("fused: single-node tasks run beside the gang", running > 0,
+          running)
+    return {
+        "phase": "fused",
+        "scheduler": scheduler,
+        "workers": n_workers,
+        "gang_workers": list(state.gang.mn_workers),
+        "single_node_tasks_running": running,
+        "ticks": ticks,
+        "device": model.last_device,
+        "resident": model.resident_stats(),
+    }
+
+
 # -------------------------------------------------------------- sharded
 def sharded(n_workers: int, n_tasks: int, n_devices: int) -> dict:
     """MultichipModel over `n_devices` chips through reactor.schedule on a
@@ -930,6 +996,11 @@ def main() -> None:
           [str(d) for d in devices])
     if chips == 1:
         emit(width())
+        rec = fused("tpu", n_workers=1024, n_tasks=20_000)
+        emit(rec)
+        check("fused: every tick solved on the device",
+              {t["backend"] for t in rec["ticks"]} == {"device-jax"},
+              rec["ticks"])
     else:
         emit(sharded(n_workers=16384, n_tasks=120_000, n_devices=chips))
     print(json.dumps({"ok": True, "device": {

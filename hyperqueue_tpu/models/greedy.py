@@ -592,9 +592,10 @@ class GreedyCutScanModel:
         pg = 0
         if has_gang:
             # gang inputs are FRESH per-solve allocations, not persistent
-            # buffers: gang rows appear on a minority of ticks and keying
-            # the donated-buffer cache on their presence would churn the
-            # steady-state shape; the arrays are tiny ((B,), (W,), (W, G))
+            # buffers: whether gang rows ride every tick (a server with a
+            # multi-node backlog) or a few, keying the donated-buffer cache
+            # on their presence would churn the shape of the ticks without
+            # them; the arrays are tiny ((B,), (W,), (W, G))
             n_g = group_onehot.shape[1] if group_onehot is not None else 1
             pg = _bucket(max(n_g, 1), 4)
             gang_p = np.zeros(pb, dtype=np.int32)

@@ -284,6 +284,10 @@ def expand_onehots(class_m, order_ids):
     return jax.lax.optimization_barrier(onehots)
 
 
+# the scope of the one-chip gang selection in a trace's op metadata
+GANG_SELECT_SCOPE = "hq_gang_select"
+
+
 def _gang_select_local(
     elig, group_onehot, n, per_group_total=None, same_group_before=0
 ):
@@ -365,7 +369,11 @@ def scan_batches(
     has_gang = gang_nodes is not None
     has_pmask = policy_mask is not None
     if has_gang and gang_select is None:
-        gang_select = _gang_select_local
+        # the one-chip selection, named in a trace's op metadata as the
+        # sharded one's gather is (parallel/solve.py GANG_SELECT_GATHER)
+        def gang_select(elig, group_onehot, n):
+            with jax.named_scope(GANG_SELECT_SCOPE):
+                return _gang_select_local(elig, group_onehot, n)
 
     def batch_body(carry, batch):
         if has_gang:

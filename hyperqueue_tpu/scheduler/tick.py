@@ -52,6 +52,18 @@ _SCAN_STEPS = REGISTRY.counter(
     "scan steps of the dense solves (live batches x variants; the sharded "
     "solve runs one water-fill all-gather a step)",
 )
+# multi-node gangs as rows of the dense solve (the reactor's fused gang
+# phase): rows sent, and rows whose gang did not start (the solve held the
+# idle members it found against the rest of the scan instead)
+_SOLVE_GANG_ROWS = REGISTRY.counter(
+    "hq_solve_gang_rows_total",
+    "gang rows (one multi-node task each) sent to a dense solve",
+)
+_SOLVE_GANG_HELD = REGISTRY.counter(
+    "hq_solve_gang_held_total",
+    "gang rows of a dense solve that placed nothing: no group had enough "
+    "idle members, and those found were held for the rest of the scan",
+)
 # a static fact of the program a solve ran, counted so that a run shows
 # which formulation its solves used
 _SOLVES_BY_PREFIX = REGISTRY.counter(
@@ -791,11 +803,16 @@ def _map_counts(queues, batches, worker_ids, cells: SolveCells,
     _SOLVE_ANSWERS.labels(cells.form).inc()
     with TRACER.phase(phases, "mapping"):
         vals = cells.vals
+        gang_rows = sum(1 for b in batches if b.gang_nodes)
+        if gang_rows:
+            _SOLVE_GANG_ROWS.inc(gang_rows)
         if vals.size == 0:
+            if gang_rows:
+                _SOLVE_GANG_HELD.inc(gang_rows)
             return assignments
         bs, vs, ws = np.unravel_index(cells.flat, cells.shape)
 
-        if any(b.gang_nodes for b in batches):
+        if gang_rows:
             # gang cells never touch the queues — the gang task lives in
             # the reactor's mn_queue until the assignment is applied.  Emit
             # one (gang_task, worker, rq, -1) sentinel per selected worker;
@@ -804,11 +821,13 @@ def _map_counts(queues, batches, worker_ids, cells: SolveCells,
             # the gang rq ids, silently registering them as single-node).
             extend = assignments.extend
             queue_by_bi: dict = {}
+            started: set = set()
             for bi, vi, wi, n in zip(
                 bs.tolist(), vs.tolist(), ws.tolist(), vals.tolist()
             ):
                 batch = batches[bi]
                 if batch.gang_nodes:
+                    started.add(bi)
                     assignments.append(
                         (batch.gang_task, worker_ids[wi], batch.rq_id, -1)
                     )
@@ -822,6 +841,8 @@ def _map_counts(queues, batches, worker_ids, cells: SolveCells,
                     [(task_id, worker_id, batch.rq_id, vi)
                      for task_id in task_ids]
                 )
+            if len(started) < gang_rows:
+                _SOLVE_GANG_HELD.inc(gang_rows - len(started))
             return assignments
 
         batch_queues = [queues.queue(b.rq_id) for b in batches]

@@ -843,11 +843,15 @@ class Server:
                     "auto to let the server pick the host solve"
                 )
             base_model = GreedyCutScanModel(backend="jax")
+            # a server's multi-node tasks ride the device solve as gang
+            # rows (reactor.fused_gang_rows), not the host reservation drain
+            self.core.fused_solve = True
         elif scheduler == "multichip":
             base_model = MultichipModel()
             # initialise the backend now: one that cannot come up stops
             # the server here instead of at the first tick
             base_model.get_mesh()
+            self.core.fused_solve = True
         elif scheduler == "greedy-numpy":
             # pinned host/numpy solve: no adaptive host/device selection,
             # so the backend (and the decision records naming it) is

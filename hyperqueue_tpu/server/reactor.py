@@ -72,7 +72,8 @@ _DISPLACE_WORKERS = REGISTRY.counter(
 _SOLVE_GANG_GROUPS = REGISTRY.counter(
     "hq_solve_gang_groups",
     "multi-node gangs co-scheduled atomically by the fused dense solve "
-    "(all-or-nothing column groups, --scheduler greedy-fused)",
+    "(all-or-nothing column groups; --scheduler tpu, multichip and "
+    "greedy-fused)",
 )
 _SOLVE_LOOKAHEAD_DEPTH = REGISTRY.gauge(
     "hq_solve_lookahead_depth",
@@ -743,55 +744,111 @@ def _clear_mn_reservations(core: Core, task_id: int) -> None:
             core.bump_membership()
 
 
+def fused_gang_rows(core: Core, phases: dict | None = None) -> list[Batch]:
+    """The gang rows of one fused tick: the head of `core.mn_queue`, at
+    most MAX_FUSED_GANG_ROWS of them in queue order, one all-or-nothing
+    `Batch` each (scheduler/tick.py Batch.gang_nodes; kernel semantics in
+    ops/assign.py scan_batches).  Tasks STAY in mn_queue until their
+    sentinel assignments come back and validate (`_apply_fused_gangs`) — a
+    stale pipelined solve simply drops its gang and the next tick retries.
+    Done or vanished tasks leave the queue here.  Timed as `gangs/rows`
+    inside `gangs`."""
+    rows: list[Batch] = []
+    with TRACER.phase(phases, "gangs"), TRACER.phase(phases, "gangs/rows"):
+        remaining_mn = []
+        for task_id in core.mn_queue:
+            task = core.tasks.get(task_id)
+            if task is None or task.is_done:
+                _clear_mn_reservations(core, task_id)
+                continue
+            remaining_mn.append(task_id)
+            if len(rows) < MAX_FUSED_GANG_ROWS:
+                # fused mode never reserves: lift any reservation left
+                # over from a host-phase tick so the workers rejoin the
+                # dense row set
+                _clear_mn_reservations(core, task_id)
+                rqv = core.rq_map.get_variants(task.rq_id)
+                rows.append(Batch(
+                    rq_id=task.rq_id, priority=task.priority, size=1,
+                    gang_task=task_id,
+                    gang_nodes=rqv.variants[0].n_nodes,
+                ))
+        core.mn_queue = remaining_mn
+    return rows
+
+
+def fused_gang_inputs(
+    core: Core, worker_ids, phases: dict | None = None
+) -> tuple[list[int], list[int]]:
+    """The worker-side gang inputs of a fused solve, aligned to the rows of
+    a dense snapshot (`worker_ids`): `gang_ok`, host idleness (prefilled
+    backlog does not show in `free`, so the kernel cannot derive it), and
+    `group_ids`, the worker-group index map, groups numbered by first
+    appearance in the rows.  Timed as `gangs/inputs` inside `gangs`."""
+    with TRACER.phase(phases, "gangs"), TRACER.phase(phases, "gangs/inputs"):
+        gmap: dict[str, int] = {}
+        gang_ok = []
+        group_ids = []
+        workers = core.workers
+        for wid in worker_ids:
+            w = workers[wid]
+            gang_ok.append(1 if w.is_idle() else 0)
+            group_ids.append(gmap.setdefault(w.group, len(gmap)))
+    return gang_ok, group_ids
+
+
 def _apply_fused_gangs(
-    core: Core, mapped, per_worker_msgs: dict, now: float
+    core: Core, mapped, per_worker_msgs: dict, now: float,
+    phases: dict | None = None,
 ) -> tuple[list, int]:
     """Apply the gang sentinel assignments (variant == -1) a fused solve
     emitted, validating against CURRENT state — a pipelined solve maps one
     tick late, so a member may have been claimed, drained or disconnected
     while the solve was in flight; the whole gang is then dropped and
-    retried next tick (it is still in core.mn_queue).
+    retried next tick (it is still in core.mn_queue).  Timed as
+    `gangs/apply` inside `gangs`.
 
     Returns (the non-gang assignments, gangs applied)."""
-    gang_cells: dict[int, list[int]] = {}
-    sn = []
-    for a in mapped:
-        if a[3] == -1:
-            gang_cells.setdefault(a[0], []).append(a[1])
-        else:
-            sn.append(a)
-    n_gangs = 0
-    for task_id, member_ids in gang_cells.items():
-        task = core.tasks.get(task_id)
-        if task is None or task.is_done or task_id not in core.mn_queue:
-            continue
-        rqv = core.rq_map.get_variants(task.rq_id)
-        n_nodes = rqv.variants[0].n_nodes
-        members = [core.workers.get(wid) for wid in member_ids]
-        if len(members) != n_nodes or any(
-            w is None or w.mn_task or w.draining or not w.is_idle()
-            for w in members
-        ):
-            continue  # stale solve: the gang retries next tick
-        core.mn_queue.remove(task_id)
-        core.bump_membership()
-        for w in members:
-            w.mn_task = task_id
-        task.mn_workers = tuple(w.worker_id for w in members)
-        task.state = TaskState.ASSIGNED
-        task.t_assigned = now
-        root = members[0]
-        msg = _compute_message(core, task, variant=0)
-        msg["node_ids"] = list(task.mn_workers)
-        msg["node_hostnames"] = [
-            core.workers[wid].configuration.hostname
-            for wid in task.mn_workers
-        ]
-        per_worker_msgs.setdefault(root.worker_id, []).append(msg)
-        n_gangs += 1
-    if n_gangs:
-        _SOLVE_GANG_GROUPS.inc(n_gangs)
-    return sn, n_gangs
+    with TRACER.phase(phases, "gangs"), TRACER.phase(phases, "gangs/apply"):
+        gang_cells: dict[int, list[int]] = {}
+        sn = []
+        for a in mapped:
+            if a[3] == -1:
+                gang_cells.setdefault(a[0], []).append(a[1])
+            else:
+                sn.append(a)
+        n_gangs = 0
+        for task_id, member_ids in gang_cells.items():
+            task = core.tasks.get(task_id)
+            if task is None or task.is_done or task_id not in core.mn_queue:
+                continue
+            rqv = core.rq_map.get_variants(task.rq_id)
+            n_nodes = rqv.variants[0].n_nodes
+            members = [core.workers.get(wid) for wid in member_ids]
+            if len(members) != n_nodes or any(
+                w is None or w.mn_task or w.draining or not w.is_idle()
+                for w in members
+            ):
+                continue  # stale solve: the gang retries next tick
+            core.mn_queue.remove(task_id)
+            core.bump_membership()
+            for w in members:
+                w.mn_task = task_id
+            task.mn_workers = tuple(w.worker_id for w in members)
+            task.state = TaskState.ASSIGNED
+            task.t_assigned = now
+            root = members[0]
+            msg = _compute_message(core, task, variant=0)
+            msg["node_ids"] = list(task.mn_workers)
+            msg["node_hostnames"] = [
+                core.workers[wid].configuration.hostname
+                for wid in task.mn_workers
+            ]
+            per_worker_msgs.setdefault(root.worker_id, []).append(msg)
+            n_gangs += 1
+        if n_gangs:
+            _SOLVE_GANG_GROUPS.inc(n_gangs)
+        return sn, n_gangs
 
 
 def _prefill_fill(core: Core, now: float, per_worker_msgs: dict,
@@ -1154,12 +1211,13 @@ def _tick(core: Core, comm: Comm, model, prefill: bool, phases: dict):
     # unless strictly-higher-priority sn work is still pending, which keeps
     # the reference's priority interleaving (the MILP schedules higher
     # classes first and only blocks lower ones, solver.rs:479-518). ---
-    # fused mode (--scheduler greedy-fused): gangs become all-or-nothing
-    # column groups INSIDE the dense solve instead of this host phase —
-    # but only when the dense snapshot can serve the tick (tick_cache
-    # refuses min-utilization workers; the scratch/mu path keeps the host
-    # gang semantics)
-    fused_tick = core.fused_solve and not any(
+    # fused mode (--scheduler tpu, multichip, greedy-fused): gangs become
+    # all-or-nothing column groups INSIDE the dense solve instead of this
+    # host phase — but only when the dense snapshot can serve the tick
+    # (tick_cache refuses min-utilization workers; the scratch/mu path
+    # keeps the host gang semantics).  Asked only while a gang waits: a
+    # tick without one walks no worker for it
+    fused_tick = bool(core.mn_queue) and core.fused_solve and not any(
         w.configuration.min_utilization > 0.001
         for w in core.workers.values()
         if not (w.mn_task or w.mn_reserved or w.draining)
@@ -1316,32 +1374,10 @@ def _tick(core: Core, comm: Comm, model, prefill: bool, phases: dict):
             core.mn_queue = remaining_mn
 
     # --- fused gangs: the head of the mn queue rides the dense solve as
-    # all-or-nothing gang rows (scheduler/tick.py Batch.gang_nodes; kernel
-    # semantics in ops/assign.py scan_batches).  Tasks STAY in mn_queue
-    # until their sentinel assignments come back and validate — a stale
-    # pipelined solve simply drops its gang and the next tick retries. ---
+    # all-or-nothing gang rows (fused_gang_rows) ---
     fused_gang_batches: list[Batch] = []
     if fused_tick and core.mn_queue:
-        with TRACER.phase(phases, "gangs"):
-            remaining_mn = []
-            for task_id in core.mn_queue:
-                task = core.tasks.get(task_id)
-                if task is None or task.is_done:
-                    _clear_mn_reservations(core, task_id)
-                    continue
-                remaining_mn.append(task_id)
-                if len(fused_gang_batches) < MAX_FUSED_GANG_ROWS:
-                    # fused mode never reserves: lift any reservation left
-                    # over from a host-phase tick so the workers rejoin the
-                    # dense row set
-                    _clear_mn_reservations(core, task_id)
-                    rqv = core.rq_map.get_variants(task.rq_id)
-                    fused_gang_batches.append(Batch(
-                        rq_id=task.rq_id, priority=task.priority, size=1,
-                        gang_task=task_id,
-                        gang_nodes=rqv.variants[0].n_nodes,
-                    ))
-            core.mn_queue = remaining_mn
+        fused_gang_batches = fused_gang_rows(core, phases)
 
     # Soft drain for fused gangs: the kernel holds members WITHIN one
     # solve, but between ticks the prefill phase would keep piling backlog
@@ -1414,7 +1450,9 @@ def _tick(core: Core, comm: Comm, model, prefill: bool, phases: dict):
             else pipeline.take_result(model=model, phases=phases,
                                       decision=decision_target)
         )
-        mapped, n_gangs = _apply_fused_gangs(core, mapped, per_worker_msgs, now)
+        mapped, n_gangs = _apply_fused_gangs(
+            core, mapped, per_worker_msgs, now, phases
+        )
         assigned += n_gangs
         gang_assigned += n_gangs
         for task_id, worker_id, rq_id, variant in mapped:
@@ -1452,20 +1490,13 @@ def _tick(core: Core, comm: Comm, model, prefill: bool, phases: dict):
         with TRACER.phase(None, "solve"):
             with TRACER.phase(phases, "batches"):
                 batches = create_batches(core.queues)
-                gang_ok = group_ids = None
                 if run_gangs_fused:
                     batches = batches + fused_gang_batches
-                    # worker-side gang inputs, aligned to the snapshot rows:
-                    # host idleness (prefilled backlog does not show in `free`,
-                    # so the kernel cannot derive it) and the worker-group
-                    # index map
-                    gmap: dict[str, int] = {}
-                    gang_ok = []
-                    group_ids = []
-                    for wid in snapshot.worker_ids:
-                        w = core.workers[wid]
-                        gang_ok.append(1 if w.is_idle() else 0)
-                        group_ids.append(gmap.setdefault(w.group, len(gmap)))
+            gang_ok = group_ids = None
+            if run_gangs_fused:
+                gang_ok, group_ids = fused_gang_inputs(
+                    core, snapshot.worker_ids, phases
+                )
             if core.policy is not None:
                 # weighted objective (--policy-file): resolve this tick's
                 # affinity rows + priority boosts against the tick's worker
@@ -1532,7 +1563,7 @@ def _tick(core: Core, comm: Comm, model, prefill: bool, phases: dict):
                     )
             if run_gangs_fused:
                 assignments, n_gangs = _apply_fused_gangs(
-                    core, assignments, per_worker_msgs, now
+                    core, assignments, per_worker_msgs, now, phases
                 )
                 assigned += n_gangs
                 gang_assigned += n_gangs

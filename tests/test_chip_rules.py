@@ -129,3 +129,16 @@ def test_served_phase_rehearsal_accounts_for_every_task(tmp_path):
     assert rec["server_exit"] == 0
     with pytest.raises(SystemExit):
         chip_smoke.check_served_on_chip(rec)
+
+
+def test_fused_phase_rehearsal_places_the_gang_through_a_server_built_core():
+    """The smoke's `fused` phase with the scheduler passed in: on four
+    virtual devices `multichip` builds what the chip's `tpu` does, a device
+    model and a core that runs the fused tick."""
+    import chip_smoke
+
+    rec = chip_smoke.fused("multichip", n_workers=64, n_tasks=400)
+    assert len(rec["gang_workers"]) == 4
+    assert {t["backend"] for t in rec["ticks"]} == {"device-sharded"}
+    assert rec["single_node_tasks_running"] > 0
+    assert "gangs/apply" in rec["ticks"][0]["gang_phases_ms"]
