@@ -458,7 +458,8 @@ def _uploads(model) -> dict:
     stats = model.resident_stats()
     return {
         key: stats.get(key, 0)
-        for key in ("upload_bytes_total", "full_uploads", "delta_uploads",
+        for key in ("upload_bytes_total", "puts_total",
+                    "input_programs_total", "full_uploads", "delta_uploads",
                     "dirty_rows_last", "readbacks_total",
                     "readback_bytes_total", "answers_total",
                     "answers_compact", "answers_dense_small",
@@ -551,12 +552,25 @@ def _tick_line(model, before: dict, phases_ms: dict, **head) -> dict:
         "guard_ms": round(model.guard_ms, 3),
         "uploaded_bytes":
             after["upload_bytes_total"] - before["upload_bytes_total"],
+        "puts": after["puts_total"] - before["puts_total"],
+        "input_programs":
+            after["input_programs_total"] - before["input_programs_total"],
         "full_uploads": after["full_uploads"] - before["full_uploads"],
         "delta_uploads": after["delta_uploads"] - before["delta_uploads"],
         "dirty_rows": after["dirty_rows_last"],
         "backend": model.last_backend,
     })
     return after
+
+
+def check_one_put(where: str, before: dict, after: dict, solves: int) -> None:
+    """What a steady resident solve brings to the device crosses in one
+    put and one unpack program (ops/inputs.py); the inputs that repeat hit
+    the placement cache."""
+    moved = (after["puts_total"] - before["puts_total"],
+             after["input_programs_total"] - before["input_programs_total"])
+    check(f"{where}: one put and one input program a steady solve",
+          moved == (solves, solves), (moved, solves))
 
 
 TICKS = 3  # per mode
@@ -694,6 +708,7 @@ def width() -> dict:
             check("width: a steady tick's answer crosses compact, once",
                   moved["answers_compact"] == 1
                   and moved["readbacks_total"] == 1, moved)
+            check_one_put("width: resident", before, after, 1)
         churn()
 
     # -- dispatched through the pipeline: tick k maps solve k-1
@@ -784,6 +799,13 @@ def fused(scheduler: str, n_workers: int, n_tasks: int) -> dict:
         assigned = reactor.schedule(core, state.comm, state.events, model,
                                     prefill=True)
         core.sanity_check()
+        if i == 0:
+            # the gang row's tick: its three gang inputs ride the packed
+            # buffer, and nothing is put beside it
+            stats = model.resident_stats()
+            check("fused: the gang tick's inputs cross in one put a solve",
+                  stats["puts_total"] == stats["input_programs_total"]
+                  == stats["answers_total"], stats)
         # what this tick added to the gang phases (none once the gang runs)
         totals = {k: v for k, v in core.tick_stats.totals_ms.items()
                   if k.startswith("gangs")}
@@ -837,6 +859,7 @@ def sharded(n_workers: int, n_tasks: int, n_devices: int) -> dict:
 
     compiles = CompileLog()
     shard_log: list = []
+    placed_log: list = []
     preps: list = []
 
     class Model(checked(
@@ -846,10 +869,12 @@ def sharded(n_workers: int, n_tasks: int, n_devices: int) -> dict:
             model, prep
         ),
     )):
-        def _kernel_dispatch(self, res, free_d, nt_d, life_d, total_d, prep):
+        def _kernel_dispatch(self, res, free_d, nt_d, life_d, total_d, prep,
+                             placed):
             out = super()._kernel_dispatch(
-                res, free_d, nt_d, life_d, total_d, prep
+                res, free_d, nt_d, life_d, total_d, prep, placed
             )
+            placed_log.append(placed)
             counts, free_after, _nt_after = out
             shard_log.append({
                 name: sorted(
@@ -869,7 +894,7 @@ def sharded(n_workers: int, n_tasks: int, n_devices: int) -> dict:
     model.paranoid_resident = 1
 
     tick_ms = []
-    solves = 0
+    solves = layouts_ran = 0
     for i in range(TICKS):
         before = _uploads(model)
         t = time.perf_counter()
@@ -883,6 +908,12 @@ def sharded(n_workers: int, n_tasks: int, n_devices: int) -> dict:
             phases_ms=core.tick_stats.last_ms, **compiles.snapshot())
         check("sharded: tick assigned work", assigned > 0, assigned)
         check_answers("sharded", before, after, len(shard_log) - solves)
+        ran = len(model._res._ran)
+        if ran == layouts_ran:
+            # no new layout this tick (a first one may bring others, run
+            # once to stay compiled: resident.py `_keep_compiled`)
+            check_one_put("sharded", before, after, len(shard_log) - solves)
+        layouts_ran = ran
         solves = len(shard_log)
         # completions and a new wave of submits before the next tick
         state.finish_some(256)
@@ -914,6 +945,7 @@ def sharded(n_workers: int, n_tasks: int, n_devices: int) -> dict:
         "free": res.free, "nt_free": res.nt_free, "lifetime": res.lifetime,
         "total": res.total,
         **{name: dev for name, (_host, dev) in res._rep_cache.items()},
+        **placed_log[-1],
     }
     spans = {name: len(arr.devices()) for name, arr in placed.items()
              if arr is not None}

@@ -12,15 +12,16 @@ zero task slots; padded batches have size 0; padded variants are all-zero
 need rows which `_variant_capacity` masks off.
 
 Device path (new in the device-resident tick): the padded state stays
-RESIDENT on the accelerator (parallel/resident.py) — per-tick uploads are
-only the dirty-row delta, the solve donates its buffers so free_after/nt_after
-of solve N feed solve N+1 on-device, and the answer crosses to the host ONCE
-and compact: a packing program behind the kernel (ops/answer.py) writes the
-nonzero cells of the counts, `free_after` and `nt_after` into one buffer, so
-a solve costs one device-to-host round trip.  Backend choice is a
-per-solve cost model over measured host and device times with a periodically
-re-probed sync latency, so one slow probe does not disable the device path
-for the life of the process.
+RESIDENT on the accelerator (parallel/resident.py) — what a solve brings
+to the device (the dirty-row delta and the inputs that change every tick)
+crosses in one packed put (ops/inputs.py), the solve donates its buffers
+so free_after/nt_after of solve N feed solve N+1 on-device, and the answer
+crosses to the host ONCE and compact: a packing program behind the kernel
+(ops/answer.py) writes the nonzero cells of the counts, `free_after` and
+`nt_after` into one buffer, so a solve costs one device-to-host round trip.
+Backend choice is a per-solve cost model over measured host and device
+times with a periodically re-probed sync latency, so one slow probe does not
+disable the device path for the life of the process.
 """
 
 from __future__ import annotations
@@ -736,17 +737,18 @@ class GreedyCutScanModel:
         phases = prep["phases"]
         with TRACER.phase(phases, "solve_dispatch"):
             res = self._residency()
-            # host time to bring the resident state up to date: the
-            # dirty-row scatter or a full upload
+            # what this solve brings to the device, in one put and one
+            # program: the dirty rows (or the whole state) and the inputs
+            # whose content changes every tick
             with TRACER.phase(phases, "solve_dispatch/upload"):
-                free_d, nt_d, life_d, total_d = res.sync(
+                free_d, nt_d, life_d, total_d, placed = res.sync(
                     prep["free_p"], prep["nt_p"], prep["life_p"],
-                    prep["total_p"],
+                    prep["total_p"], inputs=self._tick_inputs(prep),
                 )
-            # placing the replicated inputs, enqueueing kernel and packer
+            # kernel and packer enqueued
             with TRACER.phase(phases, "solve_dispatch/launch"):
                 counts, free_after, nt_after = self._kernel_dispatch(
-                    res, free_d, nt_d, life_d, total_d, prep
+                    res, free_d, nt_d, life_d, total_d, prep, placed
                 )
                 res.adopt_outputs(free_after, nt_after)
                 layout = layout_for(
@@ -761,22 +763,46 @@ class GreedyCutScanModel:
         self._resident_solves += 1
         return _DeviceCounts(self, res, packed, counts, layout, prep)
 
-    def _kernel_dispatch(self, res, free_d, nt_d, life_d, total_d, prep):
+    @staticmethod
+    def _gang_inputs(prep) -> list:
+        """The gang inputs of a solve with gang rows, as `sync` takes
+        them: fresh content every tick, (B,), (W,) and (W, G)."""
+        if prep["gang_p"] is None:
+            return []
+        return [("gang_nodes", prep["gang_p"], 2),
+                ("gang_ok", prep["gok_p"], 1),
+                ("group_onehot", prep["goh_p"], 0)]
+
+    def _tick_inputs(self, prep) -> list:
+        """What a solve brings besides the worker state, which crosses
+        with it in the residency's one put (name, array, sharding kind).
+        All of it changes from tick to tick at a deep backlog: the visit
+        classes follow `free`, the sizes the queues, and the batch-shaped
+        arrays the batch order, which follows both."""
+        inputs = [("class_m", prep["class_m"], 3),
+                  ("order_ids", prep["order_ids"], 2),
+                  ("needs", prep["needs_p"], 2),
+                  ("sizes", prep["sizes_p"], 2),
+                  ("min_time", prep["mt_p"], 2)]
+        if prep["amask_p"] is not None:
+            inputs.append(("all_mask", prep["amask_p"], 2))
+        return inputs + self._gang_inputs(prep)
+
+    def _kernel_dispatch(self, res, free_d, nt_d, life_d, total_d, prep,
+                         placed):
         """Enqueue the jitted kernel on the resident buffers (donating
-        free/nt_free); replicated inputs ride the placement cache.
+        free/nt_free) and the inputs `sync` placed; the policy mask, whose
+        content repeats while the policy does, rides the placement cache.
         Overridden by the multichip model to shard the worker axis."""
         return greedy_cut_scan(
             free_d, nt_d, life_d,
-            res.place_cached("needs", prep["needs_p"]),
-            res.place_cached("sizes", prep["sizes_p"]),
-            res.place_cached("min_time", prep["mt_p"]),
-            res.place_cached("class_m", prep["class_m"]),
-            res.place_cached("order_ids", prep["order_ids"]),
+            placed["needs"], placed["sizes"], placed["min_time"],
+            placed["class_m"], placed["order_ids"],
             total=total_d,
-            all_mask=res.place_cached("all_mask", prep["amask_p"]),
-            gang_nodes=res.place_cached("gang_nodes", prep["gang_p"]),
-            gang_ok=res.place_cached("gang_ok", prep["gok_p"]),
-            group_onehot=res.place_cached("group_onehot", prep["goh_p"]),
+            all_mask=placed.get("all_mask"),
+            gang_nodes=placed.get("gang_nodes"),
+            gang_ok=placed.get("gang_ok"),
+            group_onehot=placed.get("group_onehot"),
             policy_mask=res.place_cached("policy_mask", prep["pmask_p"]),
         )
 

@@ -20,13 +20,15 @@ the parent's host solve.
 
 Residency: the sharded solve inherits the device-resident tick state from
 the parent model — the (W, R) shards stay on their devices across ticks,
-per-tick uploads are the dirty-row delta (scattered under GSPMD, so each
-device receives only its own rows), and `sharded_cut_scan_donate` reuses
-the resident buffers for `free_after`/`nt_after`.  `--scheduler=multichip`
-is an explicit operator choice, so the adaptive host-vs-device cost model
-is bypassed: with a real mesh the sharded kernel runs unconditionally
-(the watchdog still guards failures), matching the documented contract
-that selecting multichip means "shard my solve".
+per-tick uploads are the dirty-row delta and the inputs that change every
+tick in one packed put, a row a device (ops/inputs.py: each device
+scatters the rows that fall into its own shard, no collective), and
+`sharded_cut_scan_donate` reuses the resident buffers for `free_after`/
+`nt_after`.  `--scheduler=multichip` is an explicit operator choice, so
+the adaptive host-vs-device cost model is bypassed: with a real mesh the
+sharded kernel runs unconditionally (the watchdog still guards failures),
+matching the documented contract that selecting multichip means "shard my
+solve".
 """
 
 from __future__ import annotations
@@ -112,36 +114,40 @@ class MultichipModel(GreedyCutScanModel):
                 self._res = super()._residency()
         return self._res
 
-    def _kernel_dispatch(self, res, free_d, nt_d, life_d, total_d, prep):
-        mesh = self.get_mesh()
-        if not mesh:
-            return super()._kernel_dispatch(
-                res, free_d, nt_d, life_d, total_d, prep
-            )
-        from hyperqueue_tpu.parallel.solve import (
-            pack_batch_table,
-            sharded_cut_scan_donate,
-        )
+    def _tick_inputs(self, prep) -> list:
+        if not self.get_mesh():
+            return super()._tick_inputs(prep)
+        from hyperqueue_tpu.parallel.solve import pack_batch_table
 
-        # the replicated per-batch inputs ride ONE cached put: they change
-        # together (with the batch order), and each replicated put is a
-        # round trip per device
+        # the replicated per-batch inputs change together (with the batch
+        # order) and the kernel takes them as one vector: the whole table
+        # crosses with the state, and the worker-sharded inputs beside it,
+        # each device its own columns
         table = pack_batch_table(
             prep["needs_p"], prep["sizes_p"], prep["mt_p"],
             prep["order_ids"], prep["amask_p"],
         )
+        return [("batch_table", table, 2),
+                ("class_m", prep["class_m"], 3)] + self._gang_inputs(prep)
+
+    def _kernel_dispatch(self, res, free_d, nt_d, life_d, total_d, prep,
+                         placed):
+        mesh = self.get_mesh()
+        if not mesh:
+            return super()._kernel_dispatch(
+                res, free_d, nt_d, life_d, total_d, prep, placed
+            )
+        from hyperqueue_tpu.parallel.solve import sharded_cut_scan_donate
+
         return sharded_cut_scan_donate(
             mesh, free_d, nt_d, life_d,
-            res.place_cached("batch_table", table),
-            res.place_cached("class_m", prep["class_m"], kind=3),
+            placed["batch_table"], placed["class_m"],
             extents=prep["needs_p"].shape,
             has_all=prep["amask_p"] is not None,
             total=total_d,
-            gang_nodes=res.place_cached("gang_nodes", prep["gang_p"]),
-            gang_ok=res.place_cached("gang_ok", prep["gok_p"], kind=1),
-            group_onehot=res.place_cached(
-                "group_onehot", prep["goh_p"], kind=0
-            ),
+            gang_nodes=placed.get("gang_nodes"),
+            gang_ok=placed.get("gang_ok"),
+            group_onehot=placed.get("group_onehot"),
             policy_mask=res.place_cached(
                 "policy_mask", prep["pmask_p"], kind=3
             ),

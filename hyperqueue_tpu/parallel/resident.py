@@ -14,8 +14,15 @@ across ticks and makes each solve pay only for what changed:
   device for the greedy model;
 - a HOST MIRROR (plain numpy, one per array) tracks the device contents
   exactly; each tick the new padded inputs are row-diffed against the
-  mirror and only the dirty rows are scatter-updated on device (bucketed
-  row counts keep the compiled scatter programs few);
+  mirror and only the dirty rows cross (bucketed row counts keep the
+  compiled programs few);
+- what a solve brings to the device crosses ONCE: the dirty rows (or, in
+  the full form, the whole state) and the solve's other int32 inputs
+  (`class_m` and the batch-shaped arrays — on the mesh as its batch
+  table — and the gang inputs: at a deep backlog all of them change from
+  tick to tick) ride ONE packed buffer, one `device_put`, and one jitted
+  program (ops/inputs.py `unpack_inputs`) scatters the rows into the
+  resident arrays and hands the rest out as static slices;
 - the solve runs with `free`/`nt_free` DONATED (ops/assign.greedy_cut_scan
   and parallel/solve.sharded_cut_scan_donate), so `free_after`/`nt_after`
   of solve N become the resident inputs of solve N+1 with zero host
@@ -24,9 +31,9 @@ across ticks and makes each solve pay only for what changed:
   the packing program (ops/answer.py) puts them behind the solve's cells in
   the ONE buffer a solve reads back, and `apply_outputs` takes them from
   there;
-- small replicated inputs (needs / sizes / min_time / class_m / order_ids)
-  are placement-cached by content: a steady-state tick that repeats the
-  same batch layout re-uses the device buffers outright.
+- the policy mask of a policy solve, (B, W) and stable while the policy
+  is, is placement-cached by content: a tick that repeats it re-uses the
+  device buffer outright.
 
 Correctness contract: the resident path must be BIT-IDENTICAL to a fresh
 full-upload solve of the same padded inputs.  `models/greedy.py` exposes it
@@ -43,21 +50,33 @@ from __future__ import annotations
 import numpy as np
 
 from hyperqueue_tpu.models.greedy import _bucket
+from hyperqueue_tpu.ops.inputs import (
+    InputLayout,
+    device_rows,
+    layout_for,
+    pack_inputs,
+    unpack_inputs,
+)
 
-# dirty-row fraction above which one full upload beats the gather+scatter
-# round (the scatter path costs an index gather on host + a scatter program
-# on device; at >=half the rows the dense put is strictly simpler)
+# dirty-row fraction above which the full form beats the delta (an index
+# gather on the host and a scatter on the device; at >=half the rows the
+# whole state is strictly simpler)
 FULL_UPLOAD_FRACTION = 0.5
 
 # dirty-row counts are bucketed to powers of two (floor 16, the shared
-# models/greedy._bucket rule) so the jitted scatter programs stay few;
+# models/greedy._bucket rule) so the compiled unpack programs stay few;
 # padding repeats the first dirty row (a duplicate .set() with an
 # identical payload is order-independent)
 _ROW_BUCKET_FLOOR = 16
 
+# the sharding kinds (parallel/solve._mesh_shardings) of the state arrays
+_STATE_KINDS = (0, 1, 1, 0)   # free, nt_free, lifetime, total
 
-def _scatter_rows(dst, idx, vals):
-    return dst.at[idx].set(vals)
+# a tick with no dirty row puts the inputs that changed one by one where
+# they are no more than this, and packs them otherwise: a put costs the
+# host 0.25 ms and the unpack program's dispatch 0.4 (PERF.md section 6,
+# PR 32), so two puts tie with one put and one program and three lose
+_FEW_CHANGED_INPUTS = 2
 
 
 class DeviceResidency:
@@ -91,14 +110,26 @@ class DeviceResidency:
         # while True the mirror does NOT reflect the device (the device
         # holds free_after) and sync() must not run
         self._await_apply = False
-        # replicated-input placement cache: name -> (host copy, device arr)
+        # placement cache of the inputs that repeat: name -> (host copy,
+        # device arr)
         self._rep_cache: dict = {}
-        self._scatter_jit = None
+        # (pw, pr, has_total) -> (the per-solve inputs' shapes, the forms:
+        # None or a row bucket) this state key has crossed in, and the
+        # unpack layouts already run: sync() keeps their cross product
+        # compiled
+        self._met: dict = {}
+        self._ran: set = set()
+        # the last solve's crossing: (layout, its buffer on the host,
+        # the per-solve inputs on the device); a tick with no dirty row
+        # compares its inputs with it and re-uses what has not changed
+        self._last = None
         # telemetry (scraped via the model's resident_stats())
         self.full_uploads = 0
         self.delta_uploads = 0
         self.dirty_rows_last = 0
         self.upload_bytes_total = 0
+        self.puts_total = 0
+        self.input_programs_total = 0
         self.rep_cache_hits = 0
         self.invalidations = 0
         self.readbacks_total = 0
@@ -116,110 +147,193 @@ class DeviceResidency:
         return int(nbytes) * (self.mesh_devices if kind == 2 else 1)
 
     def _put(self, arr, kind):
+        """THE `device_put` of the residency: every put is counted."""
         import jax
 
+        self.puts_total += 1
         if self._shardings is not None:
             return jax.device_put(arr, self._shardings[kind])
         if self._device is not None:
             return jax.device_put(arr, self._device)
         return jax.device_put(arr)
 
-    def _scatter(self, dst, idx, vals, kind):
-        import jax
-
-        if self._scatter_jit is None:
-            if self._shardings is not None:
-                w2, w1, _rep = self._shardings[:3]
-                self._scatter_jit = (
-                    jax.jit(_scatter_rows, donate_argnums=(0,),
-                            out_shardings=w2),
-                    jax.jit(_scatter_rows, donate_argnums=(0,),
-                            out_shardings=w1),
-                )
-            else:
-                fn = jax.jit(_scatter_rows, donate_argnums=(0,))
-                self._scatter_jit = (fn, fn)
-        return self._scatter_jit[kind](dst, idx, vals)
-
     # -- the per-tick sync ------------------------------------------------
-    def sync(self, free_p, nt_p, life_p, total_p=None):
-        """Bring the resident device state up to date with this tick's
-        padded host inputs; returns (free, nt_free, lifetime, total) device
-        arrays.  Full upload when nothing is resident (or too much changed),
-        dirty-row scatter otherwise."""
+    def sync(self, free_p, nt_p, life_p, total_p=None, inputs=()):
+        """Bring the device up to date with this tick's padded host inputs
+        in ONE put and ONE program (ops/inputs.py): the resident state,
+        and `inputs`, the solve's per-tick inputs as (name, array, kind)
+        (`kind` as `place_cached`'s).  Returns (free, nt_free, lifetime,
+        total, placed): the resident device arrays and `inputs` on the
+        device by name.  The full form (the whole state) when nothing is
+        resident or too much changed, the dirty-row delta otherwise.  A
+        tick with no dirty row re-uses the inputs that have not changed
+        (`_reuse_inputs`: no put at all where none has); where many have,
+        it re-sets row 0 to what it holds, so that they ride a layout
+        that is already compiled."""
         if self._await_apply:
             # the previous solve's counts were never applied to the mirror
             # (e.g. a dropped pipeline dispatch): residency is unknowable
             self.invalidate()
         pw, pr = free_p.shape
         key = (pw, pr, total_p is not None)
-        if not self._valid or key != self.key:
-            return self._full_upload(key, free_p, nt_p, life_p, total_p)
-
-        dirty = (self._m_free != free_p).any(axis=1)
-        np.logical_or(dirty, self._m_nt != nt_p, out=dirty)
-        np.logical_or(dirty, self._m_life != life_p, out=dirty)
-        if total_p is not None:
-            np.logical_or(
-                dirty, (self._m_total != total_p).any(axis=1), out=dirty
+        state_p = (free_p, nt_p, life_p) + (
+            () if total_p is None else (total_p,)
+        )
+        parts = [(arr, kind) for _name, arr, kind in inputs]
+        rows = None
+        if self._valid and key == self.key:
+            rows = self._dirty_rows(state_p)
+            if rows.size > pw * FULL_UPLOAD_FRACTION:
+                rows = None
+            elif rows.size == 0:
+                placed = self._reuse_inputs(parts)
+                if placed is not None:
+                    self.dirty_rows_last = 0
+                    return self._state_and(inputs, placed)
+        if rows is None:
+            layout = layout_for(key, None, parts, self.mesh_devices)
+            head = list(zip(state_p, _STATE_KINDS))
+            self.key = key
+            self._m_free, self._m_nt, self._m_life = (
+                a.copy() for a in state_p[:3]
             )
-        rows = np.nonzero(dirty)[0]
-        self.dirty_rows_last = int(rows.size)
-        if rows.size == 0:
-            return self.free, self.nt_free, self.lifetime, self.total
-        if rows.size > pw * FULL_UPLOAD_FRACTION:
-            return self._full_upload(key, free_p, nt_p, life_p, total_p)
+            self._m_total = None if total_p is None else total_p.copy()
+            self._valid = True
+            self.dirty_rows_last = pw
+            self.full_uploads += 1
+        else:
+            layout = layout_for(
+                key, _bucket(int(rows.size), _ROW_BUCKET_FLOOR), parts,
+                self.mesh_devices,
+            )
+            head = self._delta_head(layout.rows, rows, state_p)
+            for mirror, arr in zip(self._mirrors(), state_p):
+                mirror[rows] = arr[rows]
+            self.dirty_rows_last = int(rows.size)
+            if rows.size:
+                self.delta_uploads += 1
+        buf, placed = self._cross(layout, head, parts)
+        self._last = (layout, buf, placed)
+        self._keep_compiled(layout)
+        return self._state_and(inputs, placed)
 
-        k = _bucket(int(rows.size), _ROW_BUCKET_FLOOR)
-        idx = np.empty(k, dtype=np.int32)
+    def _state_and(self, inputs, placed) -> tuple:
+        return self.free, self.nt_free, self.lifetime, self.total, {
+            name: dev for (name, _arr, _kind), dev in zip(inputs, placed)
+        }
+
+    def _reuse_inputs(self, parts):
+        """A tick with no dirty row (a served cluster whose slots are all
+        busy): the inputs are compared with what the last crossing left
+        on the device, and those that have not changed keep their device
+        arrays.  The few that have (the batch sizes, as a rule) are put
+        one by one, which is cheaper than a put and a program; None where
+        more have changed, or their shapes: the packed path then."""
+        if self._last is None:
+            return None
+        layout, buf, placed = self._last
+        if tuple((arr.shape, kind) for arr, kind in parts) != layout.parts:
+            return None
+        changed, at = [], layout.head
+        for i, (arr, kind) in enumerate(parts):
+            rows = device_rows(arr, kind, layout.devices)
+            seg = buf[:, at:at + rows.shape[1]]
+            at += rows.shape[1]
+            if not (seg == rows).all():
+                changed.append((i, seg, rows))
+        if len(changed) > _FEW_CHANGED_INPUTS:
+            return None
+        placed = list(placed)
+        for i, seg, rows in changed:
+            arr, kind = parts[i]
+            # a copy: the caller's padded buffers are rewritten in place
+            placed[i] = self._put(arr.copy(), kind)
+            self.upload_bytes_total += self._put_bytes(arr.nbytes, kind)
+            seg[...] = rows
+        self._last = (layout, buf, tuple(placed))
+        return placed
+
+    def _mirrors(self) -> tuple:
+        return (self._m_free, self._m_nt, self._m_life) + (
+            () if self._m_total is None else (self._m_total,)
+        )
+
+    def _dirty_rows(self, state_p):
+        """Indices of the rows in which the padded inputs differ from the
+        mirror, ascending."""
+        dirty = None
+        for mirror, arr in zip(self._mirrors(), state_p):
+            diff = mirror != arr
+            if diff.ndim == 2:
+                diff = diff.any(axis=1)
+            dirty = diff if dirty is None else np.logical_or(
+                dirty, diff, out=dirty
+            )
+        return np.nonzero(dirty)[0]
+
+    @staticmethod
+    def _delta_head(k: int, rows, state_p) -> list:
+        """The delta form's state part: `k` row indices (the dirty rows,
+        padded with a repeat of the first: a duplicate set of an identical
+        payload is order-independent; row 0 where none is dirty) and
+        those rows of each array, all replicated."""
+        idx = np.zeros(k, dtype=np.int32)
         idx[: rows.size] = rows
-        idx[rows.size:] = rows[0]  # idempotent duplicate scatter padding
-        idx_d = self._put(idx, 2)
-        self.free = self._scatter(self.free, idx_d, self._put(free_p[idx], 2),
-                                  0)
-        self.nt_free = self._scatter(
-            self.nt_free, idx_d, self._put(nt_p[idx], 2), 1
-        )
-        self.lifetime = self._scatter(
-            self.lifetime, idx_d, self._put(life_p[idx], 2), 1
-        )
-        if total_p is not None:
-            self.total = self._scatter(
-                self.total, idx_d, self._put(total_p[idx], 2), 0
-            )
-        self._m_free[rows] = free_p[rows]
-        self._m_nt[rows] = nt_p[rows]
-        self._m_life[rows] = life_p[rows]
-        if total_p is not None:
-            self._m_total[rows] = total_p[rows]
-        self.delta_uploads += 1
-        # the row indices and the rows are put replicated (the scatter
-        # runs under GSPMD): every device receives them whole
-        self.upload_bytes_total += self._put_bytes(
-            k * (free_p.itemsize * pr * (2 if total_p is not None else 1)
-                 + nt_p.itemsize + life_p.itemsize + idx.itemsize),
-            2,
-        )
-        return self.free, self.nt_free, self.lifetime, self.total
+        idx[rows.size:] = rows[0] if rows.size else 0
+        return [(idx, 2)] + [(arr[idx], 2) for arr in state_p]
 
-    def _full_upload(self, key, free_p, nt_p, life_p, total_p):
-        self.key = key
-        self.free = self._put(free_p, 0)
-        self.nt_free = self._put(nt_p, 1)
-        self.lifetime = self._put(life_p, 1)
-        self.total = None if total_p is None else self._put(total_p, 0)
-        self._m_free = free_p.copy()
-        self._m_nt = nt_p.copy()
-        self._m_life = life_p.copy()
-        self._m_total = None if total_p is None else total_p.copy()
-        self._valid = True
-        self.dirty_rows_last = free_p.shape[0]
-        self.full_uploads += 1
-        self.upload_bytes_total += int(
-            free_p.nbytes + nt_p.nbytes + life_p.nbytes
-            + (0 if total_p is None else total_p.nbytes)
+    def _cross(self, layout: InputLayout, head, parts) -> tuple:
+        """One put, one program: the packed buffer onto the resident
+        arrays (donated in the delta form).  Returns the buffer and the
+        placed parts."""
+        buf = pack_inputs(layout, head, parts)
+        state = () if layout.rows is None else tuple(
+            a for a in (self.free, self.nt_free, self.lifetime, self.total)
+            if a is not None
         )
-        return self.free, self.nt_free, self.lifetime, self.total
+        # a row a device: each receives its own and nothing else
+        out = unpack_inputs(state, self._put(buf, 0), layout, mesh=self.mesh)
+        self.upload_bytes_total += buf.nbytes
+        self.input_programs_total += 1
+        self._ran.add(layout)
+        n = len(layout.widths)
+        self.free, self.nt_free, self.lifetime = out[:3]
+        self.total = out[3] if n == 4 else None
+        return buf, out[n:]
+
+    def _keep_compiled(self, layout: InputLayout) -> None:
+        """A form that a state key has met (the full one, a row bucket)
+        stays compiled whatever the per-solve inputs' shapes, as the
+        scatter programs did, which knew nothing of them: the first time
+        this key crosses with inputs of new shapes, or in a new form, the
+        layouts of (shapes it has met) x (forms it has met) that have not
+        run yet run once and change nothing — the mirror put again in
+        full, or a delta of no rows (row 0 re-set to what it holds), zeros
+        for the inputs, the results dropped.  So a gang row that first
+        appears beside a warm state compiles its row buckets on the tick
+        that compiles the kernel it brings, not on some later one."""
+        met = self._met.get(layout.state)
+        if met is None:
+            met = self._met[layout.state] = (set(), set())
+        shapes, forms = met
+        if layout.parts in shapes and layout.rows in forms:
+            return
+        shapes.add(layout.parts)
+        forms.add(layout.rows)
+        none = np.zeros(0, dtype=np.int64)
+        for parts in shapes:
+            for k in forms:
+                other = layout._replace(rows=k, parts=parts)
+                if other in self._ran:
+                    continue
+                mirrors = self._mirrors()
+                self._cross(
+                    other,
+                    list(zip(mirrors, _STATE_KINDS)) if k is None
+                    else self._delta_head(k, none, mirrors),
+                    [(np.zeros(shape, dtype=np.int32), kind)
+                     for shape, kind in parts],
+                )
 
     # -- donated-solve bookkeeping ---------------------------------------
     def adopt_outputs(self, free_after, nt_after) -> None:
@@ -267,6 +381,7 @@ class DeviceResidency:
         self._await_apply = False
         self.free = self.nt_free = self.lifetime = self.total = None
         self._m_free = self._m_nt = self._m_life = self._m_total = None
+        self._last = None
 
     # -- replicated-input placement cache --------------------------------
     def place_cached(self, name: str, arr, kind: int = 2):
@@ -303,6 +418,8 @@ class DeviceResidency:
             "delta_uploads": self.delta_uploads,
             "dirty_rows_last": self.dirty_rows_last,
             "upload_bytes_total": self.upload_bytes_total,
+            "puts_total": self.puts_total,
+            "input_programs_total": self.input_programs_total,
             "rep_cache_hits": self.rep_cache_hits,
             "invalidations": self.invalidations,
             "readbacks_total": self.readbacks_total,

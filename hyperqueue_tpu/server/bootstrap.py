@@ -2081,8 +2081,8 @@ class Server:
         if resident:
             REGISTRY.counter(
                 "hq_device_upload_bytes_total",
-                "bytes uploaded to the solve device (full uploads + "
-                "dirty-row deltas + replicated-input placements)",
+                "bytes uploaded to the solve device (each solve's packed "
+                "buffer + placement-cache misses)",
             ).set_total(resident.get("upload_bytes_total", 0))
             REGISTRY.gauge(
                 "hq_tick_dirty_rows",
@@ -2094,6 +2094,15 @@ class Server:
                 REGISTRY.counter(
                     f"hq_resident_{key}_total",
                     f"device-resident tick state {key.replace('_', ' ')}",
+                ).set_total(resident.get(key, 0))
+            for key, what in (
+                ("puts_total", "device_put calls of the residency"),
+                ("input_programs_total", "unpack programs dispatched"),
+            ):
+                REGISTRY.counter(
+                    f"hq_resident_{key}",
+                    f"device-resident tick state: {what} (one each a "
+                    "steady solve, ops/inputs.py)",
                 ).set_total(resident.get(key, 0))
         pipeline = core.tick_pipeline
         if pipeline is not None:

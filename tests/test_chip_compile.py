@@ -103,24 +103,60 @@ def test_single_chip_kernel_compiles_for_v5e(topo, no_cache, extras):
     _assert_no_reduce_window(compiled)
 
 
-def test_resident_scatter_compiles_for_v5e(topo, no_cache):
+def _unpack_shapes(layout, put):
+    """ShapeDtypeStructs of the unpack program's arguments (ops/inputs.py):
+    the resident state it consumes (none in the full form) and the
+    (D, L) buffer; `put` as in `_kernel_shapes`."""
     import jax
+
+    def s(shape, kind):
+        return jax.ShapeDtypeStruct(shape, np.int32, sharding=put(kind))
+
+    w = layout.state[0]
+    state = () if layout.rows is None else tuple(
+        s((w, width), "w2") if width else s((w,), "w1")
+        for width in layout.widths
+    )
+    return state, s((layout.devices, layout.length), "w2")
+
+
+def _tick_parts(w, gang, sharded):
+    """(shape, kind) of the per-solve inputs a tick brings: the one-chip
+    model's, or the mesh's with its batch table."""
+    if sharded:
+        table = B * V * R * 2 + B + 2 * B * V  # with the all-mask
+        parts = [((table,), 2), ((M, w), 3)]
+    else:
+        parts = [((M, w), 3), ((B, V), 2), ((B, V, R), 2), ((B,), 2),
+                 ((B, V), 2)]
+    if gang:
+        parts += [((B,), 2), ((w,), 1), ((w, G), 0)]
+    return tuple(parts)
+
+
+@pytest.mark.parametrize("gang", [False, True], ids=["flat", "gang"])
+@pytest.mark.parametrize("rows", [16, 512, None], ids=["k16", "k512", "full"])
+def test_unpack_inputs_compiles_for_v5e(topo, no_cache, rows, gang):
+    """The program that turns a solve's one packed put into its inputs, in
+    both forms: the delta's scatter into the donated resident arrays at
+    the smallest and the largest row bucket of 1 024 workers, and the full
+    form's slices."""
+    import re
+
     from jax.sharding import SingleDeviceSharding
 
-    from hyperqueue_tpu.parallel.resident import (
-        _ROW_BUCKET_FLOOR,
-        _scatter_rows,
-    )
+    from hyperqueue_tpu.ops.inputs import InputLayout, _unpacker
 
     one_chip = SingleDeviceSharding(topo.devices[0])
-
-    def s(shape):
-        return jax.ShapeDtypeStruct(shape, np.int32, sharding=one_chip)
-
-    k = _ROW_BUCKET_FLOOR
-    fn = jax.jit(_scatter_rows, donate_argnums=(0,))
-    fn.lower(s((W, R)), s((k,)), s((k, R))).compile()  # free / total
-    fn.lower(s((W,)), s((k,)), s((k,))).compile()      # nt_free / lifetime
+    layout = InputLayout(1, (W, R, False), rows, _tick_parts(W, gang, False))
+    state, buf = _unpack_shapes(layout, lambda kind: one_chip)
+    lowered = _unpacker().lower(state, buf, layout=layout, mesh=None)
+    out = lowered.out_info
+    assert [o.shape for o in out[:3]] == [(W, R), (W,), (W,)]
+    assert [o.shape for o in out[3:]] == [shape for shape, _k in layout.parts]
+    text = lowered.compile().as_text()
+    assert not re.search(COLLECTIVES, text)
+    assert ("scatter" in text) == (rows is not None)
 
 
 def test_device_slicer_compiles_for_v5e(topo, no_cache):
@@ -240,28 +276,49 @@ def test_sharded_kernel_compiles_for_four_v5e(topo, no_cache, extras):
     _assert_no_reduce_window(compiled)
 
 
-def test_sharded_scatter_and_slicer_compile_for_four_v5e(topo, no_cache):
-    """Around the sharded kernel at W = 16 384: the dirty-row scatter under
-    GSPMD at the two buckets a 16k cluster's churn meets (2 048 and 4 096
-    rows; indices and rows replicated, the state sharded) and the overflow
-    fallback's slicer of the W-sharded counts."""
+@pytest.mark.parametrize("rows", [2048, 4096, None],
+                         ids=["k2048", "k4096", "full"])
+def test_unpack_inputs_on_four_v5e_adds_no_collective(topo, no_cache, rows):
+    """Around the sharded kernel at W = 16 384: the unpack program at the
+    two row buckets a 16k cluster's churn meets and in the full form, with
+    totals and gang inputs.  Every chip scatters the rows of its own shard
+    out of its own row of the (4, L) buffer: the program the chip's
+    compiler makes holds no collective, and its outputs carry the
+    shardings the kernel takes them with."""
+    import re
+
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from hyperqueue_tpu.ops.inputs import InputLayout, _unpacker
+
+    mesh = Mesh(np.array(topo.devices[:4]), axis_names=("w",))
+    specs = {"w2": P("w", None), "w1": P("w"), "rep": P(),
+             "cm": P(None, "w")}
+    layout = InputLayout(
+        4, (W_SHARDED, R, True), rows, _tick_parts(W_SHARDED, True, True))
+    state, buf = _unpack_shapes(
+        layout, lambda kind: NamedSharding(mesh, specs[kind]))
+    lowered = _unpacker().lower(state, buf, layout=layout, mesh=mesh)
+    compiled = lowered.compile()
+    assert not re.search(COLLECTIVES, compiled.as_text())
+    want = [specs["w2"], specs["w1"], specs["w1"], specs["w2"],
+            specs["rep"], specs["cm"], specs["rep"], specs["w1"],
+            specs["w2"]]
+    for sharding, spec, info in zip(
+            compiled.output_shardings, want, lowered.out_info):
+        assert sharding.is_equivalent_to(
+            NamedSharding(mesh, spec), len(info.shape))
+
+
+def test_sharded_slicer_compiles_for_four_v5e(topo, no_cache):
+    """The overflow fallback's slicer of the W-sharded counts."""
     import jax
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
     from hyperqueue_tpu.ops.answer import live_slicer
-    from hyperqueue_tpu.parallel.resident import _scatter_rows
 
     mesh = Mesh(np.array(topo.devices[:4]), axis_names=("w",))
-    w2, w1, rep = (NamedSharding(mesh, spec)
-                   for spec in (P("w", None), P("w"), P()))
-
-    def s(shape, sharding):
-        return jax.ShapeDtypeStruct(shape, np.int32, sharding=sharding)
-
-    for k in (2048, 4096):
-        jax.jit(_scatter_rows, donate_argnums=(0,), out_shardings=w2).lower(
-            s((W_SHARDED, R), w2), s((k,), rep), s((k, R), rep)).compile()
-        jax.jit(_scatter_rows, donate_argnums=(0,), out_shardings=w1).lower(
-            s((W_SHARDED,), w1), s((k,), rep), s((k,), rep)).compile()
-    counts = s((B, V, W_SHARDED), NamedSharding(mesh, P(None, None, "w")))
+    counts = jax.ShapeDtypeStruct(
+        (B, V, W_SHARDED), np.int32,
+        sharding=NamedSharding(mesh, P(None, None, "w")))
     live_slicer(224, 2, W_SHARDED).lower(counts).compile()
