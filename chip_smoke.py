@@ -1,8 +1,9 @@
 """chip_smoke.py — the quickest proof that the system still starts on the chip.
 
     python chip_smoke.py            one chip: served -> cluster -> width -> fused
-    python chip_smoke.py --chips 4  the sharded solve over four chips, and
-                                    what it is compared with; nothing else
+    python chip_smoke.py --chips 4  the sharded solve over four chips and
+                                    what it is compared with, then `fused`
+                                    under `--scheduler multichip`
 
 Every phase prints one JSON line when it ends; lines before it carry what
 the next reader will want (sync probe, compile seconds, tick phases, bytes
@@ -17,7 +18,9 @@ JAX until every child that needs the chip has exited: `served` (a real
 CLI driving the real Server under 1 024 workers) each own the chip as a
 child; `width` then runs here, and `fused` (a core and a model as
 `Server(scheduler="tpu")` builds them: a multi-node task rides the device
-solve as a gang row).  There is no CPU mode: without a TPU the
+solve as a gang row; with `--chips 4` the core and the model of
+`Server(scheduler="multichip")`, the gang row in the sharded solve on the
+mesh).  There is no CPU mode: without a TPU the
 first child refuses to start and so does this script.  Tests rehearse the
 phase functions on the CPU with the scheduler passed in.
 """
@@ -774,17 +777,23 @@ def width() -> dict:
 
 
 # ---------------------------------------------------------------- fused
-def fused(scheduler: str, n_workers: int, n_tasks: int) -> dict:
+def fused(scheduler: str, n_workers: int, n_tasks: int,
+          watchdog_timeout: float | None = None) -> dict:
     """A core and a model as `Server(scheduler=...)` builds them, under
     `reactor.schedule`: the server's multi-node tasks ride the device solve
-    as gang rows (`core.fused_solve`), beside the single-node classes."""
+    as gang rows (`core.fused_solve`), beside the single-node classes.
+    `watchdog_timeout` is `--solver-watchdog-timeout` (default: the
+    server's 5 s)."""
     from __graft_entry__ import ClusterState
     from hyperqueue_tpu.server import reactor
     from hyperqueue_tpu.server.bootstrap import Server
     from hyperqueue_tpu.utils.metrics import REGISTRY
 
     with tempfile.TemporaryDirectory(prefix="hq-smoke-fused-") as tmp:
-        server = Server(server_dir=Path(tmp), scheduler=scheduler)
+        server = Server(
+            server_dir=Path(tmp), scheduler=scheduler,
+            **({} if watchdog_timeout is None
+               else {"solver_watchdog_timeout": watchdog_timeout}))
     core, model = server.core, server.model
     check("fused: the server's scheduler runs the fused tick",
           core.fused_solve is True, scheduler)
@@ -842,6 +851,16 @@ def fused(scheduler: str, n_workers: int, n_tasks: int) -> dict:
         "device": model.last_device,
         "resident": model.resident_stats(),
     }
+
+
+def check_fused_on(backend: str, n_devices: int, rec: dict) -> None:
+    check("fused: every tick solved on the device",
+          {t["backend"] for t in rec["ticks"]} == {backend}, rec["ticks"])
+    check(f"fused: the solve's arrays lie on {n_devices} device(s)",
+          (rec["device"] or {}).get("count") == n_devices
+          == rec["resident"]["mesh_devices"], rec["device"])
+    check("fused: the gang inputs' bytes were counted",
+          rec["resident"]["gang_input_bytes_total"] > 0, rec["resident"])
 
 
 # -------------------------------------------------------------- sharded
@@ -1030,11 +1049,20 @@ def main() -> None:
         emit(width())
         rec = fused("tpu", n_workers=1024, n_tasks=20_000)
         emit(rec)
-        check("fused: every tick solved on the device",
-              {t["backend"] for t in rec["ticks"]} == {"device-jax"},
-              rec["ticks"])
+        check_fused_on("device-jax", 1, rec)
     else:
         emit(sharded(n_workers=16384, n_tasks=120_000, n_devices=chips))
+        # a server-built core: the gang rides the sharded solve as a gang
+        # row (in `sharded` the reactor's host phase placed it).  On a cold
+        # compile cache the first gang solve at this width compiles three
+        # programs for four chips in more than the watchdog's default 5 s
+        # (PR 33's first run here: SolveTimeout, the tick degraded to the
+        # host), so the server is started as its operator would start it
+        # there, with a deadline the first compile fits
+        rec = fused("multichip", n_workers=16384, n_tasks=120_000,
+                    watchdog_timeout=300.0)
+        emit(rec)
+        check_fused_on("device-sharded", chips, rec)
     print(json.dumps({"ok": True, "device": {
         "platform": devices[0].platform,
         "kind": devices[0].device_kind,

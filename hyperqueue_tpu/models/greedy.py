@@ -596,17 +596,22 @@ class GreedyCutScanModel:
             # buffers: whether gang rows ride every tick (a server with a
             # multi-node backlog) or a few, keying the donated-buffer cache
             # on their presence would churn the shape of the ticks without
-            # them; the arrays are tiny ((B,), (W,), (W, G))
-            n_g = group_onehot.shape[1] if group_onehot is not None else 1
-            pg = _bucket(max(n_g, 1), 4)
-            gang_p = np.zeros(pb, dtype=np.int32)
-            gang_p[:n_b] = gang_nodes
-            gok_p = np.zeros(pw, dtype=np.int32)
-            if gang_ok is not None:
-                gok_p[:n_w] = gang_ok
-            goh_p = np.zeros((pw, pg), dtype=np.int32)
-            if group_onehot is not None:
-                goh_p[:n_w, :n_g] = group_onehot
+            # them.  (B,) and (W,) are tiny; the (W, G) one-hot is not at
+            # every width: 64 kB at 1 024 workers in 16 groups, 8 MB at
+            # 8 192 x 256 and 16 MB at 16 384 x 256, allocated, zeroed and
+            # copied here every solve (`solve_host_prep/gang` times it,
+            # PERF.md section 7 has what it costs on the chip's host)
+            with TRACER.phase(phases, "solve_host_prep/gang"):
+                n_g = group_onehot.shape[1] if group_onehot is not None else 1
+                pg = _bucket(max(n_g, 1), 4)
+                gang_p = np.zeros(pb, dtype=np.int32)
+                gang_p[:n_b] = gang_nodes
+                gok_p = np.zeros(pw, dtype=np.int32)
+                if gang_ok is not None:
+                    gok_p[:n_w] = gang_ok
+                goh_p = np.zeros((pw, pg), dtype=np.int32)
+                if group_onehot is not None:
+                    goh_p[:n_w, :n_g] = group_onehot
         aff_p = pmask_p = None
         if affinity is not None:
             # like the gang inputs: FRESH per-solve allocations — weighted

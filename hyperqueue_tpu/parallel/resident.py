@@ -72,6 +72,9 @@ _ROW_BUCKET_FLOOR = 16
 # the sharding kinds (parallel/solve._mesh_shardings) of the state arrays
 _STATE_KINDS = (0, 1, 1, 0)   # free, nt_free, lifetime, total
 
+# the inputs a solve with gang rows brings (models/greedy._gang_inputs)
+GANG_INPUT_NAMES = ("gang_nodes", "gang_ok", "group_onehot")
+
 # a tick with no dirty row puts the inputs that changed one by one where
 # they are no more than this, and packs them otherwise: a put costs the
 # host 0.25 ms and the unpack program's dispatch 0.4 (PERF.md section 6,
@@ -131,6 +134,11 @@ class DeviceResidency:
         self.puts_total = 0
         self.input_programs_total = 0
         self.rep_cache_hits = 0
+        # what the gang rows cost on the way in: host bytes of the three
+        # gang inputs handed to `sync`, and the (padded) groups of the last
+        # one-hot, (W, G) int32 every solve
+        self.gang_input_bytes_total = 0
+        self.gang_groups_last = 0
         self.invalidations = 0
         self.readbacks_total = 0
         self.readback_bytes_total = 0
@@ -174,6 +182,11 @@ class DeviceResidency:
             # the previous solve's counts were never applied to the mirror
             # (e.g. a dropped pipeline dispatch): residency is unknowable
             self.invalidate()
+        for name, arr, _kind in inputs:
+            if name in GANG_INPUT_NAMES:
+                self.gang_input_bytes_total += int(arr.nbytes)
+                if name == "group_onehot":
+                    self.gang_groups_last = int(arr.shape[1])
         pw, pr = free_p.shape
         key = (pw, pr, total_p is not None)
         state_p = (free_p, nt_p, life_p) + (
@@ -421,6 +434,8 @@ class DeviceResidency:
             "puts_total": self.puts_total,
             "input_programs_total": self.input_programs_total,
             "rep_cache_hits": self.rep_cache_hits,
+            "gang_input_bytes_total": self.gang_input_bytes_total,
+            "gang_groups_last": self.gang_groups_last,
             "invalidations": self.invalidations,
             "readbacks_total": self.readbacks_total,
             "readback_bytes_total": self.readback_bytes_total,

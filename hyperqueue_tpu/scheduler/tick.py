@@ -316,7 +316,8 @@ def run_tick(
 
 def assemble_solve_inputs(workers, batches, rq_map, resource_map,
                           cpu_floor=None, dense=None, key_cache=None,
-                          gang_ok=None, group_ids=None, policy=None):
+                          gang_ok=None, group_ids=None, policy=None,
+                          phases=None):
     """Build the dense model.solve inputs for `batches` over `workers`.
 
     Sorts `batches` IN PLACE into the production solve order (priority,
@@ -339,7 +340,8 @@ def assemble_solve_inputs(workers, batches, rq_map, resource_map,
     `key_cache` (a TickStateCache, optional) memoizes the per-request-class
     (scarcity, objective) sort keys across ticks: they are pure in the rq
     class and this tick's free column totals, which steady-state ticks
-    repeat.
+    repeat.  `phases` (the tick's dict, optional) takes `assemble/gang`,
+    the gang part's own span inside the caller's `assemble`.
     """
     n_r = len(resource_map)
     n_b = len(batches)
@@ -654,22 +656,25 @@ def assemble_solve_inputs(workers, batches, rq_map, resource_map,
             extra["affinity"] = aff
     if any(b.gang_nodes for b in batches):
         # fused gang rows: per-batch gang sizes plus the worker-side
-        # idleness/group inputs the kernel's all-or-nothing selection needs
-        extra["gang_nodes"] = np.fromiter(
-            (b.gang_nodes for b in batches), dtype=np.int32, count=n_b
-        )
-        extra["gang_ok"] = (
-            np.zeros(n_w, dtype=np.int32) if gang_ok is None
-            else np.asarray(gang_ok, dtype=np.int32)
-        )
-        gids = (
-            np.zeros(n_w, dtype=np.int32) if group_ids is None
-            else np.asarray(group_ids, dtype=np.int32)
-        )
-        n_g = int(gids.max(initial=0)) + 1
-        extra["group_onehot"] = (
-            gids[:, None] == np.arange(n_g, dtype=np.int32)[None, :]
-        ).astype(np.int32)
+        # idleness/group inputs the kernel's all-or-nothing selection
+        # needs.  The one-hot is (W, G) int32: 64 kB at 1 024 x 16, 16 MB
+        # at 16 384 x 256, which is why this part has a span of its own
+        with TRACER.phase(phases, "assemble/gang"):
+            extra["gang_nodes"] = np.fromiter(
+                (b.gang_nodes for b in batches), dtype=np.int32, count=n_b
+            )
+            extra["gang_ok"] = (
+                np.zeros(n_w, dtype=np.int32) if gang_ok is None
+                else np.asarray(gang_ok, dtype=np.int32)
+            )
+            gids = (
+                np.zeros(n_w, dtype=np.int32) if group_ids is None
+                else np.asarray(group_ids, dtype=np.int32)
+            )
+            n_g = int(gids.max(initial=0)) + 1
+            extra["group_onehot"] = (
+                gids[:, None] == np.arange(n_g, dtype=np.int32)[None, :]
+            ).astype(np.int32)
     if cpu_floor is not None:
         # joint mu path (run_tick): if _range_compress shifted the cpu
         # column, ceil-shift the floors the same way (a floor must never
@@ -722,7 +727,7 @@ def _run_main_solve(queues, workers, rq_map, resource_map, model, batches,
         kwargs = assemble_solve_inputs(
             workers, batches, rq_map, resource_map, cpu_floor=cpu_floor,
             dense=dense, key_cache=key_cache, gang_ok=gang_ok,
-            group_ids=group_ids, policy=policy,
+            group_ids=group_ids, policy=policy, phases=phases,
         )
     if pipeline is not None and hasattr(model, "solve_async"):
         # pipelined dispatch: enqueue the solve and return WITHOUT mapping

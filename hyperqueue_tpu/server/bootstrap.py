@@ -2104,6 +2104,19 @@ class Server:
                     f"device-resident tick state: {what} (one each a "
                     "steady solve, ops/inputs.py)",
                 ).set_total(resident.get(key, 0))
+            # the gang rows' inputs (parallel/resident.py): bytes of
+            # gang_nodes, gang_ok and the (W, G) one-hot handed to the
+            # residency, and the groups the last gang solve saw
+            REGISTRY.counter(
+                "hq_solve_gang_input_bytes_total",
+                "host bytes of the gang inputs (gang_nodes, gang_ok, "
+                "group_onehot) the device solves were handed",
+            ).set_total(resident.get("gang_input_bytes_total", 0))
+            REGISTRY.gauge(
+                "hq_solve_gang_input_groups",
+                "worker groups (padded) in the one-hot of the last device "
+                "solve that carried gang rows",
+            ).set(resident.get("gang_groups_last", 0))
         pipeline = core.tick_pipeline
         if pipeline is not None:
             ps = pipeline.stats()

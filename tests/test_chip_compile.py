@@ -276,6 +276,51 @@ def test_sharded_kernel_compiles_for_four_v5e(topo, no_cache, extras):
     _assert_no_reduce_window(compiled)
 
 
+@pytest.mark.parametrize("w", [8192, W_SHARDED], ids=["w8192", "w16384"])
+def test_gang_shard_cell_programs_compile_for_four_v5e(topo, no_cache, w):
+    """`gang-16k.campaign`'s solve at its own extents (96 rows in a bucket
+    of 128, one variant, 256 groups) at both worker buckets its rows pass
+    through: the sharded kernel with gang rows keeps both gathers and fits
+    a chip, and the full-form unpack of its inputs (the (W, 256) one-hot in
+    the buffer, a row a device) adds no collective."""
+    import re
+
+    import jax
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from hyperqueue_tpu.ops.inputs import InputLayout, _unpacker
+    from hyperqueue_tpu.parallel.solve import sharded_cut_scan_donate
+
+    b, v, r, m, g = 128, 1, 4, 4, 256
+    mesh = Mesh(np.array(topo.devices[:4]), axis_names=("w",))
+    specs = {"w2": P("w", None), "w1": P("w"), "rep": P(),
+             "cm": P(None, "w")}
+
+    def s(shape, kind):
+        return jax.ShapeDtypeStruct(
+            shape, np.int32, sharding=NamedSharding(mesh, specs[kind]))
+
+    table = b * v * r + b + 2 * b * v
+    compiled = sharded_cut_scan_donate.lower(
+        mesh, s((w, r), "w2"), s((w,), "w1"), s((w,), "w1"),
+        s((table,), "rep"), s((m, w), "cm"), extents=(b, v, r),
+        gang_nodes=s((b,), "rep"), gang_ok=s((w,), "w1"),
+        group_onehot=s((w, g), "w2"),
+    ).compile()
+    mem = compiled.memory_analysis()  # bytes per device
+    assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < HBM_BYTES
+    text = compiled.as_text()
+    assert len(re.findall(r"= \S+ all-(?:gather|reduce)(?:-start)?\(",
+                          text)) >= 2
+    _assert_no_reduce_window(compiled)
+    parts = (((table,), 2), ((m, w), 3), ((b,), 2), ((w,), 1), ((w, g), 0))
+    layout = InputLayout(4, (w, r, False), None, parts)
+    state, buf = _unpack_shapes(
+        layout, lambda kind: NamedSharding(mesh, specs[kind]))
+    unpack = _unpacker().lower(state, buf, layout=layout, mesh=mesh).compile()
+    assert not re.search(COLLECTIVES, unpack.as_text())
+
+
 @pytest.mark.parametrize("rows", [2048, 4096, None],
                          ids=["k2048", "k4096", "full"])
 def test_unpack_inputs_on_four_v5e_adds_no_collective(topo, no_cache, rows):

@@ -142,3 +142,10 @@ def test_fused_phase_rehearsal_places_the_gang_through_a_server_built_core():
     assert {t["backend"] for t in rec["ticks"]} == {"device-sharded"}
     assert rec["single_node_tasks_running"] > 0
     assert "gangs/apply" in rec["ticks"][0]["gang_phases_ms"]
+    # what `--chips 4` asks of this phase on the mesh: every device the
+    # process sees, as the server builds it
+    import jax
+
+    chip_smoke.check_fused_on("device-sharded", len(jax.devices()), rec)
+    with pytest.raises(SystemExit):
+        chip_smoke.check_fused_on("device-jax", 1, rec)

@@ -305,12 +305,12 @@ def test_span_reader_gives_the_median_or_nothing(metric):
     assert read({}) is None
     entry = next(m for m in manifest.load()["per_layer"]
                  if m["name"] == metric)
-    # the tick cell, since PR 27 the sharded cell and since PR 31 the
-    # `flat-1k` tick cell and the gang cell, whose drivers pass the same
-    # `tick_phases_ms`
+    # the tick cell, since PR 27 the sharded cell, since PR 31 the
+    # `flat-1k` tick cell and the gang cell, and since PR 33 the sharded
+    # gang cell, whose drivers pass the same `tick_phases_ms`
     assert entry["workloads"] == [
         "hetero-1k.backlog-1m", "shard-16k.backlog", "flat-1k.backlog-1m",
-        "gang-1k.rigid"]
+        "gang-1k.rigid", "gang-16k.campaign"]
     assert entry["moves"] == "tick_ms_p50" and entry["unit"] == "ms"
 
 
