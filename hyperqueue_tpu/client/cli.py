@@ -397,8 +397,10 @@ def cmd_server_stats(args) -> None:
         f"{cache.get('workers', 0)} workers x "
         f"{cache.get('resources', 0)} resources, "
         f"{cache.get('full_rebuilds', 0)} full rebuilds, "
-        f"{cache.get('incremental_syncs', 0)} incremental syncs "
-        f"({cache.get('rows_rewritten_last', 0)} rows rewritten last tick)"
+        f"{cache.get('incremental_syncs', 0)} incremental syncs, "
+        f"{cache.get('membership_flips', 0)} membership flips "
+        f"({cache.get('rows_rewritten_last', 0)} rows rewritten, "
+        f"{cache.get('rows_moved_last', 0)} moved last tick)"
     )
     if stats.get("shape_allocations") is not None:
         print(f"solver shape allocations: {stats['shape_allocations']}")

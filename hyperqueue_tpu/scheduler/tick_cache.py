@@ -8,17 +8,31 @@ north-star shape that host bookkeeping — not the solve — dominated the
 tick (BASELINE.json; same lesson as Gavel's round-based policy engine:
 the reallocation round must be far cheaper than the work it places).
 
-`TickStateCache` keeps the `(W, R)` matrices and `(W,)` vectors alive and
-applies dirty-tracking deltas instead of rebuilding:
+`TickStateCache` keeps `free`/`total` `(N, R)` and `nt_free`/`lifetime`
+`(N,)` alive over EVERY connected worker, in `core.workers` order, with an
+eligibility mask (`mn_task == 0 and mn_reserved == 0 and not draining`),
+and derives the solve's dense `(W, R)` rows from them.  It walks no worker
+to learn what changed — it is told:
 
-- every `Worker.assign`/`unassign` bumps the worker's `epoch`
-  (server/worker.py) — the ONE funnel for free/nt_free mutation;
-- `sync()` walks the eligible workers once, rewrites only rows whose
-  epoch moved, and refreshes lifetimes for time-limited workers;
-- membership changes (connect/disconnect, gang reservation flips) and
-  resource-map widening are structural: the row map is rebuilt and the
-  `full_rebuilds` counter increments — steady-state ticks must keep it
-  at zero (pinned by tests/test_tick_cache.py).
+- content: `Worker.assign`/`unassign` (server/worker.py), the ONE funnel
+  for free/nt_free mutation, add the worker's row to the cache's dirty
+  set; `sync()` writes those rows in one C-level conversion, whatever their
+  share of the rows;
+- membership flips: a site that changes one worker's eligibility (a gang
+  starts or ends, a reservation is set or lifted, a drain begins) names it,
+  `Core.bump_membership(worker)`; `sync()` re-reads the named workers, and
+  when any flipped cuts the dense arrays anew (`flatnonzero(mask)`, `take`).
+  Counted in `membership_flips`; nothing is rebuilt;
+- structural changes: the first sync, a worker connected or lost, a
+  `bump_membership()` that names no worker (legal: "walk everything"), a
+  named worker the rows do not hold, a worker count or membership epoch the
+  cache was not told of.  Every worker is walked, every array built, and
+  ONLY these increment `full_rebuilds` — steady-state ticks, gang churn
+  included, must keep it still (pinned by tests/test_tick_cache.py);
+- resource-map widening pads zero columns.
+
+One cache a core: a worker is attached (`tick_row`, `tick_dirty`) to the
+cache that last built its rows over it.
 
 Correctness contract: an incremental assemble must be BIT-IDENTICAL to a
 from-scratch assemble of the same state.  `paranoid_check` runs both
@@ -36,6 +50,8 @@ presence makes membership time-dependent — and they are rare, autoalloc
 from __future__ import annotations
 
 from dataclasses import dataclass, field
+from itertools import chain
+from operator import attrgetter
 
 import numpy as np
 
@@ -124,19 +140,62 @@ class TickPhaseStats:
         }
 
 
+_FREE = attrgetter("free")
+_NT_FREE = attrgetter("nt_free")
+
+
+def eligible(w) -> bool:
+    """Is this worker a row of the dense solve?  Not while it runs a gang,
+    is reserved for one, or drains (`Core.worker_rows` asks the same)."""
+    return w.mn_task == 0 and w.mn_reserved == 0 and not w.draining
+
+
+def _matrix(lists: list, n_r: int) -> np.ndarray:
+    """`lists` (one per row, each at most `n_r` long) as an (n, n_r) int64
+    array in ONE C-level conversion; a list shorter than the map (a worker
+    that lacks the map's later resources, or one older than a widening) is
+    zero-filled, as the scratch path fills it."""
+    if set(map(len, lists)) - {n_r}:
+        pad = [0] * n_r
+        lists = [
+            f if len(f) == n_r else (f + pad[len(f):])[:n_r] for f in lists
+        ]
+    return np.fromiter(
+        chain.from_iterable(lists), dtype=np.int64, count=len(lists) * n_r
+    ).reshape(len(lists), n_r)
+
+
 class TickStateCache:
-    """Dirty-tracked dense snapshot of the schedulable workers."""
+    """Dense snapshot of the schedulable workers, told what changed."""
 
     def __init__(self) -> None:
-        self.worker_ids: list[int] = []
-        self._workers: list = []          # same order as worker_ids
-        self._epochs: list[int] = []
+        # --- rows over ALL connected workers, in core.workers order ---
+        self._workers: list = []
+        self._ids: np.ndarray | None = None         # (N,) worker ids
+        self._free: np.ndarray | None = None        # (N, R)
+        self._total: np.ndarray | None = None       # (N, R)
+        self._nt_free: np.ndarray | None = None     # (N,)
+        self._lifetime: np.ndarray | None = None    # (N,)
+        self._mask: np.ndarray | None = None        # (N,) bool: eligible
+        self._mu: np.ndarray | None = None  # (N,) bool, None = no mu worker
         self._timed_rows: list[int] = []  # rows with a finite time limit
-        # (core.membership_epoch, n_r) of the last sync: when unchanged,
-        # the O(W) membership walk is skipped entirely and only row
-        # CONTENT (Worker.epoch) is scanned
-        self._sync_ver: tuple | None = None
+        # row -> position among the dense rows, -1 while not eligible
+        self._pos: np.ndarray | None = None
+        self._rows: np.ndarray | None = None        # dense position -> row
+        # --- what the cache was told since the last sync ---
+        # rows whose free/nt_free moved (Worker.assign/unassign add their
+        # own row).  A whole build starts a NEW set, so a worker of an
+        # earlier build writes into a set nobody reads, and `is` tells
+        # whether a worker belongs to the rows as they stand
+        self._dirty: set[int] = set()
+        self._flipped: set[int] = set()  # rows a bump_membership named
+        # a bump named no worker, or one the rows do not hold: walk everything
+        self._unnamed = False
+        self._heard = 0              # bumps heard since the last sync
+        self._epoch = 0              # core.membership_epoch at the last sync
         self._mu_blocked = False
+        # --- the dense rows: the eligible workers, same order ---
+        self.worker_ids: list[int] = []
         self.n_r = 0
         self.free: np.ndarray | None = None
         self.total: np.ndarray | None = None
@@ -145,7 +204,9 @@ class TickStateCache:
         # telemetry (exposed via server stats / bench --phases)
         self.full_rebuilds = 0
         self.incremental_syncs = 0
+        self.membership_flips = 0
         self.rows_rewritten_last = 0
+        self.rows_moved_last = 0
         # sort-key memo for assemble_solve_inputs: the (scarcity,
         # objective) keys are pure per rq class + per-tick free totals;
         # totals are often unchanged tick-over-tick (e.g. release then
@@ -158,48 +219,41 @@ class TickStateCache:
         self.batch_layout: dict | None = None
 
     # ------------------------------------------------------------------
+    def membership_changed(self, worker=None) -> None:
+        """`Core.bump_membership`'s word to the cache: `worker`'s
+        eligibility (mn_task, mn_reserved, draining) may have flipped, or
+        is about to; with no worker named, anything may have changed and
+        the next sync builds the rows whole."""
+        self._heard += 1
+        if worker is not None and worker.tick_dirty is self._dirty:
+            self._flipped.add(worker.tick_row)
+        else:
+            self._unnamed = True
+
     def sync(self, core) -> DenseSnapshot | None:
         """Bring the dense arrays up to date with `core`; returns the
         snapshot, or None when the cache cannot serve this tick (a
         min-utilization worker is present — see module docstring)."""
         n_r = len(core.resource_map)
-        ver = (core.membership_epoch, n_r)
-        if self.free is not None and ver == self._sync_ver:
-            # common steady-state tick: membership and map width unchanged
-            # since last sync — only row content can have moved
-            if self._mu_blocked or not self.worker_ids:
-                return None
-            self._refresh_dirty()
-            return self._snapshot()
-
-        eligible = []
-        mu_blocked = False
-        for w in core.workers.values():
-            if w.mn_task != 0 or w.mn_reserved != 0 or w.draining:
-                continue
-            if w.configuration.min_utilization > 0.001:
-                mu_blocked = True
-                break
-            eligible.append(w)
-        self._sync_ver = ver
-        self._mu_blocked = mu_blocked
-        if mu_blocked:
-            return None
-        ids = [w.worker_id for w in eligible]
-        if self.free is None or ids != self.worker_ids:
-            self._rebuild(eligible, n_r)
+        epoch = core.membership_epoch
+        if (
+            self._free is None
+            or self._unnamed
+            # a bump the cache did not hear (another cache was the core's
+            # then, or the epoch was moved by hand)
+            or epoch - self._epoch != self._heard
+            # a worker put into, or taken out of, core.workers unannounced
+            or len(core.workers) != len(self._workers)
+        ):
+            self._build(core, n_r)
         else:
-            # same rows, same order (worker ids never recycle, so equal
-            # ids means the same Worker objects): a pure width change
-            # and/or content drift
             if n_r != self.n_r:
                 self._widen(n_r)
-            self._refresh_dirty()
-        if not ids:
+            self._apply()
+        self._epoch = epoch
+        self._heard = 0
+        if self._mu_blocked or not self.worker_ids:
             return None
-        return self._snapshot()
-
-    def _snapshot(self) -> DenseSnapshot:
         return DenseSnapshot(
             worker_ids=self.worker_ids,
             free=self.free,
@@ -209,109 +263,137 @@ class TickStateCache:
         )
 
     # ------------------------------------------------------------------
-    def _rebuild(self, eligible: list, n_r: int) -> None:
-        """Structural change (membership or first tick): rebuild the row
-        map and every array.  Counted — steady state must never get here."""
+    def _build(self, core, n_r: int) -> None:
+        """Structural change (first sync, connect, disconnect, an unnamed
+        bump): walk every worker and build every array.  Counted — steady
+        state, gang starts and ends included, must never get here."""
         self.full_rebuilds += 1
-        n_w = len(eligible)
-        self.worker_ids = [w.worker_id for w in eligible]
-        self._workers = eligible
-        self._epochs = [w.epoch for w in eligible]
+        self._unnamed = False
+        self._flipped.clear()
+        workers = self._workers = list(core.workers.values())
+        n = len(workers)
         self.n_r = n_r
-        self.free = np.zeros((n_w, n_r), dtype=np.int64)
-        self.total = np.zeros((n_w, n_r), dtype=np.int64)
-        self.nt_free = np.zeros(n_w, dtype=np.int32)
-        self.lifetime = np.zeros(n_w, dtype=np.int32)
-        self._timed_rows = []
-        for i, w in enumerate(eligible):
-            self._write_row(i, w)
-            self.lifetime[i] = w.lifetime_secs()
-            if w.configuration.time_limit_secs > 0:
-                self._timed_rows.append(i)
+        self._ids = np.fromiter(
+            (w.worker_id for w in workers), dtype=np.int64, count=n
+        )
+        self._free = np.zeros((n, n_r), dtype=np.int64)
+        self._total = _matrix([w.resources.amounts for w in workers], n_r)
+        self._nt_free = np.zeros(n, dtype=np.int32)
+        self._lifetime = np.fromiter(
+            (w.lifetime_secs() for w in workers), dtype=np.int32, count=n
+        )
+        self._timed_rows = [
+            i for i, w in enumerate(workers)
+            if w.configuration.time_limit_secs > 0
+        ]
+        self._mask = np.fromiter(map(eligible, workers), dtype=bool, count=n)
+        mu = np.fromiter(
+            (w.configuration.min_utilization > 0.001 for w in workers),
+            dtype=bool, count=n,
+        )
+        self._mu = mu if mu.any() else None
+        self._pos = np.empty(n, dtype=np.int64)
+        dirty = self._dirty = set()
+        for i, w in enumerate(workers):
+            w.tick_row = i
+            w.tick_dirty = dirty
+        self._write_content(np.arange(n), workers)
+        self._cut()
+        self.rows_rewritten_last = len(self.worker_ids)
+        self.rows_moved_last = len(self.worker_ids)
 
     def _widen(self, n_r: int) -> None:
         """Resource map grew: pad new zero columns (a worker's dense row
         may lag the map right after a new name is interned — the scratch
         path zero-fills the same columns)."""
-        grow = n_r - self.n_r
-        self.free = np.pad(self.free, ((0, 0), (0, grow)))
-        self.total = np.pad(self.total, ((0, 0), (0, grow)))
+        pad = ((0, 0), (0, n_r - self.n_r))
+        self._free = np.pad(self._free, pad)
+        self._total = np.pad(self._total, pad)
+        self.free = np.pad(self.free, pad)
+        self.total = np.pad(self.total, pad)
         self.n_r = n_r
 
-    def _write_row(self, i: int, w) -> None:
-        """Full row write: free, POOL TOTALS and nt_free.  Only rebuild
-        and widening call this — pool totals are static per worker, so the
-        per-tick dirty path (_refresh_free_row) skips them."""
-        self._write_free_row(i, w)
-        amounts = w.resources.amounts
-        n = min(len(amounts), self.n_r)
-        row = self.total[i]
-        row[:n] = amounts[:n]
-        row[n:] = 0
+    def _write_content(self, rows: np.ndarray, workers: list) -> None:
+        """free and nt_free of `workers` into their `rows`, one C-level
+        conversion each (pool totals are static per worker: only a build
+        writes them)."""
+        self._free[rows] = _matrix(list(map(_FREE, workers)), self.n_r)
+        nt_free = np.fromiter(
+            map(_NT_FREE, workers), dtype=np.int32, count=len(workers)
+        )
+        self._nt_free[rows] = np.maximum(nt_free, 0, out=nt_free)
 
-    def _write_free_row(self, i: int, w) -> None:
-        free = w.free
-        n = min(len(free), self.n_r)
-        row = self.free[i]
-        row[:n] = free[:n]
-        row[n:] = 0
-        self.nt_free[i] = w.nt_free if w.nt_free > 0 else 0
+    def _cut(self) -> None:
+        """The dense arrays anew: the eligible rows, in order."""
+        rows = self._rows = np.flatnonzero(self._mask)
+        self._pos.fill(-1)
+        self._pos[rows] = np.arange(len(rows))
+        self.free = self._free.take(rows, axis=0)
+        self.total = self._total.take(rows, axis=0)
+        self.nt_free = self._nt_free.take(rows)
+        self.lifetime = self._lifetime.take(rows)
+        self.worker_ids = self._ids.take(rows).tolist()
+        self._mu_blocked = self._mu is not None and bool(
+            self._mu.take(rows).any()
+        )
 
-    # above this dirty fraction, one C-level bulk conversion of every row
-    # beats per-row Python writes (a heavily-loaded tick can touch every
-    # worker between schedules — incremental must not lose to scratch then)
-    _BULK_DIRTY_FRACTION = 8
-
-    def _refresh_dirty(self) -> None:
+    def _apply(self) -> None:
+        """What the cache was told since the last sync: the dirty rows'
+        content, then the named workers' eligibility."""
         self.incremental_syncs += 1
-        epochs = self._epochs
         workers = self._workers
-        dirty = [
-            i for i, w in enumerate(workers) if w.epoch != epochs[i]
-        ]
-        n_w = len(workers)
-        if dirty and len(dirty) > n_w // self._BULK_DIRTY_FRACTION:
-            free_lists = [w.free for w in workers]
-            n_r = self.n_r
-            if all(len(f) == n_r for f in free_lists):
-                # one-shot C conversion of every row into persistent
-                # storage (fromiter over a chained iterator beats both
-                # np.array(list-of-lists) and slice assignment ~2.4x);
-                # pool totals are static and stay untouched
-                from itertools import chain
-
-                self.free[:] = np.fromiter(
-                    chain.from_iterable(free_lists), dtype=np.int64,
-                    count=n_w * n_r,
-                ).reshape(n_w, n_r)
-                np.maximum(
-                    np.fromiter(
-                        (w.nt_free for w in workers), dtype=np.int32,
-                        count=n_w,
-                    ),
-                    0,
-                    out=self.nt_free,
-                )
-                for i in dirty:
-                    epochs[i] = workers[i].epoch
-            else:
-                for i in dirty:
-                    self._write_free_row(i, workers[i])
-                    epochs[i] = workers[i].epoch
-        else:
-            for i in dirty:
-                self._write_free_row(i, workers[i])
-                epochs[i] = workers[i].epoch
+        dirty = list(self._dirty)
+        self._dirty.clear()
+        rows = np.asarray(dirty, dtype=np.int64)
+        if dirty:
+            self._write_content(rows, [workers[i] for i in dirty])
         for i in self._timed_rows:
-            self.lifetime[i] = workers[i].lifetime_secs()
-        self.rows_rewritten_last = len(dirty)
+            self._lifetime[i] = workers[i].lifetime_secs()
+        mask = self._mask
+        n_flips = 0
+        first = len(mask)
+        for i in self._flipped:
+            ok = eligible(workers[i])
+            if mask[i] != ok:
+                mask[i] = ok
+                n_flips += 1
+                first = min(first, i)
+        self._flipped.clear()
+        if n_flips:
+            # rows left or rejoined: every dense array is cut anew from the
+            # rows over all workers (the content above is already in them)
+            self.membership_flips += n_flips
+            was = self._pos[first:].copy()
+            self._cut()
+            now = self._pos[first:]
+            self.rows_moved_last = int(((now >= 0) & (now != was)).sum())
+            self.rows_rewritten_last = int((self._pos[rows] >= 0).sum())
+            return
+        self.rows_moved_last = 0
+        # content that moved on a worker that is no dense row (one draining
+        # towards a gang) stays in the rows over all alone
+        at = self._pos[rows]
+        rows, at = rows[at >= 0], at[at >= 0]
+        self.free[at] = self._free[rows]
+        self.nt_free[at] = self._nt_free[rows]
+        if self._timed_rows:
+            self._lifetime.take(self._rows, out=self.lifetime)
+        self.rows_rewritten_last = len(at)
 
     # ------------------------------------------------------------------
+    def reset_counters(self) -> None:
+        """A measurement window starts (`Server.reset_metrics`)."""
+        self.full_rebuilds = 0
+        self.incremental_syncs = 0
+        self.membership_flips = 0
+
     def counters(self) -> dict:
         return {
             "full_rebuilds": self.full_rebuilds,
             "incremental_syncs": self.incremental_syncs,
+            "membership_flips": self.membership_flips,
             "rows_rewritten_last": self.rows_rewritten_last,
+            "rows_moved_last": self.rows_moved_last,
             "workers": len(self.worker_ids),
             "resources": self.n_r,
         }

@@ -2063,7 +2063,7 @@ class Server:
                 else -1.0
             )
         cache = core.tick_cache.counters()
-        for key in ("full_rebuilds", "incremental_syncs"):
+        for key in ("full_rebuilds", "incremental_syncs", "membership_flips"):
             REGISTRY.counter(
                 f"hq_tick_cache_{key}_total",
                 f"tick snapshot cache {key.replace('_', ' ')}",
@@ -2821,7 +2821,7 @@ class Server:
             if worker is None or worker.draining:
                 continue
             worker.draining = True
-            self.core.bump_membership()
+            self.core.bump_membership(worker)
             # retract the queued backlog so the drain is bounded by the
             # currently RUNNING tasks only (same move as the gang drain)
             refs = []
@@ -3524,8 +3524,7 @@ class Server:
         self.lag.reset()
         self.core.tick_stats = TickPhaseStats()
         self.model.reset_stats()
-        self.core.tick_cache.full_rebuilds = 0
-        self.core.tick_cache.incremental_syncs = 0
+        self.core.tick_cache.reset_counters()
         # SLO windows + alert state clear with the measurement window
         # (ISSUE 18): steady-state burn rates must not inherit a breach
         # that happened before the reset

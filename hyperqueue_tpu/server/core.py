@@ -17,7 +17,11 @@ from hyperqueue_tpu.resources.map import ResourceIdMap, ResourceRqMap
 from hyperqueue_tpu.resources.request import ResourceRequestVariants
 from hyperqueue_tpu.scheduler.queues import TaskQueues
 from hyperqueue_tpu.scheduler.tick import WorkerRow
-from hyperqueue_tpu.scheduler.tick_cache import TickPhaseStats, TickStateCache
+from hyperqueue_tpu.scheduler.tick_cache import (
+    TickPhaseStats,
+    TickStateCache,
+    eligible,
+)
 from hyperqueue_tpu.server.lazy import LazyStore
 from hyperqueue_tpu.server.task import Task, TaskState
 from hyperqueue_tpu.server.worker import Worker
@@ -70,9 +74,10 @@ class Core:
     policy: object = None
     tick_counter: int = 0
     # bumped on every change of the schedulable-worker SET (connect,
-    # disconnect, gang reservation/claim/release): lets the tick cache
-    # skip the O(W) membership walk on the common unchanged tick.
-    # Row CONTENT changes (free/nt_free) ride on Worker.epoch instead.
+    # disconnect, gang reservation/claim/release, drain), through
+    # bump_membership alone: decision.py's capability memo, the pipeline's
+    # idle signature and the tick cache compare it.  Row CONTENT changes
+    # (free/nt_free) reach the tick cache from Worker.assign/unassign.
     membership_epoch: int = 0
     # flight recorder: ring of per-tick DecisionRecords + control-plane
     # events (utils/flight.py); reactor.schedule records into it and the
@@ -99,8 +104,15 @@ class Core:
         # materializing takes; takes need the core for task creation
         self.queues.bind_lazy(self.lazy, self)
 
-    def bump_membership(self) -> None:
+    def bump_membership(self, worker: Worker | None = None) -> None:
+        """The schedulable-worker set changed.  A site that flips ONE
+        worker's eligibility (mn_task, mn_reserved, draining) names it, once
+        a worker, before or after the flip: the tick cache then moves that
+        row alone.  With no worker named (a connect, a disconnect, anything
+        else) the cache walks every worker and rebuilds its rows
+        (`hq_tick_cache_full_rebuilds_total`)."""
         self.membership_epoch += 1
+        self.tick_cache.membership_changed(worker)
 
     def intern_rqv(self, rqv: ResourceRequestVariants) -> int:
         return self.rq_map.get_or_create(rqv)
@@ -118,7 +130,7 @@ class Core:
                 cpu_floor=w.cpu_floor(),
             )
             for w in self.workers.values()
-            if w.mn_task == 0 and w.mn_reserved == 0 and not w.draining
+            if eligible(w)
         ]
 
     def variant_amounts(

@@ -433,11 +433,11 @@ def _teardown_gang(
     clean: bool = False
 ) -> None:
     root = task.mn_workers[0] if task.mn_workers else 0
-    core.bump_membership()
     for wid in task.mn_workers:
         w = core.workers.get(wid)
         if w is not None:
             w.mn_task = 0
+            core.bump_membership(w)
             # cancel on surviving workers for ASSIGNED too: the compute
             # message may already be in flight to the root even though
             # task_running has not come back yet; worker-side cancel of an
@@ -645,11 +645,11 @@ def on_cancel_tasks(
 
 def _release_task_resources(core: Core, task: Task) -> None:
     if task.mn_workers:
-        core.bump_membership()
         for wid in task.mn_workers:
             w = core.workers.get(wid)
             if w is not None:
                 w.mn_task = 0
+                core.bump_membership(w)
         task.mn_workers = ()
         return
     worker = core.workers.get(task.assigned_worker)
@@ -741,7 +741,7 @@ def _clear_mn_reservations(core: Core, task_id: int) -> None:
     for w in core.workers.values():
         if w.mn_reserved == task_id:
             w.mn_reserved = 0
-            core.bump_membership()
+            core.bump_membership(w)
 
 
 def fused_gang_rows(core: Core, phases: dict | None = None) -> list[Batch]:
@@ -831,9 +831,9 @@ def _apply_fused_gangs(
             ):
                 continue  # stale solve: the gang retries next tick
             core.mn_queue.remove(task_id)
-            core.bump_membership()
             for w in members:
                 w.mn_task = task_id
+                core.bump_membership(w)
             task.mn_workers = tuple(w.worker_id for w in members)
             task.state = TaskState.ASSIGNED
             task.t_assigned = now
@@ -1330,11 +1330,11 @@ def _tick(core: Core, comm: Comm, model, prefill: bool, phases: dict):
                             and w.worker_id not in target
                         ):
                             w.mn_reserved = 0
-                            core.bump_membership()
+                            core.bump_membership(w)
                     for w in best[:n_nodes]:
                         newly_reserved = w.mn_reserved != task_id
                         if newly_reserved:
-                            core.bump_membership()
+                            core.bump_membership(w)
                         w.mn_reserved = task_id
                         if newly_reserved and w.prefilled_tasks:
                             # steal the queued backlog back so the drain is
@@ -1355,9 +1355,9 @@ def _tick(core: Core, comm: Comm, model, prefill: bool, phases: dict):
                                 comm.send_retract(w.worker_id, refs)
                     continue
                 _clear_mn_reservations(core, task_id)
-                core.bump_membership()
                 for w in chosen:
                     w.mn_task = task_id
+                    core.bump_membership(w)
                 task.mn_workers = tuple(w.worker_id for w in chosen)
                 task.state = TaskState.ASSIGNED
                 task.t_assigned = now

@@ -183,13 +183,24 @@ class Worker:
     # prefill and gang selection (a membership mask like mn_reserved) so it
     # converges to idle; running tasks finish normally, then the server
     # stops it. Set by `hq worker stop --drain` and the elasticity
-    # controller's scale-down path; every flip MUST bump core membership.
+    # controller's scale-down path; every flip of this, of mn_task or of
+    # mn_reserved MUST be told to the core BY NAME,
+    # `core.bump_membership(worker)`: the tick snapshot moves only the rows
+    # it is told of (an unnamed bump is legal and rebuilds every row).
     draining: bool = False
-    # dirty-tracking epoch for the persistent tick snapshot
-    # (scheduler/tick_cache.TickStateCache): every mutation of the dense
-    # scheduling state (free/nt_free) MUST bump this, or the cache serves
-    # a stale row.  assign/unassign are the only such mutation funnel.
+    # content counter of the dense scheduling state (free/nt_free): every
+    # mutation bumps it, and assign/unassign are the only such mutation
+    # funnel.  Tests and debugging may compare it; no tick walks it: the
+    # tick snapshot is TOLD which rows moved, through the two fields below
     epoch: int = 0
+    # this worker's row in the tick snapshot's rows over all connected
+    # workers, and the dirty set of the cache that holds those rows
+    # (scheduler/tick_cache.TickStateCache attaches both when it builds the
+    # rows; None = not attached, the build will read this worker whole).
+    # Every mutation of free/nt_free MUST add the row there, or the cache
+    # serves a stale row: assign/unassign do
+    tick_row: int = -1
+    tick_dirty: set | None = field(default=None, repr=False, compare=False)
 
     @classmethod
     def create(
@@ -247,6 +258,8 @@ class Worker:
                 self.free[rid] -= amount
         self.nt_free -= 1
         self.epoch += 1
+        if self.tick_dirty is not None:
+            self.tick_dirty.add(self.tick_row)
 
     def unassign(self, task_id: int, amounts: list[tuple[int, int]]) -> None:
         self.assigned_tasks.discard(task_id)
@@ -255,6 +268,8 @@ class Worker:
                 self.free[rid] += amount
         self.nt_free += 1
         self.epoch += 1
+        if self.tick_dirty is not None:
+            self.tick_dirty.add(self.tick_row)
 
     def is_idle(self) -> bool:
         return (

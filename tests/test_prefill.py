@@ -800,14 +800,8 @@ def _exit_worker_lost(env, w, low, high):
 
 
 def _exit_worker_drained(env, w, low, high):
-    from hyperqueue_tpu.server.bootstrap import Server
-
     gone = set(w.prefilled_tasks)
-    host = types.SimpleNamespace(
-        core=env.core, comm=env.comm, _draining={},
-        emit_event=lambda *a, **k: None,
-    )
-    assert Server.start_drain(host, [w.worker_id]) == [w.worker_id]
+    assert env.start_drain([w.worker_id]) == [w.worker_id]
     assert _answer_retracts(env, ok=True) == len(gone)
     return gone
 

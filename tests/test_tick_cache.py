@@ -43,29 +43,63 @@ def _assert_kwargs_equal(a, b):
         assert np.array_equal(np.asarray(a[key]), np.asarray(b[key])), key
 
 
+def _assert_snapshot_is_scratch(core):
+    """The cache's dense rows against rows read whole from the workers,
+    array by array, and (where tasks wait) `paranoid_check` besides."""
+    snap = core.tick_cache.sync(core)
+    rows = core.worker_rows()
+    if snap is None:
+        assert not rows or any(r.cpu_floor > 0 for r in rows) or any(
+            w.configuration.min_utilization > 0.001
+            for w in core.workers.values()
+        )
+        return
+    n_r = len(core.resource_map)
+    assert snap.worker_ids == [r.worker_id for r in rows]
+    assert isinstance(snap.worker_ids, list)
+    assert snap.free.shape == snap.total.shape == (len(rows), n_r)
+    assert snap.free.dtype == snap.total.dtype == np.int64
+    assert snap.nt_free.dtype == snap.lifetime.dtype == np.int32
+    for i, r in enumerate(rows):
+        pad = [0] * (n_r - len(r.free))
+        assert snap.free[i].tolist() == list(r.free) + pad, r.worker_id
+        pad = [0] * (n_r - len(r.total))
+        assert snap.total[i].tolist() == list(r.total) + pad, r.worker_id
+        assert snap.nt_free[i] == max(r.nt_free, 0), r.worker_id
+        assert abs(int(snap.lifetime[i]) - r.lifetime_secs) <= 1
+    if core.queues.total_ready():
+        paranoid_check(
+            core, snap, create_batches(core.queues), core.rq_map,
+            core.resource_map,
+        )
+
+
 # ---------------------------------------------------------------- golden
 def test_randomized_incremental_vs_scratch_golden():
-    """>= 200 random mutation steps (submits, schedules, finishes, worker
-    joins/leaves, resource-map widening, gang reservations); after every
-    schedulable state change the incremental assembly must be
-    bit-identical to a from-scratch one.  paranoid_tick=1 additionally
-    runs the production paranoid check inside every schedule()."""
+    """>= 300 random mutation steps (submits, schedules, finishes, worker
+    joins/leaves, resource-map widening; gangs that reserve, start and end
+    through the host gang phase and through the fused one —
+    `_apply_fused_gangs`, `_release_task_resources`,
+    `_clear_mn_reservations` — cancelled gangs, drains); after EVERY step
+    the incremental snapshot must be bit-identical to a from-scratch one
+    (`paranoid_check`).  paranoid_tick=1 additionally runs the production
+    paranoid check inside every schedule()."""
     env = TestEnv()
     env.core.paranoid_tick = 1
     rng = random.Random(7)
     assigned_pool: list[int] = []
     worker_ids: list[int] = []
     extra_resources = 0
+    seen = {"fused": 0, "host": 0, "reserve": 0, "cancel": 0, "drain": 0}
 
-    for _ in range(3):
-        worker_ids.append(env.worker(cpus=rng.choice([2, 4, 8])).worker_id)
+    for _ in range(4):
+        worker_ids.append(env.worker(
+            cpus=rng.choice([2, 4, 8]), group=rng.choice("ab")).worker_id)
 
-    steps = 0
     mutations = 0
-    while mutations < 220:
+    while mutations < 320:
         op = rng.random()
-        steps += 1
-        if op < 0.30:
+        if op < 0.26:
             rqv = env.rqv(
                 cpus=rng.choice([1, 1, 2]),
                 gpus=rng.choice([0, 0, 0, 1]),
@@ -75,55 +109,95 @@ def test_randomized_incremental_vs_scratch_golden():
                 priority=(rng.randrange(0, 3), 0),
             )
             mutations += 1
-        elif op < 0.45 and assigned_pool:
+        elif op < 0.42 and assigned_pool:
+            # a finished gang leaves through _release_task_resources
             env.finish(assigned_pool.pop(rng.randrange(len(assigned_pool))))
             mutations += 1
-        elif op < 0.55:
+        elif op < 0.52:
             gpus = rng.choice([0, 0, 2])
-            worker_ids.append(
-                env.worker(cpus=rng.choice([2, 4, 8]), gpus=gpus).worker_id
-            )
+            worker_ids.append(env.worker(
+                cpus=rng.choice([2, 4, 8]), gpus=gpus,
+                group=rng.choice("ab"),
+            ).worker_id)
             mutations += 1
-        elif op < 0.62 and len(worker_ids) > 1:
+        elif op < 0.58 and len(worker_ids) > 2:
             wid = worker_ids.pop(rng.randrange(len(worker_ids)))
-            assigned = set(env.core.workers[wid].assigned_tasks)
-            env.lose_worker(wid)
-            assigned_pool[:] = [t for t in assigned_pool if t not in assigned]
+            worker = env.core.workers[wid]
+            gone = set(worker.assigned_tasks) | {worker.mn_task}
+            env.lose_worker(wid, clean=worker.draining)
+            assigned_pool[:] = [
+                t for t in assigned_pool
+                if t not in gone
+                and env.core.tasks[t].state.value == "running"
+            ]
             mutations += 1
-        elif op < 0.66:
+        elif op < 0.62:
             # widen the resource map without touching any worker (a task
             # naming a fresh resource interns it)
             extra_resources += 1
             env.core.resource_map.get_or_create(f"res{extra_resources}")
             mutations += 1
-        elif op < 0.70:
+        elif op < 0.74:
             # a pending gang reserves (and later releases) workers —
             # membership changes without connect/disconnect
-            env.submit(rqv=env.rqv(n_nodes=2), priority=(5, 0))
+            env.submit(
+                rqv=env.rqv(n_nodes=rng.choice([2, 2, 3])),
+                priority=(rng.choice([0, 5]), 0),
+            )
             mutations += 1
-        if rng.random() < 0.5 and env.core.queues.total_ready():
+        elif op < 0.78 and env.core.mn_queue:
+            # a cancelled gang lifts its reservations
+            reserved = {w.mn_reserved for w in env.core.workers.values()}
+            gang = min(env.core.mn_queue, key=lambda g: g not in reserved)
+            seen["cancel"] += gang in reserved
+            env.cancel([gang])
+            mutations += 1
+        elif op < 0.82 and len(worker_ids) > 3:
+            # a drain masks a worker out (Server.start_drain's own site)
+            wid = rng.choice(worker_ids)
+            if env.start_drain([wid]):
+                seen["drain"] += 1
+                mutations += 1
+        elif op < 0.88:
+            # the next schedules run the other gang phase
+            env.core.fused_solve = not env.core.fused_solve
+            mutations += 1
+        _assert_snapshot_is_scratch(env.core)
+        if rng.random() < 0.5 and (
+            env.core.queues.total_ready() or env.core.mn_queue
+        ):
             # schedule() runs the paranoid bit-identity check itself
             before = {
                 t for t, task in env.core.tasks.items()
-                if task.state.value == "assigned"
-            }
-            env.schedule()
-            env.start_all_assigned()
-            after = {
-                t for t, task in env.core.tasks.items()
                 if task.state.value == "running"
             }
-            assigned_pool.extend(after - before)
+            flips0 = env.core.tick_cache.membership_flips
+            env.schedule()
+            env.start_all_assigned()
+            started = {
+                t for t, task in env.core.tasks.items()
+                if task.state.value == "running"
+            } - before
+            assigned_pool.extend(started)
+            if any(env.core.tasks[t].mn_workers for t in started):
+                seen["fused" if env.core.fused_solve else "host"] += 1
+            seen["reserve"] += any(
+                w.mn_reserved for w in env.core.workers.values())
+            _assert_snapshot_is_scratch(env.core)
+            assert env.core.tick_cache.membership_flips >= flips0
         # independent explicit comparison of both assembly paths
         if env.core.queues.total_ready() and any(
-            w.mn_task == 0 and w.mn_reserved == 0
+            w.mn_task == 0 and w.mn_reserved == 0 and not w.draining
             for w in env.core.workers.values()
         ):
             _assert_kwargs_equal(
                 _scratch_kwargs(env.core), _incremental_kwargs(env.core)
             )
-    assert mutations >= 220
+    assert mutations >= 320
     assert env.core.tick_cache.incremental_syncs > 0
+    assert env.core.tick_cache.membership_flips > 0
+    # the walk took every road it was built to take
+    assert all(seen.values()), seen
 
 
 # ---------------------------------------------------------- dirty tracking
@@ -159,6 +233,224 @@ def test_connect_disconnect_trigger_rebuild():
     env.schedule()
     assert env.core.tick_cache.full_rebuilds == r0 + 2
     assert w1.worker_id not in env.core.tick_cache.worker_ids
+
+
+def _record_rows_of_every_sync(cache) -> list:
+    """Wrap `cache.sync` so the dense row set after each call is kept."""
+    seen: list = []
+    sync = cache.sync
+
+    def recording(core):
+        snap = sync(core)
+        seen.append(set(cache.worker_ids))
+        return snap
+
+    cache.sync = recording
+    return seen
+
+
+def test_steady_gang_churn_flips_rows_and_rebuilds_nothing():
+    """A gang starts and one ends every tick (`_apply_fused_gangs`,
+    `_release_task_resources`): the rows that leave and rejoin are flips,
+    counted one by one, and no tick builds the rows whole."""
+    env = TestEnv()
+    env.core.fused_solve = True
+    env.core.paranoid_tick = 1
+    for i in range(12):
+        env.worker(cpus=4, group="ab"[i % 2])
+    gangs = [env.submit(rqv=env.rqv(n_nodes=2), priority=(1, 0))[0]
+             for _ in range(3)]
+    env.submit(n=4)
+    env.schedule()
+    env.start_all_assigned()
+    running = [g for g in gangs if env.core.tasks[g].mn_workers]
+    assert len(running) == 3
+    cache = env.core.tick_cache
+    env.schedule()  # the three gangs' rows leave
+    assert len(cache.worker_ids) == 6
+    rebuilds, flips = cache.full_rebuilds, cache.membership_flips
+    rows = _record_rows_of_every_sync(cache)
+    rows.append(set(cache.worker_ids))
+    for _ in range(20):
+        env.finish(running.pop(0))
+        gang = env.submit(rqv=env.rqv(n_nodes=2), priority=(1, 0))[0]
+        env.submit(n=1)
+        env.schedule()
+        env.start_all_assigned()
+        assert env.core.tasks[gang].mn_workers, "the new gang starts at once"
+        running.append(gang)
+        # two rows rejoined, the two the last tick's gang took left
+        assert cache.rows_moved_last > 0
+    assert cache.full_rebuilds == rebuilds
+    moved = sum(len(a ^ b) for a, b in zip(rows, rows[1:]))
+    assert moved >= 40
+    assert cache.membership_flips - flips == moved
+    assert cache.counters()["membership_flips"] == cache.membership_flips
+    _assert_snapshot_is_scratch(env.core)
+
+
+def test_unnamed_bump_and_unseen_worker_build_the_rows_whole():
+    env = TestEnv()
+    workers = [env.worker(cpus=4) for _ in range(4)]
+    env.submit(n=2)
+    env.schedule()
+    cache = env.core.tick_cache
+    r0, f0 = cache.full_rebuilds, cache.membership_flips
+    # a bump that names no worker: everything is walked, and found
+    workers[1].mn_reserved = 77
+    env.core.bump_membership()
+    _assert_snapshot_is_scratch(env.core)
+    assert workers[1].worker_id not in cache.worker_ids
+    assert (cache.full_rebuilds, cache.membership_flips) == (r0 + 1, f0)
+    # named: a flip, no build
+    workers[1].mn_reserved = 0
+    env.core.bump_membership(workers[1])
+    _assert_snapshot_is_scratch(env.core)
+    assert (cache.full_rebuilds, cache.membership_flips) == (r0 + 1, f0 + 1)
+    # a worker put into core.workers with no word to anybody
+    from hyperqueue_tpu.server.worker import Worker
+
+    stray = Worker.create(
+        env.core.worker_id_counter.next(), workers[0].configuration,
+        env.core.resource_map,
+    )
+    env.core.workers[stray.worker_id] = stray
+    _assert_snapshot_is_scratch(env.core)
+    assert stray.worker_id in cache.worker_ids
+    assert cache.full_rebuilds == r0 + 2
+    # a named worker the rows do not hold (it joined unseen, as above, and
+    # another left: the count alone would not tell)
+    env.core.workers.pop(workers[3].worker_id)
+    stray2 = Worker.create(
+        env.core.worker_id_counter.next(), workers[0].configuration,
+        env.core.resource_map,
+    )
+    env.core.workers[stray2.worker_id] = stray2
+    env.core.bump_membership(stray2)
+    _assert_snapshot_is_scratch(env.core)
+    assert cache.full_rebuilds == r0 + 3
+    # an epoch moved behind the cache's back
+    workers[2].draining = True
+    env.core.membership_epoch += 1
+    _assert_snapshot_is_scratch(env.core)
+    assert cache.full_rebuilds == r0 + 4
+    # and a lost worker's later bookkeeping reaches no row of the new build
+    gone = workers[3]
+    gone.assign(424_242, [(0, 10_000)])
+    _assert_snapshot_is_scratch(env.core)
+    assert cache.rows_rewritten_last == 0
+    assert cache.full_rebuilds == r0 + 4
+
+
+@pytest.mark.parametrize("n_dirty", [0, 1, 2, 3, 16],
+                         ids=lambda n: f"{n}-of-16")
+def test_dirty_rows_give_scratch_arrays_whatever_their_share(n_dirty):
+    """One path whatever the share of dirty rows: 1, n/8 (where a deleted
+    threshold once switched to a second path), just above it, and every
+    row — with workers whose `free` is shorter than the map among them."""
+    env = TestEnv()
+    workers = [env.worker(cpus=8, gpus=2 if i % 3 == 0 else 0)
+               for i in range(16)]
+    env.core.resource_map.get_or_create("fpga")
+    env.submit(n=5, rqv=env.rqv(cpus=2))
+    _assert_snapshot_is_scratch(env.core)
+    cache = env.core.tick_cache
+    rebuilds = cache.full_rebuilds
+    for k, w in enumerate(workers[:n_dirty]):
+        w.assign(900_000 + k, [(0, 10_000 * (k + 1))])
+        if k % 2:
+            w.assign(910_000 + k, [(0, 10_000)])
+            w.unassign(910_000 + k, [(0, 10_000)])
+    _assert_snapshot_is_scratch(env.core)
+    assert cache.rows_rewritten_last == n_dirty
+    assert cache.rows_moved_last == 0
+    assert cache.full_rebuilds == rebuilds
+    # nothing moved since: nothing is written
+    _assert_snapshot_is_scratch(env.core)
+    assert cache.rows_rewritten_last == 0
+
+
+@pytest.mark.parametrize("fused", [False, True], ids=["host", "fused"])
+def test_gang_phases_name_every_worker_they_flip(fused):
+    """The server's tick for some tens of ticks under `--paranoid-tick 1`:
+    the host gang phase (reserve, drain towards a gang, release, claim) and
+    the fused one, with gangs ending, one cancelled while it holds
+    reservations, a worker drained and one lost.  A site that flipped
+    `mn_task`, `mn_reserved` or `draining` without naming the worker would
+    fail the tick's own bit-identity check here, not in a deployment."""
+    env = TestEnv()
+    env.core.paranoid_tick = 1
+    env.core.fused_solve = fused
+    rng = random.Random(34)
+    for i in range(10):
+        env.worker(cpus=2, group="ab"[i % 2])
+    cache = env.core.tick_cache
+    running: list[int] = []
+    reserved_seen = gangs_started = gangs_ended = 0
+
+    def tick():
+        nonlocal reserved_seen, gangs_started
+        before = {t for t, task in env.core.tasks.items()
+                  if task.state.value == "running"}
+        env.schedule(prefill=True)
+        env.start_all_assigned()
+        started = {t for t, task in env.core.tasks.items()
+                   if task.state.value == "running"} - before
+        running.extend(started)
+        gangs_started += sum(
+            1 for t in started if env.core.tasks[t].mn_workers)
+        reserved_seen += any(
+            w.mn_reserved for w in env.core.workers.values())
+        _assert_snapshot_is_scratch(env.core)
+
+    env.submit(n=16, rqv=env.rqv(cpus=1))
+    tick()
+    rebuilds = cache.full_rebuilds
+    for i in range(40):
+        # single-node work keeps arriving, gangs of a whole group's half
+        # wait for nodes that must drain first
+        env.submit(n=rng.randrange(1, 5), rqv=env.rqv(cpus=1))
+        if i % 3 == 0:
+            env.submit(rqv=env.rqv(n_nodes=rng.choice([2, 3])),
+                       priority=(rng.choice([0, 3]), 0))
+        for _ in range(rng.randrange(2, 7)):
+            if running:
+                t = running.pop(rng.randrange(len(running)))
+                gangs_ended += bool(env.core.tasks[t].mn_workers)
+                env.finish(t)
+        if i == 20:
+            holding = {w.mn_reserved for w in env.core.workers.values()} - {0}
+            if holding:
+                env.cancel([holding.pop()])
+        tick()
+    assert cache.full_rebuilds == rebuilds
+    assert gangs_started >= 5 and gangs_ended >= 3
+    assert cache.membership_flips >= 2 * gangs_started
+    if not fused:
+        assert reserved_seen >= 5
+    # a drain is a flip; a lost worker is the structural case
+    w = next(w for w in env.core.workers.values() if not w.mn_task)
+    assert env.start_drain([w.worker_id]) == [w.worker_id]
+    tick()
+    assert w.worker_id not in cache.worker_ids
+    assert cache.full_rebuilds == rebuilds
+    gone = set(w.assigned_tasks)
+    env.lose_worker(w.worker_id, clean=True)
+    running[:] = [t for t in running if t not in gone]
+    tick()
+    assert cache.full_rebuilds == rebuilds + 1
+
+
+def test_a_flip_nobody_named_fails_the_paranoid_tick():
+    env = TestEnv()
+    env.core.paranoid_tick = 1
+    workers = [env.worker(cpus=4) for _ in range(3)]
+    env.submit(n=2)
+    env.schedule()
+    workers[1].mn_task = 99  # what a forgetful site would do
+    env.submit(n=1)
+    with pytest.raises(AssertionError, match="row order diverged"):
+        env.schedule()
 
 
 def test_resource_map_widening_pads_columns():

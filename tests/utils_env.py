@@ -228,6 +228,18 @@ class TestEnv:
         )
         self.core.sanity_check()
 
+    def start_drain(self, worker_ids) -> list[int]:
+        """`Server.start_drain` itself, on a stand-in for the server."""
+        import types
+
+        from hyperqueue_tpu.server.bootstrap import Server
+
+        host = types.SimpleNamespace(
+            core=self.core, comm=self.comm, _draining={},
+            emit_event=lambda *a, **k: None,
+        )
+        return Server.start_drain(host, worker_ids)
+
     def cancel(self, task_ids):
         out = reactor.on_cancel_tasks(self.core, self.comm, self.events, task_ids)
         self.core.sanity_check()
