@@ -387,10 +387,15 @@ def cmd_server_stats(args) -> None:
     print(f"ticks: {tick.get('ticks', 0)}")
     phase_rows = tick.get("phases") or {}
     if phase_rows:
-        print(f"{'phase':<16}{'mean ms':>10}{'last ms':>10}{'max ms':>10}")
+        # a phase's part of `total`: the top-level phases and `unattributed`
+        # (what no span covers) sum to 1; `cycle/...` lies between ticks
+        shares = stats.get("tick_shares") or {}
+        print(f"{'phase':<24}{'mean ms':>10}{'last ms':>10}{'max ms':>10}"
+              f"{'share':>8}")
         for name, row in phase_rows.items():
-            print(f"{name:<16}{row['mean_ms']:>10.3f}"
-                  f"{row['last_ms']:>10.3f}{row['max_ms']:>10.3f}")
+            share = f"{shares[name]:>8.3f}" if name in shares else ""
+            print(f"{name:<24}{row['mean_ms']:>10.3f}"
+                  f"{row['last_ms']:>10.3f}{row['max_ms']:>10.3f}{share}")
     cache = stats.get("tick_cache") or {}
     print(
         "tick cache: "

@@ -235,7 +235,10 @@ def run_tick(
     persistent incremental snapshot: the cache only serves ticks with no
     min-utilization workers, so the mu carve-out below is skipped
     structurally.  `phases` (optional dict) collects a per-phase latency
-    breakdown in ms; `key_cache` memoizes sort keys across ticks;
+    breakdown in ms, and first takes what `key_cache` (the core's
+    `TickStateCache`) holds parked for this tick's record: a `sync` whose
+    caller had no dict, the ready path since the previous tick;
+    `key_cache` memoizes sort keys across ticks;
     `decision` (optional dict) receives the solver's verdict for this
     tick's DecisionRecord (scheduler/decision.py): status, backend,
     solve_ms, objective.
@@ -254,6 +257,8 @@ def run_tick(
     solve path — device, numpy twin, watchdog fallback, pipelined — sees
     the same weighted objective.
     """
+    if phases is not None and key_cache is not None:
+        key_cache.take_parked(phases)
     if batches is None:
         batches = create_batches(queues)
     else:

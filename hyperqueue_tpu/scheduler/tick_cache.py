@@ -55,6 +55,8 @@ from operator import attrgetter
 
 import numpy as np
 
+from hyperqueue_tpu.utils.trace import TRACER
+
 
 @dataclass(slots=True)
 class DenseSnapshot:
@@ -77,15 +79,18 @@ class TickPhaseStats:
     """Per-phase tick latency breakdown, recorded by the reactor.
 
     One entry per key that `TRACER.phase` wrote into the tick's `phases`
-    dict during one schedule(), in order: gangs, batches, assemble,
-    solve_host_prep (child /visit), solve_dispatch (children /upload,
-    /launch), device_sync (children /counts, /state), pipeline_wait (the
-    pipelined tick's wait, parent of the device_sync children there),
-    mapping, prefill (children /fill, /displace, /rebalance), decide, and
-    total (the root).  A key with a `/` lies inside its parent in time
-    (span catalog: docs/observability.md).  Surfaced through `hq server
-    stats` and the benchmark's `tick_phases_ms` so a latency regression
-    names its phase instead of one opaque number.
+    dict during one schedule(), in order: gangs, sync (child /build, only
+    when the snapshot was built whole), batches, assemble, solve_host_prep
+    (child /visit), solve_dispatch (children /upload, /launch), device_sync
+    (children /counts, /state), pipeline_wait (the pipelined tick's wait,
+    parent of the device_sync children there), mapping, prefill (children
+    /fill, /displace, /rebalance), decide, total (the root) and
+    unattributed (the root's self time: `total` less the top-level keys).
+    A key with a `/` lies inside its parent in time, but for the keys under
+    `cycle/` (cycle/ready, cycle/ready/mn_sort): the work since the
+    previous tick, outside `total` (span catalog: docs/observability.md).
+    Surfaced through `hq server stats` and the benchmark's `tick_phases_ms`
+    so a latency regression names its phase instead of one opaque number.
     """
 
     ticks: int = 0
@@ -117,26 +122,23 @@ class TickPhaseStats:
         return out
 
     def shares(self) -> dict:
-        """Phase -> fraction of the tick time the phases account for: the
-        top-level phases sum to 1.0 (`total` holds them all and a child
-        lies inside its parent, so neither is in the denominator); a
-        child's share is its part of the same whole.
+        """Phase -> fraction of `total`, the whole tick: the top-level
+        phases and `unattributed` (what no span covers) sum to 1.0; a
+        child's share is its part of the same whole.  The keys under
+        `cycle/` lie between ticks and have no share of one.
 
         The per-phase half of the profiling plane's attribution (ISSUE
         19): `hq server stats` and the simulator's results carry these
         next to the profiler's per-plane CPU shares, so a latency
         regression names the phase whose share grew rather than one
         opaque wall-clock number."""
-        total = sum(
-            t for name, t in self.totals_ms.items()
-            if name != "total" and "/" not in name
-        )
+        total = self.totals_ms.get("total", 0.0)
         if total <= 0:
             return {}
         return {
             name: round(t / total, 4)
             for name, t in sorted(self.totals_ms.items())
-            if name != "total"
+            if name != "total" and not name.startswith("cycle/")
         }
 
 
@@ -194,6 +196,10 @@ class TickStateCache:
         self._heard = 0              # bumps heard since the last sync
         self._epoch = 0              # core.membership_epoch at the last sync
         self._mu_blocked = False
+        # --- phases timed where no tick's dict was in reach (a `sync` its
+        # caller gave none, the ready path between two ticks): key -> ms,
+        # summed, until the next tick's record takes them (`take_parked`) ---
+        self.parked: dict[str, float] = {}
         # --- the dense rows: the eligible workers, same order ---
         self.worker_ids: list[int] = []
         self.n_r = 0
@@ -230,28 +236,44 @@ class TickStateCache:
         else:
             self._unnamed = True
 
-    def sync(self, core) -> DenseSnapshot | None:
+    def take_parked(self, phases: dict) -> None:
+        """Move what was timed since the last tick's record into `phases`:
+        `run_tick` and `reactor.schedule` call it, once a tick each."""
+        parked = self.parked
+        if parked:
+            for key, ms in parked.items():
+                phases[key] = phases.get(key, 0.0) + ms
+            parked.clear()
+
+    def sync(self, core, phases: dict | None = None) -> DenseSnapshot | None:
         """Bring the dense arrays up to date with `core`; returns the
         snapshot, or None when the cache cannot serve this tick (a
-        min-utilization worker is present — see module docstring)."""
-        n_r = len(core.resource_map)
-        epoch = core.membership_epoch
-        if (
-            self._free is None
-            or self._unnamed
-            # a bump the cache did not hear (another cache was the core's
-            # then, or the epoch was moved by hand)
-            or epoch - self._epoch != self._heard
-            # a worker put into, or taken out of, core.workers unannounced
-            or len(core.workers) != len(self._workers)
-        ):
-            self._build(core, n_r)
-        else:
-            if n_r != self.n_r:
-                self._widen(n_r)
-            self._apply()
-        self._epoch = epoch
-        self._heard = 0
+        min-utilization worker is present — see module docstring).  Timed
+        here as `sync` (child `sync/build` when every worker is walked),
+        into the tick's `phases`, or parked for the tick that runs next."""
+        if phases is None:
+            phases = self.parked
+        with TRACER.phase(phases, "sync"):
+            n_r = len(core.resource_map)
+            epoch = core.membership_epoch
+            if (
+                self._free is None
+                or self._unnamed
+                # a bump the cache did not hear (another cache was the
+                # core's then, or the epoch was moved by hand)
+                or epoch - self._epoch != self._heard
+                # a worker put into, or taken out of, core.workers
+                # unannounced
+                or len(core.workers) != len(self._workers)
+            ):
+                with TRACER.phase(phases, "sync/build"):
+                    self._build(core, n_r)
+            else:
+                if n_r != self.n_r:
+                    self._widen(n_r)
+                self._apply()
+            self._epoch = epoch
+            self._heard = 0
         if self._mu_blocked or not self.worker_ids:
             return None
         return DenseSnapshot(
@@ -383,6 +405,7 @@ class TickStateCache:
     # ------------------------------------------------------------------
     def reset_counters(self) -> None:
         """A measurement window starts (`Server.reset_metrics`)."""
+        self.parked.clear()
         self.full_rebuilds = 0
         self.incremental_syncs = 0
         self.membership_flips = 0

@@ -39,10 +39,12 @@ logger = logging.getLogger(__name__)
 _TICK_PHASE_SECONDS = REGISTRY.histogram(
     "hq_tick_phase_seconds",
     "scheduler tick latency per phase, timed by TRACER.phase: gangs, "
-    "batches, assemble, solve_host_prep[/visit], "
+    "sync[/build], batches, assemble, solve_host_prep[/visit], "
     "solve_dispatch[/upload|/launch], device_sync[/counts|/state], "
     "pipeline_wait, mapping, prefill[/fill|/displace|/rebalance], decide, "
-    "total (a key with a / lies inside its parent)",
+    "total, unattributed = total less the top-level phases (a key with a "
+    "/ lies inside its parent); and, outside total, the ready path since "
+    "the previous tick: cycle/ready[/mn_sort]",
     labels=("phase",),
 )
 _TICKS_TOTAL = REGISTRY.counter(
@@ -146,20 +148,24 @@ def on_new_tasks(core: Core, comm: Comm, tasks: list[Task]) -> None:
 
     Reference reactor.rs:188 (on_new_tasks).
     """
-    for task in tasks:
-        core.tasks[task.task_id] = task
-    _apply_blevel_lookahead(core, tasks)
-    for task in tasks:
-        unfinished = 0
-        for dep_id in task.deps:
-            dep = core.tasks.get(dep_id)
-            if dep is None or dep.state is TaskState.FINISHED:
-                continue
-            dep.consumers.add(task.task_id)
-            unfinished += 1
-        task.unfinished_deps = unfinished
-        if unfinished == 0:
-            _make_ready(core, task)
+    # between two ticks: timed as `cycle/ready` into the record of the tick
+    # that runs next (the stat names it)
+    with TRACER.phase(core.tick_cache.parked, "cycle/ready", root="hq",
+                      tasks=len(tasks), tick=core.tick_counter + 1):
+        for task in tasks:
+            core.tasks[task.task_id] = task
+        _apply_blevel_lookahead(core, tasks)
+        for task in tasks:
+            unfinished = 0
+            for dep_id in task.deps:
+                dep = core.tasks.get(dep_id)
+                if dep is None or dep.state is TaskState.FINISHED:
+                    continue
+                dep.consumers.add(task.task_id)
+                unfinished += 1
+            task.unfinished_deps = unfinished
+            if unfinished == 0:
+                _make_ready(core, task)
     comm.ask_for_scheduling()
 
 
@@ -223,8 +229,12 @@ def _make_ready(core: Core, task: Task) -> None:
             return
     rqv = core.rq_map.get_variants(task.rq_id)
     if rqv.is_multi_node:
-        core.mn_queue.append(task.task_id)
-        core.mn_queue.sort(key=lambda t: core.tasks[t].priority, reverse=True)
+        with TRACER.phase(core.tick_cache.parked, "cycle/ready/mn_sort",
+                          root="hq", queued=len(core.mn_queue)):
+            core.mn_queue.append(task.task_id)
+            core.mn_queue.sort(
+                key=lambda t: core.tasks[t].priority, reverse=True
+            )
     else:
         core.queues.add(task.rq_id, task.priority, task.task_id)
 
@@ -1147,11 +1157,20 @@ def schedule(
     # and the dict feeds core.tick_stats (`hq server stats`),
     # hq_tick_phase_seconds and the flight record below
     phases: dict = {}
+    # what was timed since the previous tick (`cycle/...`: the ready path)
+    # joins this tick's record here: a tick with nothing to solve never
+    # reaches run_tick, which takes it for callers that run no schedule()
+    core.tick_cache.take_parked(phases)
     # the root carries the number this tick takes (`_tick` counts it on)
     with TRACER.phase(phases, "total", tick=core.tick_counter + 1):
         assigned, prefilled, record = _tick(
             core, comm, model, prefill, phases
         )
+    # the root's self time: what no top-level span of the tick covers
+    phases["unattributed"] = max(0.0, phases["total"] - sum(
+        ms for key, ms in phases.items()
+        if key != "total" and "/" not in key
+    ))
     core.tick_stats.record(phases)
     if core.policy is not None:
         # fairness/prediction telemetry: one ledger fold + two dict reads
@@ -1476,7 +1495,7 @@ def _tick(core: Core, comm: Comm, model, prefill: bool, phases: dict):
                 _compute_message(core, task, variant)
             )
             assigned += 1
-    snapshot = core.tick_cache.sync(core)
+    snapshot = core.tick_cache.sync(core, phases)
     rows = core.worker_rows() if snapshot is None else None
     leftover_batches = None
     have_workers = (

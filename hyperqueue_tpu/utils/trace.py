@@ -13,7 +13,8 @@ a profiler.
 `TRACER.phase` is the one primitive the scheduling tick's phases are timed
 with: it knows every sink of a tick phase (the caller's `phases` dict,
 `hq_span_seconds`, the profiler's trace), so a phase is named once, where
-its work happens (span catalog: docs/observability.md).
+its work happens (span catalog: docs/observability.md).  The work between
+two ticks is timed with it too, as `hq/cycle/...` (keys `cycle/...`).
 """
 
 from __future__ import annotations
@@ -140,7 +141,10 @@ class Tracer:
         `<root>/<key>` (`<root>` itself for the tick's `total`) in a
         profiler trace, on the device operations' clock, with `metadata`
         as the event's stats.  A nested key (`a/b`) is opened inside its
-        parent's block."""
+        parent's block.  Work between two ticks is timed with `root="hq"`
+        and a key under `cycle/` (`hq/cycle/ready`): outside every tick's
+        `total`, into the dict the next tick's record takes
+        (`TickStateCache.parked`)."""
         name = root if key == "total" else f"{root}/{key}"
         return _Phase(self, phases, key, name, done, metadata)
 
