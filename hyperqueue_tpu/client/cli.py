@@ -407,6 +407,14 @@ def cmd_server_stats(args) -> None:
         f"({cache.get('rows_rewritten_last', 0)} rows rewritten, "
         f"{cache.get('rows_moved_last', 0)} moved last tick)"
     )
+    mn = stats.get("mn_queue") or {}
+    print(
+        "gang queue: "
+        f"{mn.get('queued', 0)} queued, "
+        f"{mn.get('reserved_for', 0)} holding reservations, "
+        f"{mn.get('examined_total', 0)} entries examined, "
+        f"{mn.get('swept_total', 0)} workers swept by fused ticks"
+    )
     if stats.get("shape_allocations") is not None:
         print(f"solver shape allocations: {stats['shape_allocations']}")
     wd = stats.get("watchdog") or {}

@@ -3390,6 +3390,14 @@ class Server:
             # smokes store next to the profiler's plane shares (ISSUE 19)
             "tick_shares": self.core.tick_stats.shares(),
             "tick_cache": self.core.tick_cache.counters(),
+            # the multi-node queue's bookkeeping: what the fused gang phase
+            # looked at, summed over its ticks (reactor.fused_gang_rows)
+            "mn_queue": {
+                "queued": len(self.core.mn_queue),
+                "reserved_for": len(self.core.mn_reservations),
+                "examined_total": self.core.mn_examined_total,
+                "swept_total": self.core.mn_swept_total,
+            },
             "paranoid_tick": self.core.paranoid_tick,
             "scheduler": self.scheduler_kind,
             # ISSUE 20: active weighted-objective policy (None = flat
@@ -3525,6 +3533,7 @@ class Server:
         self.core.tick_stats = TickPhaseStats()
         self.model.reset_stats()
         self.core.tick_cache.reset_counters()
+        self.core.mn_examined_total = self.core.mn_swept_total = 0
         # SLO windows + alert state clear with the measurement window
         # (ISSUE 18): steady-state burn rates must not inherit a breach
         # that happened before the reset

@@ -39,6 +39,16 @@ class Core:
     worker_id_counter: IdCounter = field(default_factory=IdCounter)
     # multi-node gang tasks waiting for enough workers, in priority order
     mn_queue: list[int] = field(default_factory=list)
+    # which workers drain for which pending gang, task id -> worker ids:
+    # the inverse of `Worker.mn_reserved` over `workers`, written by
+    # `reserve_mn` and `forget_mn_reservation` alone, so that lifting a
+    # gang's reservations visits its workers and no other
+    mn_reservations: dict[int, set[int]] = field(default_factory=dict)
+    # what the fused gang phase looked at so far: entries of `mn_queue`
+    # examined and workers visited to lift reservations, summed over ticks
+    # (`hq server stats`; a tick's own two are on its `gangs/rows` span)
+    mn_examined_total: int = 0
+    mn_swept_total: int = 0
     scheduling_needed: bool = False
     # restart fencing base for THIS boot: n_prior_boots * the generation
     # stride (task.py INSTANCE_GENERATION_STRIDE), set by journal restore.
@@ -114,6 +124,30 @@ class Core:
         self.membership_epoch += 1
         self.tick_cache.membership_changed(worker)
 
+    def reserve_mn(self, worker: Worker, task_id: int) -> None:
+        """Set `worker.mn_reserved` (0 lifts the reservation): the one place
+        the field is written, so `mn_reservations` stays its inverse and the
+        flip is told to the tick cache by name."""
+        if worker.mn_reserved == task_id:
+            return
+        self.forget_mn_reservation(worker)
+        if task_id:
+            self.mn_reservations.setdefault(task_id, set()).add(
+                worker.worker_id
+            )
+        worker.mn_reserved = task_id
+        self.bump_membership(worker)
+
+    def forget_mn_reservation(self, worker: Worker) -> None:
+        """Take `worker` out of `mn_reservations`, its field left as it is:
+        what `reserve_mn` does first, and all a worker that has left
+        `workers` needs."""
+        held = self.mn_reservations.get(worker.mn_reserved)
+        if held is not None:
+            held.discard(worker.worker_id)
+            if not held:
+                del self.mn_reservations[worker.mn_reserved]
+
     def intern_rqv(self, rqv: ResourceRequestVariants) -> int:
         return self.rq_map.get_or_create(rqv)
 
@@ -186,7 +220,12 @@ class Core:
                 assert task.unfinished_deps > 0, task
             if task.state in (TaskState.ASSIGNED, TaskState.RUNNING):
                 assert task.assigned_worker in self.workers or task.mn_workers
+        reserved: dict[int, set[int]] = {}
         for worker in self.workers.values():
+            if worker.mn_reserved:
+                reserved.setdefault(worker.mn_reserved, set()).add(
+                    worker.worker_id
+                )
             for rid, amount in enumerate(worker.free):
                 assert 0 <= amount <= worker.resources.amount(rid), (
                     worker.worker_id,
@@ -212,3 +251,6 @@ class Core:
                 held.level_counts() == levels
                 and held.lowest == min(levels, default=math.inf)
             ), (worker.worker_id, held, levels)
+        assert reserved == self.mn_reservations, (
+            reserved, self.mn_reservations
+        )

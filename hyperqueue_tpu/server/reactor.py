@@ -9,6 +9,7 @@ batches via an "ask_for_scheduling" flag + wakeup, never reentrantly
 
 from __future__ import annotations
 
+import bisect
 import logging
 import math
 from typing import Protocol
@@ -217,6 +218,27 @@ def _apply_blevel_lookahead(core: Core, tasks: list[Task]) -> None:
         _SOLVE_LOOKAHEAD_DEPTH.set(depth)
 
 
+def _mn_enqueue(core: Core, task: Task) -> None:
+    """Put a ready multi-node task into `core.mn_queue` after every queued
+    task of equal or higher priority: where an append and a stable sort by
+    descending priority would leave it, found by bisection.  The queue is in
+    that order already (every entry came this way or through
+    `resume_jobs`' sort), a queued task's priority does not change, and a
+    task is forgotten (`core.tasks`) only once its job has ended or left,
+    when the queue no longer holds it."""
+    tasks = core.tasks
+
+    def descending(task_id: int) -> tuple[int, int]:
+        user, sched = tasks[task_id].priority
+        return -user, -sched
+
+    user, sched = task.priority
+    core.mn_queue.insert(
+        bisect.bisect_right(core.mn_queue, (-user, -sched), key=descending),
+        task.task_id,
+    )
+
+
 def _make_ready(core: Core, task: Task) -> None:
     task.state = TaskState.READY
     task.t_ready = clock.now()
@@ -231,10 +253,7 @@ def _make_ready(core: Core, task: Task) -> None:
     if rqv.is_multi_node:
         with TRACER.phase(core.tick_cache.parked, "cycle/ready/mn_sort",
                           root="hq", queued=len(core.mn_queue)):
-            core.mn_queue.append(task.task_id)
-            core.mn_queue.sort(
-                key=lambda t: core.tasks[t].priority, reverse=True
-            )
+            _mn_enqueue(core, task)
     else:
         core.queues.add(task.rq_id, task.priority, task.task_id)
 
@@ -377,6 +396,7 @@ def on_remove_worker(
     worker = core.workers.pop(worker_id, None)
     if worker is None:
         return
+    core.forget_mn_reservation(worker)
     core.bump_membership()
     events.on_worker_lost(worker_id, reason)
     for task_id in list(worker.prefilled_tasks):
@@ -747,11 +767,14 @@ def _sn_runnable_on(core: Core, above_user_priority: int, workers) -> bool:
     return False
 
 
-def _clear_mn_reservations(core: Core, task_id: int) -> None:
-    for w in core.workers.values():
-        if w.mn_reserved == task_id:
-            w.mn_reserved = 0
-            core.bump_membership(w)
+def _clear_mn_reservations(core: Core, task_id: int) -> int:
+    """Lift the reservations held for `task_id`; returns how many workers it
+    visited: those `core.mn_reservations` names for the task, none where the
+    task reserved nothing."""
+    reserved = sorted(core.mn_reservations.get(task_id, ()))
+    for wid in reserved:
+        core.reserve_mn(core.workers[wid], 0)
+    return len(reserved)
 
 
 def fused_gang_rows(core: Core, phases: dict | None = None) -> list[Batch]:
@@ -761,29 +784,43 @@ def fused_gang_rows(core: Core, phases: dict | None = None) -> list[Batch]:
     ops/assign.py scan_batches).  Tasks STAY in mn_queue until their
     sentinel assignments come back and validate (`_apply_fused_gangs`) — a
     stale pipelined solve simply drops its gang and the next tick retries.
-    Done or vanished tasks leave the queue here.  Timed as `gangs/rows`
-    inside `gangs`."""
+    Only the head is read: a done or vanished task met there leaves the
+    queue, one deeper leaves when it surfaces (it is never a row).  Timed
+    as `gangs/rows` inside `gangs`; the span's `examined` and `swept` say
+    how many queue entries and how many workers the tick looked at."""
     rows: list[Batch] = []
-    with TRACER.phase(phases, "gangs"), TRACER.phase(phases, "gangs/rows"):
-        remaining_mn = []
+    with TRACER.phase(phases, "gangs"), \
+            TRACER.phase(phases, "gangs/rows") as span:
+        tasks = core.tasks
+        examined = swept = 0
         for task_id in core.mn_queue:
-            task = core.tasks.get(task_id)
+            if len(rows) == MAX_FUSED_GANG_ROWS:
+                break
+            examined += 1
+            # fused mode never reserves: lift what a host-phase tick left
+            # for this row, so the workers rejoin the dense row set, or for
+            # a task that is gone
+            swept += _clear_mn_reservations(core, task_id)
+            task = tasks.get(task_id)
             if task is None or task.is_done:
-                _clear_mn_reservations(core, task_id)
                 continue
-            remaining_mn.append(task_id)
-            if len(rows) < MAX_FUSED_GANG_ROWS:
-                # fused mode never reserves: lift any reservation left
-                # over from a host-phase tick so the workers rejoin the
-                # dense row set
-                _clear_mn_reservations(core, task_id)
-                rqv = core.rq_map.get_variants(task.rq_id)
-                rows.append(Batch(
-                    rq_id=task.rq_id, priority=task.priority, size=1,
-                    gang_task=task_id,
-                    gang_nodes=rqv.variants[0].n_nodes,
-                ))
-        core.mn_queue = remaining_mn
+            rqv = core.rq_map.get_variants(task.rq_id)
+            rows.append(Batch(
+                rq_id=task.rq_id, priority=task.priority, size=1,
+                gang_task=task_id,
+                gang_nodes=rqv.variants[0].n_nodes,
+            ))
+        if len(rows) < examined:
+            core.mn_queue[:examined] = [row.gang_task for row in rows]
+        # a task that ended deeper in the queue holds no worker either
+        for task_id in [
+            t for t in core.mn_reservations
+            if t not in tasks or tasks[t].is_done
+        ]:
+            swept += _clear_mn_reservations(core, task_id)
+        core.mn_examined_total += examined
+        core.mn_swept_total += swept
+        span.set(examined=examined, swept=swept)
     return rows
 
 
@@ -1343,18 +1380,12 @@ def _tick(core: Core, comm: Comm, model, prefill: bool, phases: dict):
                         )
                     )
                     target = {w.worker_id for w in best[:n_nodes]}
-                    for w in core.workers.values():
-                        if (
-                            w.mn_reserved == task_id
-                            and w.worker_id not in target
-                        ):
-                            w.mn_reserved = 0
-                            core.bump_membership(w)
+                    for wid in sorted(core.mn_reservations.get(task_id, ())):
+                        if wid not in target:
+                            core.reserve_mn(core.workers[wid], 0)
                     for w in best[:n_nodes]:
                         newly_reserved = w.mn_reserved != task_id
-                        if newly_reserved:
-                            core.bump_membership(w)
-                        w.mn_reserved = task_id
+                        core.reserve_mn(w, task_id)
                         if newly_reserved and w.prefilled_tasks:
                             # steal the queued backlog back so the drain is
                             # bounded by the currently-running tasks only (sent

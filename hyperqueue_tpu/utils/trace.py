@@ -96,6 +96,12 @@ class _Phase:
         self._t0 = time.perf_counter()
         return self
 
+    def set(self, **metadata) -> None:
+        """Stats that are known only inside the block (`examined=`): added
+        to the block's event in a profiler trace, like `phase`'s own."""
+        if self._annotation is not None:
+            self._annotation.set_metadata(**metadata)
+
     def __exit__(self, *exc):
         self.seconds = dt = time.perf_counter() - self._t0
         if self._annotation is not None:

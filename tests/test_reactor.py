@@ -5,6 +5,8 @@ test_scheduler_sn.rs/test_scheduler_mn.rs: dependency counting, assignment,
 worker loss with crash counters, cancellation propagation, gang scheduling.
 """
 
+import pytest
+
 from hyperqueue_tpu.server.task import TaskState
 
 from utils_env import TestEnv
@@ -473,6 +475,286 @@ def test_gang_reservation_retract_sent_once():
     env.schedule(prefill=True)
     env.schedule(prefill=True)
     assert len(env.comm.retracts) == after_first  # not re-sent every tick
+
+
+def _walked_reservations(core) -> dict:
+    """`core.mn_reservations` as a walk over the workers gives it."""
+    walked: dict = {}
+    for w in core.workers.values():
+        if w.mn_reserved:
+            walked.setdefault(w.mn_reserved, set()).add(w.worker_id)
+    return walked
+
+
+def _assert_index_is_the_walk(core):
+    assert core.mn_reservations == _walked_reservations(core)
+    assert all(
+        wid in core.workers
+        for held in core.mn_reservations.values() for wid in held
+    )
+
+
+def _busy_group(env, n, group="g1"):
+    """`n` one-cpu workers of one group, each running a task."""
+    workers = [env.worker(cpus=1, group=group) for _ in range(n)]
+    busy = env.submit(n=n)
+    env.schedule()
+    env.start_all_assigned()
+    return workers, busy
+
+
+def test_reservation_index_follows_reserve_retarget_release_and_start():
+    env = TestEnv()
+    (w1, w2, w3), busy = _busy_group(env, 3)
+    (g,) = env.submit(rqv=env.rqv(n_nodes=2))
+    env.schedule()  # host phase: the two lowest ids of the busy group drain
+    assert env.core.mn_reservations == {g: {w1.worker_id, w2.worker_id}}
+    _assert_index_is_the_walk(env.core)
+    # w3 falls idle: the reservation moves to it and to one busy worker
+    env.finish(next(iter(w3.assigned_tasks)))
+    env.schedule()
+    assert env.core.mn_reservations == {g: {w3.worker_id, w1.worker_id}}
+    assert w2.mn_reserved == 0
+    _assert_index_is_the_walk(env.core)
+    # higher-priority single-node work outranks the gang: released
+    env.submit(n=4, priority=(5, 0))
+    env.schedule()
+    assert env.core.mn_reservations == {}
+    _assert_index_is_the_walk(env.core)
+    # that work runs out; the gang reserves again, then starts on its workers
+    for _ in range(8):
+        env.start_all_assigned()
+        for task in list(env.core.tasks.values()):
+            if task.state is TaskState.RUNNING:
+                env.finish(task.task_id)
+        env.schedule()
+        _assert_index_is_the_walk(env.core)
+        if env.state(g) is TaskState.ASSIGNED:
+            break
+    assert env.state(g) is TaskState.ASSIGNED
+    assert env.core.mn_reservations == {}
+
+
+def test_reservation_index_follows_cancel_pause_resume_and_disconnect():
+    from hyperqueue_tpu.server import reactor
+
+    env = TestEnv()
+    workers, busy = _busy_group(env, 4)
+    (ga,) = env.submit(rqv=env.rqv(n_nodes=2), job=2)
+    (gb,) = env.submit(rqv=env.rqv(n_nodes=2), job=3)
+    env.schedule()  # each gang drains two workers of its own
+    ids = [w.worker_id for w in workers]
+    assert env.core.mn_reservations == {ga: set(ids[:2]), gb: set(ids[2:])}
+    _assert_index_is_the_walk(env.core)
+    env.cancel([ga])
+    assert env.core.mn_reservations == {gb: set(ids[2:])}
+    _assert_index_is_the_walk(env.core)
+    reactor.pause_jobs(env.core, env.comm, [3])
+    assert env.core.mn_reservations == {} and env.core.mn_queue == []
+    _assert_index_is_the_walk(env.core)
+    reactor.resume_jobs(env.core, env.comm, [3])
+    env.schedule()
+    assert env.core.mn_reservations == {gb: set(ids[:2])}
+    _assert_index_is_the_walk(env.core)
+    # a reserved worker disconnects: the index forgets it with the worker
+    env.lose_worker(ids[0])
+    assert env.core.mn_reservations == {gb: {ids[1]}}
+    _assert_index_is_the_walk(env.core)
+    env.schedule()  # three workers left: the gang re-targets among them
+    assert len(env.core.mn_reservations[gb]) == 2
+    _assert_index_is_the_walk(env.core)
+    for wid in list(env.core.workers):
+        env.lose_worker(wid)
+        _assert_index_is_the_walk(env.core)
+    assert env.core.mn_reservations == {}
+
+
+def _sorted_as_the_parent_did(core, queue):
+    """What `append` + the parent's stable sort left in `mn_queue`."""
+    queue.sort(key=lambda t: core.tasks[t].priority, reverse=True)
+
+
+@pytest.mark.parametrize("seed", [1, 2, 3, 5, 8, 2147483659])
+def test_ready_gang_is_inserted_where_the_sort_left_it(seed):
+    import random
+
+    rng = random.Random(seed)
+    env = TestEnv()
+    gang = env.rqv(n_nodes=2)
+    expected: list[int] = []
+    for step in range(300):
+        if expected and rng.random() < 0.15:
+            gone = rng.choice(expected)
+            env.cancel([gone])
+            expected.remove(gone)
+        else:
+            # three user levels and few scheduler levels: many ties
+            priority = (rng.randrange(3), -rng.randrange(4))
+            (task_id,) = env.submit(rqv=gang, priority=priority)
+            expected.append(task_id)
+            _sorted_as_the_parent_did(env.core, expected)
+        assert env.core.mn_queue == expected, step
+
+
+def test_ready_gangs_and_resumed_jobs_keep_the_sorts_order():
+    import random
+
+    from hyperqueue_tpu.ids import task_id_job
+    from hyperqueue_tpu.server import reactor
+
+    rng = random.Random(13)
+    env = TestEnv()
+    gang = env.rqv(n_nodes=2)
+    expected: list[int] = []
+    held: dict[int, list[int]] = {}
+    for step in range(300):
+        roll = rng.random()
+        job = rng.randrange(1, 4)
+        if roll < 0.08 and job not in held:
+            reactor.pause_jobs(env.core, env.comm, [job])
+            held[job] = [t for t in expected if task_id_job(t) == job]
+            expected = [t for t in expected if task_id_job(t) != job]
+        elif roll < 0.16 and job in held:
+            reactor.resume_jobs(env.core, env.comm, [job])
+            expected += sorted(held.pop(job))
+            _sorted_as_the_parent_did(env.core, expected)
+        else:
+            priority = (rng.randrange(3), -job)
+            (task_id,) = env.submit(rqv=gang, priority=priority, job=job)
+            if job in held:
+                held[job].append(task_id)
+            else:
+                expected.append(task_id)
+                _sorted_as_the_parent_did(env.core, expected)
+        assert env.core.mn_queue == expected, step
+    assert held or len(expected) > 100
+
+
+def _parents_fused_gang_rows(core):
+    """`fused_gang_rows` as it was before the queue was indexed: every entry
+    walked, the workers swept for each row and each dead entry."""
+    from hyperqueue_tpu.scheduler.tick import Batch
+    from hyperqueue_tpu.server import reactor
+
+    def sweep(task_id):
+        for w in core.workers.values():
+            if w.mn_reserved == task_id:
+                core.reserve_mn(w, 0)
+
+    rows, remaining = [], []
+    for task_id in core.mn_queue:
+        task = core.tasks.get(task_id)
+        if task is None or task.is_done:
+            sweep(task_id)
+            continue
+        remaining.append(task_id)
+        if len(rows) < reactor.MAX_FUSED_GANG_ROWS:
+            sweep(task_id)
+            rqv = core.rq_map.get_variants(task.rq_id)
+            rows.append(Batch(
+                rq_id=task.rq_id, priority=task.priority, size=1,
+                gang_task=task_id, gang_nodes=rqv.variants[0].n_nodes,
+            ))
+    core.mn_queue = remaining
+    return rows
+
+
+def _queue_with_reservations_and_dead_entries():
+    """Three gangs that a host-phase tick reserved two workers each, then
+    eighteen gangs ahead of them and thousands behind; of the reserved, the
+    first is a row, the second is dead deep in the queue, the third lives
+    beyond the rows; dead entries at the head and in the tail."""
+    env = TestEnv()
+    _busy_group(env, 6)
+    gang = env.rqv(n_nodes=2)
+    reserved = env.submit(n=3, rqv=gang, priority=(3, 0))
+    env.schedule()
+    assert set(env.core.mn_reservations) == set(reserved)
+    ahead = env.submit(n=18, rqv=gang, priority=(5, 0))
+    behind = env.submit(n=3000, rqv=gang, priority=(1, 0))
+    env.cancel([ahead[7]])  # so that reserved[0] is the sixteenth row
+    from hyperqueue_tpu.server.task import TaskState as State
+
+    dead = [ahead[0], ahead[3], reserved[1], behind[1500]]
+    for task_id in dead[1:]:
+        env.core.tasks[task_id].state = State.CANCELED
+    del env.core.tasks[dead[0]]
+    return env, reserved, ahead, behind, dead
+
+
+def test_fused_rows_read_the_head_and_equal_the_parents_rows():
+    from hyperqueue_tpu.server import reactor
+
+    env, reserved, ahead, behind, dead = (
+        _queue_with_reservations_and_dead_entries())
+    then, *_ = _queue_with_reservations_and_dead_entries()
+    assert then.core.mn_queue == env.core.mn_queue
+    rows_then = _parents_fused_gang_rows(then.core)
+    rows = reactor.fused_gang_rows(env.core)
+    assert rows == rows_then and len(rows) == reactor.MAX_FUSED_GANG_ROWS
+    assert rows[-1].gang_task == reserved[0]
+    # the same workers stay reserved: the live gang beyond the rows keeps
+    # its two, the row's and the dead gang's are lifted
+    assert env.core.mn_reservations == then.core.mn_reservations
+    assert set(env.core.mn_reservations) == {reserved[2]}
+    _assert_index_is_the_walk(env.core)
+    assert ({w.worker_id: w.mn_reserved for w in env.core.workers.values()}
+            == {w.worker_id: w.mn_reserved
+                for w in then.core.workers.values()})
+    # 16 live rows and the two dead entries among them were looked at, and
+    # the four workers that held a reservation to lift, of 3 021 and 6
+    assert env.core.mn_examined_total == 18
+    assert env.core.mn_swept_total == 4
+    # the live entries are the parent's, in its order; the dead entries
+    # beyond the head wait there and are no row
+    live = [t for t in env.core.mn_queue if t not in dead]
+    assert live == then.core.mn_queue
+    assert [t for t in env.core.mn_queue if t in dead] == dead[2:]
+    assert not {r.gang_task for r in rows} & set(dead)
+    # the next tick finds nothing dead at the head and nothing to lift
+    assert reactor.fused_gang_rows(env.core) == rows
+    assert env.core.mn_examined_total == 18 + 16
+    assert env.core.mn_swept_total == 4
+
+
+def test_dead_entry_is_dropped_when_it_reaches_the_head():
+    from hyperqueue_tpu.server import reactor
+
+    env = TestEnv()
+    gang = env.rqv(n_nodes=2)
+    queued = env.submit(n=4000, rqv=gang)
+    gone = queued[2000]
+    env.core.tasks[gone].state = TaskState.CANCELED
+    rows = reactor.fused_gang_rows(env.core)
+    assert [r.gang_task for r in rows] == queued[:16]
+    assert (env.core.mn_examined_total, env.core.mn_swept_total) == (16, 0)
+    assert gone in env.core.mn_queue and len(env.core.mn_queue) == 4000
+    # the gangs ahead of it start, sixteen a tick, until it is at the head
+    del env.core.mn_queue[:1990]
+    rows = reactor.fused_gang_rows(env.core)
+    assert [r.gang_task for r in rows] == queued[1990:2000] + queued[2001:2007]
+    assert gone not in env.core.mn_queue and len(env.core.mn_queue) == 2009
+    assert (env.core.mn_examined_total, env.core.mn_swept_total) == (33, 0)
+
+
+def test_fused_tick_lifts_the_reservation_a_host_tick_left():
+    env = TestEnv()
+    workers, busy = _busy_group(env, 2)
+    (g,) = env.submit(rqv=env.rqv(n_nodes=2))
+    env.schedule()  # host phase
+    assert all(w.mn_reserved == g for w in workers)
+    env.core.fused_solve = True
+    env.schedule()  # fused: the gang is a row, nobody drains for it
+    assert all(w.mn_reserved == 0 for w in workers)
+    assert env.core.mn_reservations == {}
+    assert (env.core.mn_examined_total, env.core.mn_swept_total) == (1, 2)
+    assert len(env.core.tick_cache.sync(env.core).worker_ids) == 2
+    env.schedule()
+    assert (env.core.mn_examined_total, env.core.mn_swept_total) == (2, 2)
+    for t in busy:
+        env.finish(t)
+    env.schedule()
+    assert env.state(g) is TaskState.ASSIGNED
 
 
 def test_mn_task_fail_releases_gang():

@@ -606,6 +606,37 @@ def test_sync_and_the_ready_path_lie_in_the_profilers_trace(tmp_path):
                for s0, s1, _ in sorts)
 
 
+def test_gang_rows_span_says_what_it_examined_and_swept(tmp_path):
+    import jax
+    from jax.profiler import ProfileData
+
+    from hyperqueue_tpu.server import reactor
+    from hyperqueue_tpu.server.task import TaskState
+
+    env = _env("numpy", workers=2, tasks=4)
+    env.schedule()
+    env.start_all_assigned()
+    gangs = env.submit(n=40, rqv=env.rqv(n_nodes=2))
+    env.schedule()                   # host phase: both workers drain
+    assert env.core.mn_reservations == {gangs[0]: set(env.core.workers)}
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        reactor.fused_gang_rows(env.core, {})
+        env.core.tasks[gangs[1]].state = TaskState.CANCELED
+        reactor.fused_gang_rows(env.core, {})
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "plugins/profile/*/*.xplane.pb"))
+    stats = sorted(
+        (e.start_ns, dict(e.stats))
+        for plane in ProfileData.from_file(path).planes
+        for line in plane.lines for e in line.events
+        if e.name == "hq/tick/gangs/rows")
+    assert [s for _, s in stats] == [
+        {"examined": 16, "swept": 2}, {"examined": 17, "swept": 0}]
+    assert (env.core.mn_examined_total, env.core.mn_swept_total) == (33, 2)
+
+
 def test_server_stats_show_sync_unattributed_and_shares_of_total(tmp_path):
     from hyperqueue_tpu.utils.metrics import (
         histogram_summary,
@@ -627,10 +658,15 @@ def test_server_stats_show_sync_unattributed_and_shares_of_total(tmp_path):
         assert not [k for k in shares if k.startswith("cycle/")]
         assert sum(v for k, v in shares.items() if "/" not in k) == \
             pytest.approx(1.0, abs=5e-3)
+        assert stats["mn_queue"] == {
+            "queued": 0, "reserved_for": 0, "examined_total": 0,
+            "swept_total": 0}
         text = env.command(["server", "stats"])
         rows = {ln.split()[0]: ln.split() for ln in text.splitlines()
                 if ln.strip()}
         assert rows["phase"][-1] == "share"
+        assert "gang queue: 0 queued, 0 holding reservations, " \
+            "0 entries examined, 0 workers swept by fused ticks" in text
         # name, mean, last, max and, inside `total` only, the share
         assert len(rows["sync"]) == len(rows["unattributed"]) == 5
         assert len(rows["cycle/ready"]) == len(rows["total"]) == 4
