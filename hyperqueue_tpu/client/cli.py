@@ -405,7 +405,9 @@ def cmd_server_stats(args) -> None:
         f"{cache.get('incremental_syncs', 0)} incremental syncs, "
         f"{cache.get('membership_flips', 0)} membership flips "
         f"({cache.get('rows_rewritten_last', 0)} rows rewritten, "
-        f"{cache.get('rows_moved_last', 0)} moved last tick)"
+        f"{cache.get('rows_moved_last', 0)} moved last tick), "
+        f"gang inputs {cache.get('gang_input_reads', 0)} read, "
+        f"{cache.get('gang_input_walks', 0)} walked"
     )
     mn = stats.get("mn_queue") or {}
     print(

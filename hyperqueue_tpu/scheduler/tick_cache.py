@@ -29,10 +29,21 @@ to learn what changed — it is told:
   cache was not told of.  Every worker is walked, every array built, and
   ONLY these increment `full_rebuilds` — steady-state ticks, gang churn
   included, must keep it still (pinned by tests/test_tick_cache.py);
-- resource-map widening pads zero columns.
+- resource-map widening pads zero columns;
+- gang inputs (`gang_inputs`): a fused solve with gang rows needs each dense
+  row's host idleness (nothing assigned, nothing prefilled) and group.  The
+  first tick that asks after a build walks every worker once and writes two
+  columns over all rows: `_idle` and `_group` (the group names interned to
+  codes).  From then on the column of idleness is told, by `Worker.tell_idle`
+  where `assigned_tasks` (`assign`/`unassign`) or `prefilled_tasks`
+  (`PrefilledTasks.add`/`discard`) goes empty or stops being so, and a
+  tick reads both columns at the dense rows.  A build drops them; a cache no
+  tick with gang rows ever asked keeps none, and the funnels then test one
+  attribute.
 
-One cache a core: a worker is attached (`tick_row`, `tick_dirty`) to the
-cache that last built its rows over it.
+One cache a core: a worker is attached (`tick_row`, `tick_dirty`, and while
+the columns exist `tick_idle`) to the cache that last built its rows over
+it.
 
 Correctness contract: an incremental assemble must be BIT-IDENTICAL to a
 from-scratch assemble of the same state.  `paranoid_check` runs both
@@ -196,6 +207,10 @@ class TickStateCache:
         self._heard = 0              # bumps heard since the last sync
         self._epoch = 0              # core.membership_epoch at the last sync
         self._mu_blocked = False
+        # --- the gang inputs' columns over all rows: None until a tick with
+        # gang rows asks, and again after every build (`gang_inputs`) ---
+        self._idle: np.ndarray | None = None   # (N,) bool, told by workers
+        self._group: np.ndarray | None = None  # (N,) int64 group code
         # --- phases timed where no tick's dict was in reach (a `sync` its
         # caller gave none, the ready path between two ticks): key -> ms,
         # summed, until the next tick's record takes them (`take_parked`) ---
@@ -213,6 +228,8 @@ class TickStateCache:
         self.membership_flips = 0
         self.rows_rewritten_last = 0
         self.rows_moved_last = 0
+        self.gang_input_walks = 0  # gang inputs read from the workers
+        self.gang_input_reads = 0  # gang inputs read from the columns
         # sort-key memo for assemble_solve_inputs: the (scarcity,
         # objective) keys are pure per rq class + per-tick free totals;
         # totals are often unchanged tick-over-tick (e.g. release then
@@ -315,10 +332,12 @@ class TickStateCache:
         )
         self._mu = mu if mu.any() else None
         self._pos = np.empty(n, dtype=np.int64)
+        self._idle = self._group = None
         dirty = self._dirty = set()
         for i, w in enumerate(workers):
             w.tick_row = i
             w.tick_dirty = dirty
+            w.tick_idle = None
         self._write_content(np.arange(n), workers)
         self._cut()
         self.rows_rewritten_last = len(self.worker_ids)
@@ -403,23 +422,95 @@ class TickStateCache:
         self.rows_rewritten_last = len(at)
 
     # ------------------------------------------------------------------
+    def gang_inputs(self, core, worker_ids) -> tuple:
+        """(`gang_ok`, `group_ids`, walked) for the rows `worker_ids`: as
+        `walk_gang_inputs` gives them, read from the two columns when
+        `worker_ids` is this cache's own dense row list and no membership
+        change waits for the next sync; a first call after a build writes
+        the columns whole, and any other list is walked."""
+        if (
+            worker_ids is not self.worker_ids
+            or self._free is None
+            # a worker named since the sync may have started a gang, which
+            # the walk's `is_idle` sees and the column does not
+            or self._heard
+        ):
+            self.gang_input_walks += 1
+            return (*walk_gang_inputs(core.workers, worker_ids), True)
+        walked = self._idle is None
+        if walked:
+            self.gang_input_walks += 1
+            self._write_gang_columns()
+        else:
+            self.gang_input_reads += 1
+        rows = self._rows
+        gang_ok = self._idle.take(rows).astype(np.int32)
+        # groups renumbered by first appearance in the rows, as the walk
+        # numbers them: the kernel takes the first group with n eligible
+        # workers, so this order is part of the answer
+        _, first, codes = np.unique(
+            self._group.take(rows), return_index=True, return_inverse=True
+        )
+        rank = np.empty(len(first), dtype=np.int32)
+        rank[np.argsort(first)] = np.arange(len(first), dtype=np.int32)
+        return gang_ok, rank[codes], walked
+
+    def _write_gang_columns(self) -> None:
+        """Idleness and group code of every row, walked from the workers,
+        who are attached to the idleness column from here on.  A worker's
+        group is fixed once it schedules, so the codes are never told."""
+        workers = self._workers
+        n = len(workers)
+        idle = self._idle = np.fromiter(
+            (not w.assigned_tasks and not w.prefilled_tasks for w in workers),
+            dtype=bool, count=n,
+        )
+        names: dict[str, int] = {}
+        self._group = np.fromiter(
+            (names.setdefault(w.configuration.group, len(names))
+             for w in workers),
+            dtype=np.int64, count=n,
+        )
+        for w in workers:
+            w.tick_idle = idle
+
+    # ------------------------------------------------------------------
     def reset_counters(self) -> None:
         """A measurement window starts (`Server.reset_metrics`)."""
         self.parked.clear()
         self.full_rebuilds = 0
         self.incremental_syncs = 0
         self.membership_flips = 0
+        self.gang_input_walks = 0
+        self.gang_input_reads = 0
 
     def counters(self) -> dict:
         return {
             "full_rebuilds": self.full_rebuilds,
             "incremental_syncs": self.incremental_syncs,
             "membership_flips": self.membership_flips,
+            "gang_input_walks": self.gang_input_walks,
+            "gang_input_reads": self.gang_input_reads,
             "rows_rewritten_last": self.rows_rewritten_last,
             "rows_moved_last": self.rows_moved_last,
             "workers": len(self.worker_ids),
             "resources": self.n_r,
         }
+
+
+def walk_gang_inputs(workers: dict, worker_ids) -> tuple:
+    """The gang inputs read from the workers themselves: per row of
+    `worker_ids`, `gang_ok` (1 where `Worker.is_idle`) and `group_ids`, the
+    groups numbered by first appearance in the rows; int32 arrays."""
+    gmap: dict[str, int] = {}
+    gang_ok = []
+    group_ids = []
+    for wid in worker_ids:
+        w = workers[wid]
+        gang_ok.append(w.is_idle())
+        group_ids.append(gmap.setdefault(w.configuration.group, len(gmap)))
+    return (np.array(gang_ok, dtype=np.int32),
+            np.array(group_ids, dtype=np.int32))
 
 
 def paranoid_check(core, snapshot: DenseSnapshot, batches, rq_map,
@@ -431,10 +522,30 @@ def paranoid_check(core, snapshot: DenseSnapshot, batches, rq_map,
     in place but pops nothing), and compares every kwargs array exactly —
     including the fused-gang inputs (gang_nodes/gang_ok/group_onehot)
     and the policy affinity matrix when the tick carries them.  Raises
-    AssertionError naming the first differing array.  Debug tool:
+    AssertionError naming the first differing array.  On a tick with gang
+    rows, `gang_ok` and `group_ids` as the tick read them are first held to
+    a walk over the workers, and a difference names its row.  Debug tool:
     `hq server start --paranoid-tick N` runs this every N ticks.
     """
     from hyperqueue_tpu.scheduler.tick import Batch, assemble_solve_inputs
+
+    if gang_ok is not None:
+        for name, told, walked in zip(
+            ("gang_ok", "group_ids"),
+            (gang_ok, group_ids),
+            walk_gang_inputs(core.workers, snapshot.worker_ids),
+        ):
+            told = np.asarray(told)
+            assert told.shape == walked.shape, (
+                f"paranoid-tick: {name} has {told.shape[0]} rows, "
+                f"the snapshot {walked.shape[0]}"
+            )
+            (differ,) = np.nonzero(told != walked)
+            assert not len(differ), (
+                f"paranoid-tick: {name} diverged from the walk at row "
+                f"{differ[0]} (worker {snapshot.worker_ids[differ[0]]}: "
+                f"{told[differ[0]]}, walked {walked[differ[0]]})"
+            )
 
     def copy_batches(src):
         return [Batch(rq_id=b.rq_id, priority=b.priority, size=b.size,

@@ -2063,7 +2063,8 @@ class Server:
                 else -1.0
             )
         cache = core.tick_cache.counters()
-        for key in ("full_rebuilds", "incremental_syncs", "membership_flips"):
+        for key in ("full_rebuilds", "incremental_syncs", "membership_flips",
+                    "gang_input_walks", "gang_input_reads"):
             REGISTRY.counter(
                 f"hq_tick_cache_{key}_total",
                 f"tick snapshot cache {key.replace('_', ' ')}",

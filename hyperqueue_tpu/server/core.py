@@ -251,6 +251,11 @@ class Core:
                 held.level_counts() == levels
                 and held.lowest == min(levels, default=math.inf)
             ), (worker.worker_id, held, levels)
+            # and so is the tick snapshot's idleness, where it keeps one
+            if worker.tick_idle is not None:
+                assert worker.tick_idle[worker.tick_row] == (
+                    not worker.assigned_tasks and not held
+                ), worker.worker_id
         assert reserved == self.mn_reservations, (
             reserved, self.mn_reservations
         )

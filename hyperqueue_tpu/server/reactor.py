@@ -12,7 +12,7 @@ from __future__ import annotations
 import bisect
 import logging
 import math
-from typing import Protocol
+from typing import TYPE_CHECKING, Protocol
 
 from hyperqueue_tpu.ids import task_id_job, task_id_task
 from hyperqueue_tpu.scheduler import decision as decision_mod
@@ -31,6 +31,9 @@ from hyperqueue_tpu.transport.framing import attach_trace_wire
 from hyperqueue_tpu.utils.metrics import REGISTRY
 from hyperqueue_tpu.utils.trace import TRACER
 from hyperqueue_tpu.utils import clock
+
+if TYPE_CHECKING:
+    import numpy as np
 
 logger = logging.getLogger(__name__)
 
@@ -370,6 +373,10 @@ def recall_tasks(core: Core, comm: Comm, task_ids: list[int]) -> int:
         recalled += 1
     for wid, tids in per_worker.items():
         comm.send_cancel(wid, tids)
+    if recalled:
+        # the released resources may fit other jobs' ready tasks, and no
+        # other event need come to place them
+        comm.ask_for_scheduling()
     return recalled
 
 
@@ -826,21 +833,21 @@ def fused_gang_rows(core: Core, phases: dict | None = None) -> list[Batch]:
 
 def fused_gang_inputs(
     core: Core, worker_ids, phases: dict | None = None
-) -> tuple[list[int], list[int]]:
+) -> tuple[np.ndarray, np.ndarray]:
     """The worker-side gang inputs of a fused solve, aligned to the rows of
-    a dense snapshot (`worker_ids`): `gang_ok`, host idleness (prefilled
-    backlog does not show in `free`, so the kernel cannot derive it), and
-    `group_ids`, the worker-group index map, groups numbered by first
-    appearance in the rows.  Timed as `gangs/inputs` inside `gangs`."""
-    with TRACER.phase(phases, "gangs"), TRACER.phase(phases, "gangs/inputs"):
-        gmap: dict[str, int] = {}
-        gang_ok = []
-        group_ids = []
-        workers = core.workers
-        for wid in worker_ids:
-            w = workers[wid]
-            gang_ok.append(1 if w.is_idle() else 0)
-            group_ids.append(gmap.setdefault(w.group, len(gmap)))
+    a dense snapshot (`worker_ids`), int32 arrays: `gang_ok`, host idleness
+    (prefilled backlog does not show in `free`, so the kernel cannot derive
+    it), and `group_ids`, the worker-group index map, groups numbered by
+    first appearance in the rows.  Read from the snapshot's columns when
+    `worker_ids` is the snapshot's own list (`TickStateCache.gang_inputs`),
+    else walked.  Timed as `gangs/inputs` inside `gangs`; the span's
+    `walked` is 1 where the workers were walked."""
+    with TRACER.phase(phases, "gangs"), \
+            TRACER.phase(phases, "gangs/inputs") as span:
+        gang_ok, group_ids, walked = core.tick_cache.gang_inputs(
+            core, worker_ids
+        )
+        span.set(walked=int(walked))
     return gang_ok, group_ids
 
 

@@ -10,12 +10,16 @@ from __future__ import annotations
 
 import math
 from dataclasses import dataclass, field
+from typing import TYPE_CHECKING
 
 from hyperqueue_tpu.utils.constants import INF_TIME
 from hyperqueue_tpu.resources.descriptor import ResourceDescriptor
 from hyperqueue_tpu.resources.map import ResourceIdMap
 from hyperqueue_tpu.resources.worker_resources import WorkerResources
 from hyperqueue_tpu.utils import clock
+
+if TYPE_CHECKING:
+    import numpy as np
 
 
 @dataclass
@@ -105,14 +109,17 @@ class PrefilledTasks(set):
     ids (the set's other mutators refuse).  `lowest` is the lowest level
     held, above every priority when nothing is held: the displacement pass
     (reactor._prefill_displace) compares it before it looks at any task.
-    Core.sanity_check recounts."""
+    Core.sanity_check recounts.  When the set goes empty or stops being so,
+    it tells its worker (`Worker.tell_idle`): the worker's idleness may
+    have flipped."""
 
-    __slots__ = ("_levels", "lowest")
+    __slots__ = ("_levels", "lowest", "worker")
 
     def __init__(self) -> None:
         super().__init__()
         self._levels: dict[int, int] = {}
         self.lowest: float = math.inf
+        self.worker: Worker | None = None  # the owner, set by Worker
 
     def add(self, task_id: int, level: int) -> None:
         if task_id in self:
@@ -121,11 +128,15 @@ class PrefilledTasks(set):
         self._levels[level] = self._levels.get(level, 0) + 1
         if level < self.lowest:
             self.lowest = level
+        if len(self) == 1 and self.worker is not None:
+            self.worker.tell_idle()
 
     def discard(self, task_id: int, level: int) -> None:
         if task_id not in self:
             return
         set.discard(self, task_id)
+        if not self and self.worker is not None:
+            self.worker.tell_idle()
         left = self._levels[level] - 1
         if left:
             self._levels[level] = left
@@ -201,6 +212,17 @@ class Worker:
     # serves a stale row: assign/unassign do
     tick_row: int = -1
     tick_dirty: set | None = field(default=None, repr=False, compare=False)
+    # the same cache's idleness column over those rows, attached only while
+    # the cache keeps one (from the first tick with gang rows to the next
+    # build; None otherwise).  `tell_idle` is its one writer: assign/
+    # unassign and PrefilledTasks.add/discard call it where their set goes
+    # empty or stops being so
+    tick_idle: np.ndarray | None = field(
+        default=None, repr=False, compare=False
+    )
+
+    def __post_init__(self) -> None:
+        self.prefilled_tasks.worker = self
 
     @classmethod
     def create(
@@ -260,6 +282,8 @@ class Worker:
         self.epoch += 1
         if self.tick_dirty is not None:
             self.tick_dirty.add(self.tick_row)
+        if len(self.assigned_tasks) == 1:
+            self.tell_idle()
 
     def unassign(self, task_id: int, amounts: list[tuple[int, int]]) -> None:
         self.assigned_tasks.discard(task_id)
@@ -270,6 +294,19 @@ class Worker:
         self.epoch += 1
         if self.tick_dirty is not None:
             self.tick_dirty.add(self.tick_row)
+        if not self.assigned_tasks:
+            self.tell_idle()
+
+    def tell_idle(self) -> None:
+        """assigned_tasks or prefilled_tasks went empty or stopped being so:
+        write the worker's idleness into the tick snapshot's column, where
+        one is attached.  `mn_task` has no part in it: a worker that runs a
+        gang is no dense row, and the column is read at dense rows only."""
+        idle = self.tick_idle
+        if idle is not None:
+            idle[self.tick_row] = (
+                not self.assigned_tasks and not self.prefilled_tasks
+            )
 
     def is_idle(self) -> bool:
         return (

@@ -203,8 +203,9 @@ def test_extracted_functions_give_tick_the_rows_and_inputs_it_built():
     assert rows == want_rows and [b.gang_task for b in rows] == gangs
     assert core.mn_queue == gangs  # they stay queued until applied
     gang_ok, group_ids = reactor.fused_gang_inputs(core, worker_ids, phases)
-    assert (gang_ok, group_ids) == (want_ok, want_groups)
-    assert sum(gang_ok) == 3 and group_ids == [0, 0, 1, 1, 1, 0]
+    assert (gang_ok.tolist(), group_ids.tolist()) == (want_ok, want_groups)
+    assert gang_ok.dtype == group_ids.dtype == np.int32
+    assert sum(gang_ok) == 3 and group_ids.tolist() == [0, 0, 1, 1, 1, 0]
     assert {"gangs", "gangs/rows", "gangs/inputs"} <= set(phases)
     assert phases["gangs"] >= phases["gangs/rows"] + phases["gangs/inputs"]
     # and `_tick` hands the solve exactly these

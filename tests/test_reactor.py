@@ -139,6 +139,28 @@ def test_cancel_ready_and_running():
     assert any(a in tids for _, tids in env.comm.cancels)
 
 
+def test_recall_asks_for_a_tick_to_place_what_waited():
+    """A migration's recall frees its tasks' resources: the next tick must
+    come by itself, to place another job's task that waited for them."""
+    from hyperqueue_tpu.server import reactor
+
+    env = TestEnv()
+    env.worker(cpus=1)
+    (a,) = env.submit(job=1)
+    env.schedule()
+    env.start_all_assigned()
+    (b,) = env.submit(job=2)
+    env.schedule()
+    assert env.state(b) is TaskState.READY
+    reactor.pause_jobs(env.core, env.comm, [1])
+    asked = env.comm.scheduling_asked
+    assert reactor.recall_tasks(env.core, env.comm, [a]) == 1
+    assert env.comm.scheduling_asked > asked
+    env.schedule()
+    assert env.state(b) is TaskState.ASSIGNED
+    assert reactor.recall_tasks(env.core, env.comm, [a]) == 0
+
+
 def test_priorities_respected():
     env = TestEnv()
     env.worker(cpus=1)

@@ -13,7 +13,11 @@ import pytest
 from utils_env import TestEnv
 
 from hyperqueue_tpu.scheduler.tick import assemble_solve_inputs, create_batches
-from hyperqueue_tpu.scheduler.tick_cache import paranoid_check
+from hyperqueue_tpu.scheduler.tick_cache import (
+    paranoid_check,
+    walk_gang_inputs,
+)
+from hyperqueue_tpu.server import reactor
 
 
 def _scratch_kwargs(core):
@@ -74,23 +78,45 @@ def _assert_snapshot_is_scratch(core):
         )
 
 
+def _assert_gang_inputs_are_walk(core):
+    """The gang inputs as a tick with gang rows reads them, against the
+    workers: the idleness column at the dense rows is `is_idle()` row by
+    row, and the renumbered groups are the walk's."""
+    cache = core.tick_cache
+    snap = cache.sync(core)
+    if snap is None:
+        return
+    gang_ok, group_ids = reactor.fused_gang_inputs(core, snap.worker_ids)
+    rows = [core.workers[wid] for wid in snap.worker_ids]
+    assert cache._idle.take(cache._rows).tolist() == [
+        w.is_idle() for w in rows]
+    assert gang_ok.tolist() == [int(w.is_idle()) for w in rows]
+    want_ok, want_groups = walk_gang_inputs(core.workers, snap.worker_ids)
+    assert gang_ok.tolist() == want_ok.tolist()
+    assert group_ids.tolist() == want_groups.tolist()
+    assert gang_ok.dtype == group_ids.dtype == np.int32
+
+
 # ---------------------------------------------------------------- golden
 def test_randomized_incremental_vs_scratch_golden():
     """>= 300 random mutation steps (submits, schedules, finishes, worker
     joins/leaves, resource-map widening; gangs that reserve, start and end
     through the host gang phase and through the fused one —
     `_apply_fused_gangs`, `_release_task_resources`,
-    `_clear_mn_reservations` — cancelled gangs, drains); after EVERY step
-    the incremental snapshot must be bit-identical to a from-scratch one
-    (`paranoid_check`).  paranoid_tick=1 additionally runs the production
-    paranoid check inside every schedule()."""
+    `_clear_mn_reservations` — cancelled gangs, drains, prefilled tasks
+    that arrive, start and are released); after EVERY step the incremental
+    snapshot must be bit-identical to a from-scratch one
+    (`paranoid_check`), and the gang inputs read from its columns must be
+    the walk's.  paranoid_tick=1 additionally runs the production paranoid
+    check inside every schedule()."""
     env = TestEnv()
     env.core.paranoid_tick = 1
     rng = random.Random(7)
     assigned_pool: list[int] = []
     worker_ids: list[int] = []
     extra_resources = 0
-    seen = {"fused": 0, "host": 0, "reserve": 0, "cancel": 0, "drain": 0}
+    seen = {"fused": 0, "host": 0, "reserve": 0, "cancel": 0, "drain": 0,
+            "prefill": 0, "unprefill": 0, "prefill_only": 0, "read": 0}
 
     for _ in range(4):
         worker_ids.append(env.worker(
@@ -163,16 +189,38 @@ def test_randomized_incremental_vs_scratch_golden():
             env.core.fused_solve = not env.core.fused_solve
             mutations += 1
         _assert_snapshot_is_scratch(env.core)
+        reads0 = env.core.tick_cache.gang_input_reads
+        _assert_gang_inputs_are_walk(env.core)
+        seen["read"] += env.core.tick_cache.gang_input_reads - reads0
         if rng.random() < 0.5 and (
             env.core.queues.total_ready() or env.core.mn_queue
         ):
-            # schedule() runs the paranoid bit-identity check itself
+            # schedule() runs the paranoid bit-identity check itself; every
+            # other one prefills, and every third cancels a prefilled task,
+            # so prefilled sets fill and empty
             before = {
                 t for t, task in env.core.tasks.items()
                 if task.state.value == "running"
             }
             flips0 = env.core.tick_cache.membership_flips
-            env.schedule()
+            env.schedule(prefill=mutations % 2 == 0)
+            seen["prefill"] += any(
+                w.prefilled_tasks for w in env.core.workers.values())
+            _assert_gang_inputs_are_walk(env.core)
+            # a worker that runs nothing and holds prefilled tasks first:
+            # all of them are cancelled, and the last one leaving makes it
+            # idle; else one task of a busy worker
+            held = sorted(
+                (len(w.assigned_tasks) > 0, w.worker_id,
+                 sorted(w.prefilled_tasks))
+                for w in env.core.workers.values() if w.prefilled_tasks
+            )
+            if held and mutations % 3 == 0:
+                busy, _, tasks = held[0]
+                seen["prefill_only"] += not busy
+                env.cancel(tasks[:1] if busy else tasks)
+                seen["unprefill"] += 1
+                _assert_gang_inputs_are_walk(env.core)
             env.start_all_assigned()
             started = {
                 t for t, task in env.core.tasks.items()
@@ -184,6 +232,7 @@ def test_randomized_incremental_vs_scratch_golden():
             seen["reserve"] += any(
                 w.mn_reserved for w in env.core.workers.values())
             _assert_snapshot_is_scratch(env.core)
+            _assert_gang_inputs_are_walk(env.core)
             assert env.core.tick_cache.membership_flips >= flips0
         # independent explicit comparison of both assembly paths
         if env.core.queues.total_ready() and any(
@@ -196,6 +245,9 @@ def test_randomized_incremental_vs_scratch_golden():
     assert mutations >= 320
     assert env.core.tick_cache.incremental_syncs > 0
     assert env.core.tick_cache.membership_flips > 0
+    # a walk writes the columns after each build, and only then
+    cache = env.core.tick_cache
+    assert 0 < cache.gang_input_walks <= cache.full_rebuilds
     # the walk took every road it was built to take
     assert all(seen.values()), seen
 
@@ -513,6 +565,154 @@ def test_paranoid_check_detects_corruption():
         paranoid_check(
             env.core, snap, batches, env.core.rq_map, env.core.resource_map
         )
+
+
+# ------------------------------------------------------------ gang inputs
+def _gang_inputs(core):
+    snap = core.tick_cache.sync(core)
+    return reactor.fused_gang_inputs(core, snap.worker_ids)
+
+
+def test_interleaved_groups_are_numbered_by_first_appearance():
+    """Groups a, a, b, b, b, a in row order number 0, 0, 1, 1, 1, 0 from
+    the columns as from the walk; a seventh worker whose group's name sorts
+    first but appears last still numbers last."""
+    env = TestEnv()
+    for group in "aabbba":
+        env.worker(cpus=2, group=group)
+    cache = env.core.tick_cache
+    assert _gang_inputs(env.core)[1].tolist() == [0, 0, 1, 1, 1, 0]
+    assert (cache.gang_input_walks, cache.gang_input_reads) == (1, 0)
+    gang_ok, group_ids = _gang_inputs(env.core)
+    assert (cache.gang_input_walks, cache.gang_input_reads) == (1, 1)
+    assert group_ids.tolist() == [0, 0, 1, 1, 1, 0]
+    assert gang_ok.tolist() == [1] * 6
+    env.worker(cpus=2, group="0-first-by-name")
+    assert _gang_inputs(env.core)[1].tolist() == [0, 0, 1, 1, 1, 0, 2]
+    assert _gang_inputs(env.core)[1].tolist() == [0, 0, 1, 1, 1, 0, 2]
+    assert (cache.gang_input_walks, cache.gang_input_reads) == (2, 2)
+
+
+def test_a_group_whose_first_row_leaves_moves_back_in_the_order():
+    """Rows a, b, a, b: a numbers 0.  Once the first a drains, b's row comes
+    first and numbers 0 — with no build and no walk."""
+    env = TestEnv()
+    workers = [env.worker(cpus=2, group=g) for g in "abab"]
+    cache = env.core.tick_cache
+    assert _gang_inputs(env.core)[1].tolist() == [0, 1, 0, 1]
+    rebuilds, walks = cache.full_rebuilds, cache.gang_input_walks
+    assert env.start_drain([workers[0].worker_id])
+    gang_ok, group_ids = _gang_inputs(env.core)
+    assert cache.worker_ids == [w.worker_id for w in workers[1:]]
+    assert group_ids.tolist() == [0, 1, 0]
+    assert (cache.full_rebuilds, cache.gang_input_walks) == (rebuilds, walks)
+    assert group_ids.tolist() == walk_gang_inputs(
+        env.core.workers, cache.worker_ids)[1].tolist()
+
+
+def test_each_funnel_tells_the_idleness_column():
+    """assign/unassign and PrefilledTasks.add/discard each write the
+    worker's idleness where their set goes empty or stops being so; the
+    column is read, never walked, after the first call."""
+    env = TestEnv()
+    w, other = env.worker(cpus=4), env.worker(cpus=4)
+    cache = env.core.tick_cache
+    assert _gang_inputs(env.core)[0].tolist() == [1, 1]
+    walks = cache.gang_input_walks
+    w.assign(700_001, [(0, 10_000)])
+    assert _gang_inputs(env.core)[0].tolist() == [0, 1]
+    w.assign(700_002, [(0, 10_000)])
+    w.unassign(700_001, [(0, 10_000)])
+    assert _gang_inputs(env.core)[0].tolist() == [0, 1]
+    w.unassign(700_002, [(0, 10_000)])
+    assert _gang_inputs(env.core)[0].tolist() == [1, 1]
+    other.prefilled_tasks.add(700_003, 0)
+    assert _gang_inputs(env.core)[0].tolist() == [1, 0]
+    other.assign(700_004, [(0, 10_000)])
+    other.prefilled_tasks.discard(700_003, 0)
+    assert _gang_inputs(env.core)[0].tolist() == [1, 0]
+    other.unassign(700_004, [(0, 10_000)])
+    assert _gang_inputs(env.core)[0].tolist() == [1, 1]
+    # prefilled alone: the set's own emptiness flips the worker
+    other.prefilled_tasks.add(700_005, 1)
+    other.prefilled_tasks.add(700_006, 0)
+    assert _gang_inputs(env.core)[0].tolist() == [1, 0]
+    other.prefilled_tasks.discard(700_005, 1)
+    assert _gang_inputs(env.core)[0].tolist() == [1, 0]
+    other.prefilled_tasks.discard(700_006, 0)
+    assert _gang_inputs(env.core)[0].tolist() == [1, 1]
+    assert cache.gang_input_walks == walks
+    # a worker's list that is not the snapshot's own is walked
+    gang_ok, _ = reactor.fused_gang_inputs(
+        env.core, list(cache.worker_ids))
+    assert gang_ok.tolist() == [1, 1]
+    assert cache.gang_input_walks == walks + 1
+
+
+def test_a_membership_change_not_yet_synced_is_walked():
+    """A gang started on a row since the last sync: the row is still in the
+    snapshot's list, and the walk's `is_idle()` sees the gang where the
+    column cannot, so the call walks until the next sync."""
+    env = TestEnv()
+    w, _ = env.worker(cpus=4), env.worker(cpus=4)
+    cache = env.core.tick_cache
+    assert _gang_inputs(env.core)[0].tolist() == [1, 1]
+    w.mn_task = 424_242
+    env.core.bump_membership(w)
+    walks = cache.gang_input_walks
+    gang_ok, _ = reactor.fused_gang_inputs(env.core, cache.worker_ids)
+    assert gang_ok.tolist() == [0, 1]
+    assert cache.gang_input_walks == walks + 1
+    assert _gang_inputs(env.core)[0].tolist() == [1]  # synced: w left
+    assert cache.gang_input_walks == walks + 1
+
+
+def test_a_core_without_gang_rows_never_builds_the_gang_columns():
+    """Ticks that assign, prefill, finish and cancel, with no multi-node
+    task: no gang input is asked, no column is built or attached, and both
+    counters read 0."""
+    env = TestEnv()
+    env.core.fused_solve = True
+    workers = [env.worker(cpus=2, group="ab"[i % 2]) for i in range(4)]
+    ids = env.submit(n=30)
+    for _ in range(4):
+        env.schedule(prefill=True)
+        env.start_all_assigned()
+        running = [t for t in ids
+                   if env.core.tasks[t].state.value == "running"]
+        for t in running[:3]:
+            env.finish(t)
+    held = [t for w in workers for t in w.prefilled_tasks]
+    assert held, "the ticks prefilled"
+    env.cancel(held[:1])
+    cache = env.core.tick_cache
+    assert (cache.gang_input_walks, cache.gang_input_reads) == (0, 0)
+    assert cache._idle is None and cache._group is None
+    assert all(w.tick_idle is None for w in workers)
+    counters = cache.counters()
+    assert (counters["gang_input_walks"], counters["gang_input_reads"]) == \
+        (0, 0)
+
+
+def test_paranoid_check_names_the_first_gang_row_that_differs():
+    env = TestEnv()
+    workers = [env.worker(cpus=4, group="ab"[i % 2]) for i in range(4)]
+    env.submit(n=2)
+    gang_ok, group_ids = _gang_inputs(env.core)
+    snap = env.core.tick_cache.sync(env.core)
+    batches = create_batches(env.core.queues)
+    paranoid_check(env.core, snap, batches, env.core.rq_map,
+                   env.core.resource_map, gang_ok=gang_ok,
+                   group_ids=group_ids)  # clean state passes
+    # a prefilled task that bypassed its funnel
+    set.add(workers[2].prefilled_tasks, 800_001)
+    gang_ok, group_ids = _gang_inputs(env.core)
+    with pytest.raises(AssertionError, match=(
+            rf"gang_ok diverged from the walk at row 2 "
+            rf"\(worker {workers[2].worker_id}")):
+        paranoid_check(env.core, snap, batches, env.core.rq_map,
+                       env.core.resource_map, gang_ok=gang_ok,
+                       group_ids=group_ids)
 
 
 def test_phase_stats_recorded():

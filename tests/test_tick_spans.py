@@ -637,6 +637,33 @@ def test_gang_rows_span_says_what_it_examined_and_swept(tmp_path):
     assert (env.core.mn_examined_total, env.core.mn_swept_total) == (33, 2)
 
 
+def test_gang_inputs_span_says_whether_it_walked(tmp_path):
+    import jax
+    from jax.profiler import ProfileData
+
+    from hyperqueue_tpu.server import reactor
+
+    env = _env("numpy", workers=3, tasks=4)
+    cache = env.core.tick_cache
+    snap = cache.sync(env.core)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        reactor.fused_gang_inputs(env.core, snap.worker_ids, {})
+        reactor.fused_gang_inputs(env.core, snap.worker_ids, {})
+        reactor.fused_gang_inputs(env.core, list(snap.worker_ids), {})
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "plugins/profile/*/*.xplane.pb"))
+    stats = sorted(
+        (e.start_ns, dict(e.stats))
+        for plane in ProfileData.from_file(path).planes
+        for line in plane.lines for e in line.events
+        if e.name == "hq/tick/gangs/inputs")
+    assert [s for _, s in stats] == [
+        {"walked": 1}, {"walked": 0}, {"walked": 1}]
+    assert (cache.gang_input_walks, cache.gang_input_reads) == (2, 1)
+
+
 def test_server_stats_show_sync_unattributed_and_shares_of_total(tmp_path):
     from hyperqueue_tpu.utils.metrics import (
         histogram_summary,
@@ -667,6 +694,10 @@ def test_server_stats_show_sync_unattributed_and_shares_of_total(tmp_path):
         assert rows["phase"][-1] == "share"
         assert "gang queue: 0 queued, 0 holding reservations, " \
             "0 entries examined, 0 workers swept by fused ticks" in text
+        # no gang row met: the snapshot built no gang columns
+        assert (stats["tick_cache"]["gang_input_reads"],
+                stats["tick_cache"]["gang_input_walks"]) == (0, 0)
+        assert "gang inputs 0 read, 0 walked" in text
         # name, mean, last, max and, inside `total` only, the share
         assert len(rows["sync"]) == len(rows["unattributed"]) == 5
         assert len(rows["cycle/ready"]) == len(rows["total"]) == 4
