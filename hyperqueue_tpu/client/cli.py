@@ -188,6 +188,7 @@ def cmd_server_start(args) -> None:
             metrics_host=args.metrics_host,
             flight_recorder_ticks=args.flight_recorder_ticks,
             tick_pipeline=args.tick_pipeline,
+            gang_drain=args.gang_drain,
             policy_file=(
                 Path(args.policy_file) if args.policy_file else None
             ),
@@ -416,6 +417,13 @@ def cmd_server_stats(args) -> None:
         f"{mn.get('reserved_for', 0)} holding reservations, "
         f"{mn.get('examined_total', 0)} entries examined, "
         f"{mn.get('swept_total', 0)} workers swept by fused ticks"
+    )
+    drain = stats.get("gang_drain") or {}
+    print(
+        f"gang drain: {drain.get('mode', 'idle')}, "
+        f"{drain.get('reserved_total', 0)} workers reserved, "
+        f"{drain.get('reserved_busy_total', 0)} reserved ones busy at a "
+        "solve (summed per tick)"
     )
     if stats.get("shape_allocations") is not None:
         print(f"solver shape allocations: {stats['shape_allocations']}")
@@ -2554,6 +2562,14 @@ def build_parser() -> argparse.ArgumentParser:
                         "solve path the same cadence re-solves from a "
                         "fresh full upload and asserts identical counts, "
                         "and forces --tick-pipeline ticks synchronous")
+    p.add_argument("--gang-drain", choices=["idle", "busy"], default="idle",
+                   help="what a waiting multi-node gang does to busy "
+                        "members under --scheduler tpu, multichip and "
+                        "greedy-fused: idle holds idle members within one "
+                        "solve and drains nothing; busy reserves n members "
+                        "of one group across ticks, which then take no "
+                        "single-node work and empty out "
+                        "(docs/scheduler.md \"The tick\")")
     p.add_argument("--tick-pipeline", action="store_true",
                    help="two-stage async scheduling ticks: dispatch solve "
                         "N without blocking and map it at tick N+1, "

@@ -453,6 +453,8 @@ class GreedyCutScanModel:
         affinity: np.ndarray | None = None,      # (B, W) float policy
                                                  # weights (heterogeneity
                                                  # matrix rows per batch)
+        gang_resv: np.ndarray | None = None,     # (W,) int32 reservation
+                                                 # codes (--gang-drain busy)
     ) -> np.ndarray:
         """Returns counts (B, V, W) int32 (unpadded, C-contiguous)."""
         return self.solve_async(
@@ -460,6 +462,7 @@ class GreedyCutScanModel:
             priorities=priorities, total=total, all_mask=all_mask,
             weights=weights, gang_nodes=gang_nodes, gang_ok=gang_ok,
             group_onehot=group_onehot, affinity=affinity,
+            gang_resv=gang_resv,
         ).result()
 
     def solve_cells(self, *args, **kwargs) -> SolveCells:
@@ -483,6 +486,7 @@ class GreedyCutScanModel:
         self, free, nt_free, lifetime, needs, sizes, min_time,
         priorities=None, total=None, all_mask=None, weights=None,
         gang_nodes=None, gang_ok=None, group_onehot=None, affinity=None,
+        gang_resv=None,
     ):
         self.last_phases = phases = {}
         with TRACER.phase(phases, "solve_host_prep"):
@@ -490,6 +494,7 @@ class GreedyCutScanModel:
                 free, nt_free, lifetime, needs, sizes, min_time, total,
                 all_mask, phases, gang_nodes=gang_nodes, gang_ok=gang_ok,
                 group_onehot=group_onehot, affinity=affinity,
+                gang_resv=gang_resv,
             )
             backend, reason = self._backend_decision(prep["shape_key"])
         self.last_backend_reason = reason
@@ -512,7 +517,7 @@ class GreedyCutScanModel:
     # -- preparation (shared by every backend) ----------------------------
     def _prepare(self, free, nt_free, lifetime, needs, sizes, min_time,
                  total, all_mask, phases, gang_nodes=None, gang_ok=None,
-                 group_onehot=None, affinity=None) -> dict:
+                 group_onehot=None, affinity=None, gang_resv=None) -> dict:
         n_w, n_r = free.shape
         n_b, n_v, _ = needs.shape
 
@@ -589,7 +594,7 @@ class GreedyCutScanModel:
                 amask_p[:n_b, n_v:lv] = 0
             total_p[:n_w, :n_r] = total if total is not None else free
             amask_p[:n_b, :n_v, :n_r] = all_mask
-        gang_p = gok_p = goh_p = None
+        gang_p = gok_p = goh_p = resv_p = None
         pg = 0
         if has_gang:
             # gang inputs are FRESH per-solve allocations, not persistent
@@ -612,6 +617,9 @@ class GreedyCutScanModel:
                 goh_p = np.zeros((pw, pg), dtype=np.int32)
                 if group_onehot is not None:
                     goh_p[:n_w, :n_g] = group_onehot
+                if gang_resv is not None:
+                    resv_p = np.zeros(pw, dtype=np.int32)
+                    resv_p[:n_w] = gang_resv
         aff_p = pmask_p = None
         if affinity is not None:
             # like the gang inputs: FRESH per-solve allocations — weighted
@@ -645,11 +653,11 @@ class GreedyCutScanModel:
             "needs_p": needs_p, "sizes_p": sizes_p, "mt_p": mt_p,
             "total_p": total_p, "amask_p": amask_p,
             "gang_p": gang_p, "gok_p": gok_p, "goh_p": goh_p,
-            "pmask_p": pmask_p,
+            "resv_p": resv_p, "pmask_p": pmask_p,
             "class_m": class_m, "order_ids": order_ids,
             "extents": (n_b, n_v, n_w),
             "shape_key": (pw, pb, pr, pv, pm, has_all, has_gang, pg,
-                          has_pmask),
+                          has_pmask, resv_p is not None),
             "has_all": has_all, "has_gang": has_gang,
             "has_pmask": has_pmask,
             "phases": phases,
@@ -691,7 +699,7 @@ class GreedyCutScanModel:
                 prep["class_m"], prep["order_ids"], total=prep["total_p"],
                 all_mask=prep["amask_p"], gang_nodes=prep["gang_p"],
                 gang_ok=prep["gok_p"], group_onehot=prep["goh_p"],
-                policy_mask=prep["pmask_p"],
+                policy_mask=prep["pmask_p"], gang_resv=prep["resv_p"],
             )
             return counts
         counts = native_cut_scan(
@@ -771,12 +779,16 @@ class GreedyCutScanModel:
     @staticmethod
     def _gang_inputs(prep) -> list:
         """The gang inputs of a solve with gang rows, as `sync` takes
-        them: fresh content every tick, (B,), (W,) and (W, G)."""
+        them: fresh content every tick, (B,), (W,) and (W, G), and under
+        `--gang-drain busy` the (W,) reservation codes."""
         if prep["gang_p"] is None:
             return []
-        return [("gang_nodes", prep["gang_p"], 2),
-                ("gang_ok", prep["gok_p"], 1),
-                ("group_onehot", prep["goh_p"], 0)]
+        inputs = [("gang_nodes", prep["gang_p"], 2),
+                  ("gang_ok", prep["gok_p"], 1),
+                  ("group_onehot", prep["goh_p"], 0)]
+        if prep["resv_p"] is not None:
+            inputs.append(("gang_resv", prep["resv_p"], 1))
+        return inputs
 
     def _tick_inputs(self, prep) -> list:
         """What a solve brings besides the worker state, which crosses
@@ -808,6 +820,7 @@ class GreedyCutScanModel:
             gang_nodes=placed.get("gang_nodes"),
             gang_ok=placed.get("gang_ok"),
             group_onehot=placed.get("group_onehot"),
+            gang_resv=placed.get("gang_resv"),
             policy_mask=res.place_cached("policy_mask", prep["pmask_p"]),
         )
 
@@ -849,7 +862,7 @@ class GreedyCutScanModel:
             total=None if prep["total_p"] is None else prep["total_p"].copy(),
             all_mask=prep["amask_p"], gang_nodes=prep["gang_p"],
             gang_ok=prep["gok_p"], group_onehot=prep["goh_p"],
-            policy_mask=prep["pmask_p"],
+            policy_mask=prep["pmask_p"], gang_resv=prep["resv_p"],
         )
         return counts
 

@@ -148,6 +148,7 @@ class MultichipModel(GreedyCutScanModel):
             gang_nodes=placed.get("gang_nodes"),
             gang_ok=placed.get("gang_ok"),
             group_onehot=placed.get("group_onehot"),
+            gang_resv=placed.get("gang_resv"),
             policy_mask=res.place_cached(
                 "policy_mask", prep["pmask_p"], kind=3
             ),
@@ -171,7 +172,7 @@ class MultichipModel(GreedyCutScanModel):
             has_all=prep["amask_p"] is not None,
             total=prep["total_p"], gang_nodes=prep["gang_p"],
             gang_ok=prep["gok_p"], group_onehot=prep["goh_p"],
-            policy_mask=prep["pmask_p"],
+            policy_mask=prep["pmask_p"], gang_resv=prep["resv_p"],
         )
         return args, kwargs
 

@@ -73,7 +73,7 @@ _ROW_BUCKET_FLOOR = 16
 _STATE_KINDS = (0, 1, 1, 0)   # free, nt_free, lifetime, total
 
 # the inputs a solve with gang rows brings (models/greedy._gang_inputs)
-GANG_INPUT_NAMES = ("gang_nodes", "gang_ok", "group_onehot")
+GANG_INPUT_NAMES = ("gang_nodes", "gang_ok", "group_onehot", "gang_resv")
 
 # a tick with no dirty row puts the inputs that changed one by one where
 # they are no more than this, and packs them otherwise: a put costs the

@@ -48,6 +48,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from hyperqueue_tpu.ops.assign import (
     _gang_select_local,
+    _group_counts,
     _water_fill_classed,
     expand_onehots,
     scan_batches,
@@ -97,31 +98,33 @@ def _sharded_water_fill_classed(cap, remaining, class_onehot, axis):
     )
 
 
-def _sharded_gang_select(elig, group_onehot, n, axis):
+def _sharded_gang_select(elig, group_onehot, n, axis, mine=None):
     """Gang selection with cluster-wide group counts.
 
     elig (Wl,), group_onehot (Wl, G): LOCAL worker shards. The selection
     itself IS ops.assign._gang_select_local — this wrapper only gathers the
-    per-group eligible counts across devices (one (G,)-vector all_gather)
-    and feeds them in as the global totals + lower-device same-group
-    offsets. shard_map splits the worker axis contiguously, so that is the
-    single-chip "first n eligible members in global index order".
+    per-group eligible counts across devices (one (G,)-vector all_gather;
+    (2, G) with `mine`, the row's own reserved workers, as
+    ops.assign._group_counts stacks them) and feeds them in as the global
+    totals + lower-device same-group offsets. shard_map splits the worker
+    axis contiguously, so that is the single-chip "first n eligible
+    members in global index order".
     """
     my_dev = jax.lax.axis_index(axis)
-    per_group_local = jnp.sum(elig[:, None] * group_onehot, axis=0)  # (G,)
+    per_group_local = _group_counts(elig, group_onehot, mine)  # (G,)|(2, G)
     with jax.named_scope(GANG_SELECT_GATHER):
-        all_per_group = jax.lax.all_gather(per_group_local, axis)  # (D, G)
+        all_per_group = jax.lax.all_gather(per_group_local, axis)  # (D, ...)
     n_dev = all_per_group.shape[0]
+    lower = (jnp.arange(n_dev) < my_dev)[
+        (slice(None),) + (None,) * per_group_local.ndim]
     lower_dev = jnp.sum(
-        jnp.where(
-            (jnp.arange(n_dev) < my_dev)[:, None], all_per_group, 0
-        ),
-        axis=0,
-    )  # (G,) same-group eligible workers on lower-index devices
+        jnp.where(lower, all_per_group, 0), axis=0,
+    )  # same-group eligible workers on lower-index devices
+    own = {} if mine is None else {"mine": mine}
     return _gang_select_local(
         elig, group_onehot, n,
         per_group_total=jnp.sum(all_per_group, axis=0),
-        same_group_before=lower_dev,
+        same_group_before=lower_dev, **own,
     )
 
 
@@ -129,6 +132,7 @@ def _sharded_body(
     free, nt_free, lifetime, needs, sizes, min_time, class_m, order_ids,
     total=None, all_mask=None,
     gang_nodes=None, gang_ok=None, group_onehot=None, policy_mask=None,
+    gang_resv=None,
 ):
     """shard_map body: free/nt_free/lifetime/class_m/total are local worker
     shards; needs/sizes/min_time/order_ids/all_mask are replicated. The
@@ -147,15 +151,15 @@ def _sharded_body(
     def water_fill(cap, remaining, class_onehot):
         return _sharded_water_fill_classed(cap, remaining, class_onehot, "w")
 
-    def gang_select(elig, goh, n):
-        return _sharded_gang_select(elig, goh, n, "w")
+    def gang_select(elig, goh, n, mine=None):
+        return _sharded_gang_select(elig, goh, n, "w", mine)
 
     return scan_batches(
         free, nt_free, lifetime, needs, sizes, min_time, onehots, water_fill,
         total=total, all_mask=all_mask,
         gang_nodes=gang_nodes, gang_ok=gang_ok, group_onehot=group_onehot,
         gang_select=gang_select if gang_nodes is not None else None,
-        policy_mask=policy_mask,
+        policy_mask=policy_mask, gang_resv=gang_resv,
     )
 
 
@@ -163,6 +167,7 @@ def _sharded_cut_scan_impl(
     mesh: Mesh, free, nt_free, lifetime, needs, sizes, min_time, class_m,
     order_ids, total=None, all_mask=None,
     gang_nodes=None, gang_ok=None, group_onehot=None, policy_mask=None,
+    gang_resv=None,
 ):
     in_specs = [
         P("w", None),              # free
@@ -190,11 +195,14 @@ def _sharded_cut_scan_impl(
     if policy_mask is not None:
         in_specs.append(P(None, "w"))  # (B, W) per-batch worker mask
         args.append(policy_mask)
+    if gang_resv is not None:
+        in_specs.append(P("w"))
+        args.append(gang_resv)
 
     def body(free, nt_free, lifetime, needs, sizes, min_time, class_m,
              order_ids, *extra):
         i = 0
-        t = m = gn = go = goh = pm = None
+        t = m = gn = go = goh = pm = gr = None
         if total is not None:
             t = extra[i]
             i += 1
@@ -206,10 +214,14 @@ def _sharded_cut_scan_impl(
             i += 3
         if policy_mask is not None:
             pm = extra[i]
+            i += 1
+        if gang_resv is not None:
+            gr = extra[i]
         return _sharded_body(
             free, nt_free, lifetime, needs, sizes, min_time, class_m,
             order_ids, total=t, all_mask=m,
             gang_nodes=gn, gang_ok=go, group_onehot=goh, policy_mask=pm,
+            gang_resv=gr,
         )
 
     return jax.shard_map(
@@ -262,12 +274,13 @@ def sharded_cut_scan_donate(
     mesh: Mesh, free, nt_free, lifetime, batch_table, class_m, extents,
     has_all=False, total=None,
     gang_nodes=None, gang_ok=None, group_onehot=None, policy_mask=None,
+    gang_resv=None,
 ):
     """Worker-sharded variant of ops.assign.greedy_cut_scan: identical
     semantics, `free`/`nt_free` DONATED (the input buffers are consumed
     and their storage reused for `free_after`/`nt_after`).
 
-    free/total (W, R), nt_free/lifetime/gang_ok (W,), class_m (M, W),
+    free/total (W, R), nt_free/lifetime/gang_ok/gang_resv (W,), class_m (M, W),
     policy_mask (B, W) and group_onehot (W, G) sharded on axis "w";
     batch_table/gang_nodes replicated. Returns counts (B, V, W) sharded on
     W, plus free/nt_free after.
@@ -289,7 +302,7 @@ def sharded_cut_scan_donate(
         mesh, free, nt_free, lifetime, needs, sizes, min_time, class_m,
         order_ids, total=total, all_mask=all_mask,
         gang_nodes=gang_nodes, gang_ok=gang_ok, group_onehot=group_onehot,
-        policy_mask=policy_mask,
+        policy_mask=policy_mask, gang_resv=gang_resv,
     )
 
 

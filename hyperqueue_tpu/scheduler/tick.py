@@ -220,6 +220,7 @@ def run_tick(
     gang_ok=None,
     group_ids=None,
     policy=None,
+    gang_resv=None,
 ) -> list[Assignment]:
     """Solve one tick and pop assigned tasks from the queues.
 
@@ -250,6 +251,11 @@ def run_tick(
     solve at the top of its next tick (pipeline.take_result), overlapping
     the device execution with the inter-tick host work.
 
+    `gang_resv` (the dense path's gang rows only; `--gang-drain busy`):
+    the gang task each dense row is reserved for, 0 for none
+    (reactor.fused_gang_reserve); assemble_solve_inputs turns it into the
+    kernel's reservation codes.
+
     `policy` (a scheduler/policy.TickPolicyContext) carries this tick's
     resolved heterogeneity-affinity rows and per-job priority boosts; both
     fold into assemble_solve_inputs (the boost into the batch sort, the
@@ -271,6 +277,7 @@ def run_tick(
             dense=dense, phases=phases, key_cache=key_cache,
             decision=decision, pipeline=pipeline,
             gang_ok=gang_ok, group_ids=group_ids, policy=policy,
+            gang_resv=gang_resv,
         )
     if not batches or not workers:
         return []
@@ -322,7 +329,7 @@ def run_tick(
 def assemble_solve_inputs(workers, batches, rq_map, resource_map,
                           cpu_floor=None, dense=None, key_cache=None,
                           gang_ok=None, group_ids=None, policy=None,
-                          phases=None):
+                          phases=None, gang_resv=None):
     """Build the dense model.solve inputs for `batches` over `workers`.
 
     Sorts `batches` IN PLACE into the production solve order (priority,
@@ -347,6 +354,11 @@ def assemble_solve_inputs(workers, batches, rq_map, resource_map,
     class and this tick's free column totals, which steady-state ticks
     repeat.  `phases` (the tick's dict, optional) takes `assemble/gang`,
     the gang part's own span inside the caller's `assemble`.
+
+    `gang_resv` (W,) (with gang rows only) is the gang task each row is
+    reserved for, 0 for none; it becomes the kernel's `gang_resv` codes,
+    b + 1 for the gang of sorted row b and ops/assign.RESV_ELSEWHERE for a
+    gang no row carries (ops/assign.py scan_batches).
     """
     n_r = len(resource_map)
     n_b = len(batches)
@@ -680,6 +692,8 @@ def assemble_solve_inputs(workers, batches, rq_map, resource_map,
             extra["group_onehot"] = (
                 gids[:, None] == np.arange(n_g, dtype=np.int32)[None, :]
             ).astype(np.int32)
+            if gang_resv is not None:
+                extra["gang_resv"] = reservation_codes(gang_resv, batches)
     if cpu_floor is not None:
         # joint mu path (run_tick): if _range_compress shifted the cpu
         # column, ceil-shift the floors the same way (a floor must never
@@ -698,6 +712,22 @@ def assemble_solve_inputs(workers, batches, rq_map, resource_map,
         "priorities": [b.priority for b in batches],
         **extra,
     }
+
+
+def reservation_codes(gang_resv, batches) -> np.ndarray:
+    """The kernel's (W,) int32 reservation codes from the gang task each
+    row is reserved for (0: none), against `batches` in solve order."""
+    from hyperqueue_tpu.ops.assign import RESV_ELSEWHERE
+
+    resv = np.asarray(gang_resv, dtype=np.int64)
+    codes = np.zeros(len(resv), dtype=np.int32)
+    if not resv.any():
+        return codes
+    codes[resv != 0] = RESV_ELSEWHERE
+    for bi, b in enumerate(batches):
+        if b.gang_nodes:
+            codes[resv == b.gang_task] = bi + 1
+    return codes
 
 
 def fold_model_phases(phases, model, prefix: str = "") -> None:
@@ -727,12 +757,13 @@ def _count_solve(model, needs) -> None:
 def _run_main_solve(queues, workers, rq_map, resource_map, model, batches,
                     cpu_floor=None, dense=None, phases=None, key_cache=None,
                     decision=None, pipeline=None, gang_ok=None,
-                    group_ids=None, policy=None):
+                    group_ids=None, policy=None, gang_resv=None):
     with TRACER.phase(phases, "assemble"):
         kwargs = assemble_solve_inputs(
             workers, batches, rq_map, resource_map, cpu_floor=cpu_floor,
             dense=dense, key_cache=key_cache, gang_ok=gang_ok,
             group_ids=group_ids, policy=policy, phases=phases,
+            gang_resv=gang_resv,
         )
     if pipeline is not None and hasattr(model, "solve_async"):
         # pipelined dispatch: enqueue the solve and return WITHOUT mapping

@@ -39,7 +39,13 @@ to learn what changed — it is told:
   (`PrefilledTasks.add`/`discard`) goes empty or stops being so, and a
   tick reads both columns at the dense rows.  A build drops them; a cache no
   tick with gang rows ever asked keeps none, and the funnels then test one
-  attribute.
+  attribute;
+- reservations: the gang task each worker is reserved for is a third
+  column, `_resv`, written by a build and told by `Core.reserve_mn`
+  (`tell_reserved`).  Under `--gang-drain busy` (`keep_reserved`) a
+  reserved worker stays a dense row, so a reservation moves no row; under
+  `--gang-drain idle` a reservation is also a membership flip as above,
+  and the column at the dense rows is all none.
 
 One cache a core: a worker is attached (`tick_row`, `tick_dirty`, and while
 the columns exist `tick_idle`) to the cache that last built its rows over
@@ -157,10 +163,16 @@ _FREE = attrgetter("free")
 _NT_FREE = attrgetter("nt_free")
 
 
-def eligible(w) -> bool:
+def eligible(w, keep_reserved: bool = False) -> bool:
     """Is this worker a row of the dense solve?  Not while it runs a gang,
-    is reserved for one, or drains (`Core.worker_rows` asks the same)."""
-    return w.mn_task == 0 and w.mn_reserved == 0 and not w.draining
+    is reserved for one (unless `keep_reserved`: `--gang-drain busy`, where
+    the reservation is a column of the rows), or drains (`Core.worker_rows`
+    asks the same)."""
+    return (
+        w.mn_task == 0
+        and (keep_reserved or w.mn_reserved == 0)
+        and not w.draining
+    )
 
 
 def _matrix(lists: list, n_r: int) -> np.ndarray:
@@ -211,6 +223,13 @@ class TickStateCache:
         # gang rows asks, and again after every build (`gang_inputs`) ---
         self._idle: np.ndarray | None = None   # (N,) bool, told by workers
         self._group: np.ndarray | None = None  # (N,) int64 group code
+        # --- the reservation column over all rows: the gang task each
+        # worker is reserved for (0: none), written by a build and then
+        # told by `Core.reserve_mn` alone (`tell_reserved`).  While
+        # `keep_reserved` (`--gang-drain busy`, `Core.set_gang_drain`) a
+        # reserved worker stays a dense row, and the solve reads it ---
+        self._resv: np.ndarray | None = None   # (N,) int64
+        self.keep_reserved = False
         # --- phases timed where no tick's dict was in reach (a `sync` its
         # caller gave none, the ready path between two ticks): key -> ms,
         # summed, until the next tick's record takes them (`take_parked`) ---
@@ -230,6 +249,11 @@ class TickStateCache:
         self.rows_moved_last = 0
         self.gang_input_walks = 0  # gang inputs read from the workers
         self.gang_input_reads = 0  # gang inputs read from the columns
+        # `--gang-drain busy` (reactor.fused_gang_reserve): members newly
+        # reserved, and reserved members that ran a single-node task at
+        # the solve, summed per tick
+        self.gang_reserved = 0
+        self.gang_reserved_busy = 0
         # sort-key memo for assemble_solve_inputs: the (scarcity,
         # objective) keys are pure per rq class + per-tick free totals;
         # totals are often unchanged tick-over-tick (e.g. release then
@@ -252,6 +276,30 @@ class TickStateCache:
             self._flipped.add(worker.tick_row)
         else:
             self._unnamed = True
+
+    def tell_reserved(self, worker) -> None:
+        """`Core.reserve_mn`'s word: `worker`'s reservation changed.
+        Written into the column; a worker the rows do not hold makes the
+        next sync build them whole, as an unnamed bump does."""
+        if worker.tick_dirty is self._dirty and self._resv is not None:
+            self._resv[worker.tick_row] = worker.mn_reserved
+        else:
+            self._unnamed = True
+
+    def reservations(self) -> np.ndarray:
+        """The reservation column at the dense rows: (W,) int64, the gang
+        task each row is reserved for, 0 for none."""
+        return self._resv.take(self._rows)
+
+    def reservations_told(self, workers: dict) -> bool:
+        """Does the column hold every attached worker's reservation?
+        (`Core.sanity_check`)"""
+        if self._resv is None:
+            return True
+        return all(
+            self._resv[w.tick_row] == w.mn_reserved
+            for w in workers.values() if w.tick_dirty is self._dirty
+        )
 
     def take_parked(self, phases: dict) -> None:
         """Move what was timed since the last tick's record into `phases`:
@@ -325,7 +373,13 @@ class TickStateCache:
             i for i, w in enumerate(workers)
             if w.configuration.time_limit_secs > 0
         ]
-        self._mask = np.fromiter(map(eligible, workers), dtype=bool, count=n)
+        keep = self.keep_reserved
+        self._mask = np.fromiter(
+            (eligible(w, keep) for w in workers), dtype=bool, count=n
+        )
+        self._resv = np.fromiter(
+            (w.mn_reserved for w in workers), dtype=np.int64, count=n
+        )
         mu = np.fromiter(
             (w.configuration.min_utilization > 0.001 for w in workers),
             dtype=bool, count=n,
@@ -393,8 +447,9 @@ class TickStateCache:
         mask = self._mask
         n_flips = 0
         first = len(mask)
+        keep = self.keep_reserved
         for i in self._flipped:
-            ok = eligible(workers[i])
+            ok = eligible(workers[i], keep)
             if mask[i] != ok:
                 mask[i] = ok
                 n_flips += 1
@@ -483,6 +538,8 @@ class TickStateCache:
         self.membership_flips = 0
         self.gang_input_walks = 0
         self.gang_input_reads = 0
+        self.gang_reserved = 0
+        self.gang_reserved_busy = 0
 
     def counters(self) -> dict:
         return {
@@ -491,6 +548,8 @@ class TickStateCache:
             "membership_flips": self.membership_flips,
             "gang_input_walks": self.gang_input_walks,
             "gang_input_reads": self.gang_input_reads,
+            "gang_reserved": self.gang_reserved,
+            "gang_reserved_busy": self.gang_reserved_busy,
             "rows_rewritten_last": self.rows_rewritten_last,
             "rows_moved_last": self.rows_moved_last,
             "workers": len(self.worker_ids),
@@ -515,7 +574,7 @@ def walk_gang_inputs(workers: dict, worker_ids) -> tuple:
 
 def paranoid_check(core, snapshot: DenseSnapshot, batches, rq_map,
                    resource_map, gang_ok=None, group_ids=None,
-                   policy=None) -> None:
+                   policy=None, gang_resv=None) -> None:
     """Assert the incremental assembly is bit-identical to from-scratch.
 
     Runs BOTH assemble paths on copies of the batch list (assemble sorts
@@ -524,7 +583,9 @@ def paranoid_check(core, snapshot: DenseSnapshot, batches, rq_map,
     and the policy affinity matrix when the tick carries them.  Raises
     AssertionError naming the first differing array.  On a tick with gang
     rows, `gang_ok` and `group_ids` as the tick read them are first held to
-    a walk over the workers, and a difference names its row.  Debug tool:
+    a walk over the workers, and a difference names its row, as is
+    `gang_resv` (the reservation column) to the workers' `mn_reserved`.
+    Debug tool:
     `hq server start --paranoid-tick N` runs this every N ticks.
     """
     from hyperqueue_tpu.scheduler.tick import Batch, assemble_solve_inputs
@@ -546,16 +607,25 @@ def paranoid_check(core, snapshot: DenseSnapshot, batches, rq_map,
                 f"{differ[0]} (worker {snapshot.worker_ids[differ[0]]}: "
                 f"{told[differ[0]]}, walked {walked[differ[0]]})"
             )
+    if gang_resv is not None:
+        walked = [core.workers[w].mn_reserved for w in snapshot.worker_ids]
+        assert np.asarray(gang_resv).tolist() == walked, (
+            "paranoid-tick: the reservation column diverged from the walk"
+        )
 
     def copy_batches(src):
         return [Batch(rq_id=b.rq_id, priority=b.priority, size=b.size,
                       gang_task=b.gang_task, gang_nodes=b.gang_nodes)
                 for b in src]
 
-    scratch_rows = [r for r in core.worker_rows() if r.cpu_floor <= 0]
+    scratch_rows = [
+        r for r in core.worker_rows(core.tick_cache.keep_reserved)
+        if r.cpu_floor <= 0
+    ]
     k_scratch = assemble_solve_inputs(
         scratch_rows, copy_batches(batches), rq_map, resource_map,
         gang_ok=gang_ok, group_ids=group_ids, policy=policy,
+        gang_resv=gang_resv,
     )
     # key_cache=core.tick_cache: the check must exercise the SAME memoized
     # sort-key/batch-layout/needs32 path the production assemble uses, or
@@ -563,7 +633,7 @@ def paranoid_check(core, snapshot: DenseSnapshot, batches, rq_map,
     k_incr = assemble_solve_inputs(
         None, copy_batches(batches), rq_map, resource_map, dense=snapshot,
         key_cache=core.tick_cache, gang_ok=gang_ok, group_ids=group_ids,
-        policy=policy,
+        policy=policy, gang_resv=gang_resv,
     )
     scratch_ids = [r.worker_id for r in scratch_rows]
     assert scratch_ids == snapshot.worker_ids, (

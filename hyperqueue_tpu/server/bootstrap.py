@@ -611,6 +611,7 @@ class Server:
         failover_watch: bool = False,
         memory_transport: bool = False,
         policy_file: Path | None = None,
+        gang_drain: str = "idle",
     ):
         # idle_timeout: default worker idle timeout, adopted at registration
         # by workers that set none (reference ServerStartOpts idle_timeout,
@@ -869,6 +870,15 @@ class Server:
             self.core.fused_solve = True
         else:
             base_model = GreedyCutScanModel()
+        # what a waiting gang does to busy members on the fused path
+        # (--gang-drain, docs/scheduler.md "The tick"); the host phase
+        # of the other schedulers always drains them
+        if gang_drain != "idle" and not self.core.fused_solve:
+            raise ValueError(
+                "--gang-drain busy applies to --scheduler tpu, multichip "
+                f"and greedy-fused (got {scheduler!r})"
+            )
+        self.core.set_gang_drain(gang_drain)
         # weighted scheduling objective (--policy-file, scheduler/policy.py):
         # heterogeneity affinity + fairness + runtime prediction on top of
         # the fused dense solve. Gated to greedy-fused — the policy's
@@ -3398,6 +3408,14 @@ class Server:
                 "reserved_for": len(self.core.mn_reservations),
                 "examined_total": self.core.mn_examined_total,
                 "swept_total": self.core.mn_swept_total,
+            },
+            # --gang-drain: the mode, the workers newly reserved for a
+            # waiting gang and the reserved ones that ran a task at a solve
+            "gang_drain": {
+                "mode": self.core.gang_drain,
+                "reserved_total": self.core.tick_cache.gang_reserved,
+                "reserved_busy_total":
+                    self.core.tick_cache.gang_reserved_busy,
             },
             "paranoid_tick": self.core.paranoid_tick,
             "scheduler": self.scheduler_kind,

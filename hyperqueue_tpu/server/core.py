@@ -28,6 +28,8 @@ from hyperqueue_tpu.server.worker import Worker
 from hyperqueue_tpu.utils.flight import FlightRecorder
 from hyperqueue_tpu.utils.trace import TaskTraceStore
 
+GANG_DRAIN_MODES = ("idle", "busy")
+
 
 @dataclass
 class Core:
@@ -75,6 +77,12 @@ class Core:
     # all-or-nothing column groups inside the dense solve (scheduler/tick.py
     # gang rows) instead of the host-side reservation drain
     fused_solve: bool = False
+    # what a waiting gang does to busy members on the fused path
+    # (`--gang-drain`, set through `set_gang_drain`): "idle", the kernel
+    # holds idle members within one solve and nothing drains a busy one;
+    # "busy", a gang that cannot start reserves n members across ticks
+    # (reactor.fused_gang_reserve) and they drain, staying dense rows
+    gang_drain: str = "idle"
     # two-stage async tick pipeline (scheduler/pipeline.TickPipeline) when
     # the server started with --tick-pipeline; None = synchronous ticks
     tick_pipeline: object = None
@@ -124,10 +132,27 @@ class Core:
         self.membership_epoch += 1
         self.tick_cache.membership_changed(worker)
 
+    def set_gang_drain(self, mode: str) -> None:
+        """Choose what a waiting gang does to busy members on the fused
+        path (`hq server start --gang-drain`): "idle" or "busy".  Under
+        "busy" a reserved worker stays a dense row of the tick snapshot and
+        its reservation is a column of it (TickStateCache.tell_reserved)."""
+        if mode not in GANG_DRAIN_MODES:
+            raise ValueError(
+                f"--gang-drain must be one of {GANG_DRAIN_MODES}, not {mode!r}"
+            )
+        if mode != self.gang_drain:
+            self.gang_drain = mode
+            self.tick_cache.keep_reserved = mode == "busy"
+            self.bump_membership()  # every row's eligibility is read anew
+
     def reserve_mn(self, worker: Worker, task_id: int) -> None:
         """Set `worker.mn_reserved` (0 lifts the reservation): the one place
         the field is written, so `mn_reservations` stays its inverse and the
-        flip is told to the tick cache by name."""
+        flip is told to the tick cache by name: as a write of its
+        reservation column, and but under `--gang-drain busy` (where the
+        row stays a dense row) as a membership flip (the row leaves the
+        dense rows)."""
         if worker.mn_reserved == task_id:
             return
         self.forget_mn_reservation(worker)
@@ -136,7 +161,9 @@ class Core:
                 worker.worker_id
             )
         worker.mn_reserved = task_id
-        self.bump_membership(worker)
+        self.tick_cache.tell_reserved(worker)
+        if self.gang_drain != "busy":
+            self.bump_membership(worker)
 
     def forget_mn_reservation(self, worker: Worker) -> None:
         """Take `worker` out of `mn_reservations`, its field left as it is:
@@ -151,9 +178,12 @@ class Core:
     def intern_rqv(self, rqv: ResourceRequestVariants) -> int:
         return self.rq_map.get_or_create(rqv)
 
-    def worker_rows(self) -> list[WorkerRow]:
-        """Snapshot rows for the tick; excludes workers reserved for gangs
-        and workers draining toward a graceful stop."""
+    def worker_rows(self, keep_reserved: bool = False) -> list[WorkerRow]:
+        """Snapshot rows for the tick; excludes workers that run a gang,
+        workers reserved for one and workers draining toward a graceful
+        stop.  `keep_reserved` keeps the reserved ones, as the snapshot
+        does under `--gang-drain busy` (`paranoid_check` compares the
+        two); the path that solves these rows has no mask for them."""
         return [
             WorkerRow(
                 worker_id=w.worker_id,
@@ -164,7 +194,7 @@ class Core:
                 cpu_floor=w.cpu_floor(),
             )
             for w in self.workers.values()
-            if eligible(w)
+            if eligible(w, keep_reserved)
         ]
 
     def variant_amounts(
@@ -256,6 +286,8 @@ class Core:
                 assert worker.tick_idle[worker.tick_row] == (
                     not worker.assigned_tasks and not held
                 ), worker.worker_id
+        # and its reservation column, where the rows hold it
+        assert self.tick_cache.reservations_told(self.workers), "reserved"
         assert reserved == self.mn_reservations, (
             reserved, self.mn_reservations
         )

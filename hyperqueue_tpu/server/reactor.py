@@ -32,8 +32,7 @@ from hyperqueue_tpu.utils.metrics import REGISTRY
 from hyperqueue_tpu.utils.trace import TRACER
 from hyperqueue_tpu.utils import clock
 
-if TYPE_CHECKING:
-    import numpy as np
+import numpy as np
 
 logger = logging.getLogger(__name__)
 
@@ -80,6 +79,16 @@ _SOLVE_GANG_GROUPS = REGISTRY.counter(
     "multi-node gangs co-scheduled atomically by the fused dense solve "
     "(all-or-nothing column groups; --scheduler tpu, multichip and "
     "greedy-fused)",
+)
+_SOLVE_GANG_RESERVED = REGISTRY.counter(
+    "hq_solve_gang_reserved_total",
+    "workers newly reserved for a waiting multi-node gang on the fused "
+    "path (--gang-drain busy: reactor.fused_gang_reserve)",
+)
+_SOLVE_GANG_RESERVED_BUSY = REGISTRY.counter(
+    "hq_solve_gang_reserved_busy_total",
+    "reserved workers that ran a single-node task at the solve, summed "
+    "per tick (--gang-drain busy): the drain still to come",
 )
 _SOLVE_LOOKAHEAD_DEPTH = REGISTRY.gauge(
     "hq_solve_lookahead_depth",
@@ -759,6 +768,25 @@ def _top_sn_priority(core: Core) -> Priority_t | None:
     return best
 
 
+def _top_sn_above(core: Core, above: int, batches) -> int | None:
+    """The highest user priority strictly above `above` of a single-node
+    batch of the tick (`batches`, gang rows passed over) whose class at
+    least one worker can run; None if there is none.  A batch no higher is
+    passed over without a look at any worker."""
+    best: int | None = None
+    for batch in batches:
+        top = batch.priority[0]
+        if batch.gang_nodes or top <= above or (
+                best is not None and top <= best):
+            continue
+        rqv = core.rq_map.get_variants(batch.rq_id)
+        if any(
+            w.resources.is_capable_of_rqv(rqv) for w in core.workers.values()
+        ):
+            best = top
+    return best
+
+
 def _sn_runnable_on(core: Core, above_user_priority: int, workers) -> bool:
     """Is some ready single-node class with user priority strictly above
     `above_user_priority` runnable on one of these (idle) workers right
@@ -792,7 +820,10 @@ def fused_gang_rows(core: Core, phases: dict | None = None) -> list[Batch]:
     sentinel assignments come back and validate (`_apply_fused_gangs`) — a
     stale pipelined solve simply drops its gang and the next tick retries.
     Only the head is read: a done or vanished task met there leaves the
-    queue, one deeper leaves when it surfaces (it is never a row).  Timed
+    queue, one deeper leaves when it surfaces (it is never a row).  What
+    a gang that ended holds is lifted, and under `--gang-drain busy` what
+    any gang that is no row of this tick holds (`core.mn_reservations`
+    names them: a visit per reserving gang).  Timed
     as `gangs/rows` inside `gangs`; the span's `examined` and `swept` say
     how many queue entries and how many workers the tick looked at."""
     rows: list[Batch] = []
@@ -804,11 +835,13 @@ def fused_gang_rows(core: Core, phases: dict | None = None) -> list[Batch]:
             if len(rows) == MAX_FUSED_GANG_ROWS:
                 break
             examined += 1
-            # fused mode never reserves: lift what a host-phase tick left
-            # for this row, so the workers rejoin the dense row set, or for
-            # a task that is gone
-            swept += _clear_mn_reservations(core, task_id)
             task = tasks.get(task_id)
+            if task is None or task.is_done or core.gang_drain != "busy":
+                # under --gang-drain idle the fused path never reserves:
+                # lift what a host-phase tick left for this row, so the
+                # workers rejoin the dense row set; and for a task that
+                # is gone under either
+                swept += _clear_mn_reservations(core, task_id)
             if task is None or task.is_done:
                 continue
             rqv = core.rq_map.get_variants(task.rq_id)
@@ -819,10 +852,16 @@ def fused_gang_rows(core: Core, phases: dict | None = None) -> list[Batch]:
             ))
         if len(rows) < examined:
             core.mn_queue[:examined] = [row.gang_task for row in rows]
-        # a task that ended deeper in the queue holds no worker either
+        # a task that ended deeper in the queue holds no worker either; nor,
+        # under --gang-drain busy, a waiting gang that is no row of this
+        # tick (a gang of a higher priority came ahead of it): it reserves
+        # again once it is back among the rows.  A visit per reserving gang
+        rowed = ({row.gang_task for row in rows}
+                 if core.gang_drain == "busy" else None)
         for task_id in [
             t for t in core.mn_reservations
             if t not in tasks or tasks[t].is_done
+            or (rowed is not None and t not in rowed)
         ]:
             swept += _clear_mn_reservations(core, task_id)
         core.mn_examined_total += examined
@@ -849,6 +888,237 @@ def fused_gang_inputs(
         )
         span.set(walked=int(walked))
     return gang_ok, group_ids
+
+
+def _member_rows(req, snapshot) -> np.ndarray:
+    """(W,) bool: the dense rows that may serve as members of a gang of
+    `req` (`_mn_member_eligible`, read from the snapshot: remaining
+    lifetime and pool totals)."""
+    ok = snapshot.lifetime >= req.min_time_secs
+    for entry in req.entries:
+        rid = entry.resource_id
+        have = (snapshot.total[:, rid] if rid < snapshot.total.shape[1]
+                else np.zeros(len(ok), dtype=np.int64))
+        ok &= have >= entry.amount
+    return ok
+
+
+def _largest_group(group: np.ndarray, cand: np.ndarray) -> int:
+    """The group with the most `cand` rows; on ties the one whose first
+    such row comes first."""
+    g = group[cand]
+    counts = np.bincount(g)
+    tied = np.flatnonzero(counts == counts.max())
+    return int(tied[0]) if len(tied) == 1 else int(g[np.isin(g, tied)][0])
+
+
+def _drain_order(core: Core, rows: np.ndarray, idle: np.ndarray,
+                 worker_ids: list) -> np.ndarray:
+    """`rows` in the order a drain takes them: idle first, then fewest
+    assigned plus prefilled tasks, then lowest worker number.  Only the
+    busy ones are visited, for their task counts."""
+    workers = core.workers
+    keys = []
+    for r in rows.tolist():
+        wid = worker_ids[r]
+        if idle[r]:
+            keys.append((0, 0, wid, r))
+        else:
+            w = workers[wid]
+            keys.append((1, len(w.assigned_tasks) + len(w.prefilled_tasks),
+                         wid, r))
+    keys.sort()
+    return np.asarray([k[3] for k in keys], dtype=np.int64)
+
+
+def fused_gang_hold(core: Core, rows: list[Batch], snapshot, gang_ok,
+                    group_ids, batches) -> set[int]:
+    """The prefill-exempt soft drain of `--gang-drain idle`: for each gang
+    row of the tick that strictly-higher-priority single-node work does
+    not outrank, the n members its drain would take (`_drain_order`) of
+    the group with the most candidates (the first on ties), candidates
+    being the dense rows that may serve it and no earlier row holds.  No
+    membership changes, so the rows stay in the dense solve for the gang
+    row to take; the prefill phase spares the workers returned.  Read
+    from the snapshot's columns (`gang_ok`, `group_ids`) at the dense
+    rows; only a chosen group's busy members are visited.  `batches` as
+    `fused_gang_reserve` takes them."""
+    hold: set[int] = set()
+    if not rows:
+        return hold
+    outranked = _top_sn_above(
+        core, min(gb.priority[0] for gb in rows), batches)
+    idle = np.asarray(gang_ok, dtype=bool)
+    group = np.asarray(group_ids)
+    ids = snapshot.worker_ids
+    held = np.zeros(len(ids), dtype=bool)
+    for gb in rows:
+        if outranked is not None and outranked > gb.priority[0]:
+            continue
+        req = core.rq_map.get_variants(gb.rq_id).variants[0]
+        cand = _member_rows(req, snapshot) & ~held
+        if not cand.any():
+            continue
+        members = np.flatnonzero(cand & (group == _largest_group(group, cand)))
+        if len(members) < gb.gang_nodes:
+            continue
+        chosen = _drain_order(core, members, idle, ids)[:gb.gang_nodes]
+        held[chosen] = True
+        hold.update(ids[r] for r in chosen.tolist())
+    return hold
+
+
+def fused_gang_reserve(core: Core, comm, rows: list[Batch], snapshot,
+                       gang_ok, group_ids, batches,
+                       phases: dict | None = None) -> np.ndarray | None:
+    """The reservation drain of `--gang-drain busy` on the fused path
+    (docs/scheduler.md, "The tick"); None under `--gang-drain idle`.
+    Returns the gang task each dense row of `snapshot` is reserved for
+    (0: none) once this tick's reservations are made, for the solve.
+    `batches` are the tick's rows, the single-node ones as
+    `create_batches` gives them: they say what ready work outranks a gang.
+
+    In the tick's gang-row order, a row's gang
+    - lifts its reservation and reserves nothing while ready single-node
+      work of a strictly higher user priority exists (the host phase's
+      interleave rule);
+    - changes nothing while some group holds n idle rows that may serve
+      it: rows reserved for no gang or for it (it may start in the solve);
+    - keeps its reservation while it holds n reserved rows;
+    - else reserves anew: in the group with the most rows that may serve
+      it (the first in the snapshot's group order on ties; none if that
+      is under n), the n first in drain order (idle first, then fewest
+      assigned plus prefilled tasks, then lowest worker number).  A
+      newly reserved worker's prefilled tasks are retracted, once.
+    Reservations made by an earlier row count for the later ones.  Read
+    from the idleness, group and reservation columns at the dense rows;
+    only a chosen group's busy members are visited.  Timed as
+    `gangs/reserve` inside `gangs`; the span's `reserved` and `busy` are
+    the members newly reserved and the reserved rows that run a task."""
+    if core.gang_drain != "busy":
+        return None
+    with TRACER.phase(phases, "gangs"), \
+            TRACER.phase(phases, "gangs/reserve") as span:
+        cache = core.tick_cache
+        resv = cache.reservations()
+        idle = np.asarray(gang_ok, dtype=bool)
+        group = np.asarray(group_ids, dtype=np.int64)
+        n_groups = int(group.max(initial=-1)) + 1
+        ids = snapshot.worker_ids
+        outranked = _top_sn_above(
+            core, min((gb.priority[0] for gb in rows), default=0), batches)
+        newly = 0
+        held = free_idle = None
+        for gb in rows:
+            if held is None:
+                # what the reservations stand at: per gang its reserved
+                # rows, how many are idle and their group (a reservation is
+                # made in one group), and per group the idle rows reserved
+                # for no gang
+                held = _rows_by_gang(resv, idle, group)
+                free_idle = np.bincount(group[idle & (resv == 0)],
+                                        minlength=n_groups)
+                most_free_idle = int(free_idle.max(initial=0))
+            task_id = gb.gang_task
+            n = gb.gang_nodes
+            mine, mine_idle, mine_group = held.get(task_id, _NO_HOLD)
+            if outranked is not None and outranked > gb.priority[0]:
+                if task_id in core.mn_reservations:
+                    _clear_mn_reservations(core, task_id)
+                    resv[mine] = 0
+                    held = None
+                continue
+            req = core.rq_map.get_variants(gb.rq_id).variants[0]
+            member = (None if req.min_time_secs <= 0 and not req.entries
+                      else _member_rows(req, snapshot))
+            if member is None:
+                # can it start on idle rows: free ones, or its own too
+                if most_free_idle >= n or (mine and (
+                        free_idle[mine_group] + mine_idle >= n)):
+                    continue
+            else:
+                own = np.asarray(mine, dtype=np.int64)
+                own = own[member[own]]
+                if (np.bincount(group[idle & (resv == 0) & member],
+                                minlength=n_groups)
+                        + np.bincount(group[own[idle[own]]],
+                                      minlength=n_groups) >= n).any():
+                    continue
+            if len(mine) == n:
+                continue  # it stands
+            ok = resv == 0
+            ok[mine] = True
+            if member is not None:
+                ok &= member
+            target = _NO_ROWS
+            if ok.any():
+                members = np.flatnonzero(ok & (group == np.argmax(
+                    np.bincount(group[ok]))))
+                if len(members) >= n:
+                    target = _drain_order(core, members, idle, ids)[:n]
+            keep = {ids[r] for r in target.tolist()}
+            for wid in sorted(core.mn_reservations.get(task_id, ())):
+                if wid not in keep:
+                    core.reserve_mn(core.workers[wid], 0)
+            resv[mine] = 0
+            resv[target] = task_id
+            held = None
+            for r in target.tolist():
+                w = core.workers[ids[r]]
+                if w.mn_reserved == task_id:
+                    continue
+                core.reserve_mn(w, task_id)
+                newly += 1
+                if w.prefilled_tasks:
+                    _retract_for_gang(core, comm, w)
+        busy = int(np.count_nonzero(resv[~idle]))
+        cache.gang_reserved += newly
+        cache.gang_reserved_busy += busy
+        if newly:
+            _SOLVE_GANG_RESERVED.inc(newly)
+        if busy:
+            _SOLVE_GANG_RESERVED_BUSY.inc(busy)
+        span.set(reserved=newly, busy=busy)
+    return resv
+
+
+_NO_ROWS = np.zeros(0, dtype=np.int64)
+_NO_HOLD = ([], 0, -1)
+
+
+def _rows_by_gang(resv: np.ndarray, idle: np.ndarray,
+                  group: np.ndarray) -> dict:
+    """{gang task: [its reserved rows ascending, how many are idle, the
+    group of its last]} from a reservation column."""
+    rows = np.flatnonzero(resv)
+    held: dict = {}
+    for row, task, is_idle, grp in zip(
+        rows.tolist(), resv[rows].tolist(), idle[rows].tolist(),
+        group[rows].tolist(),
+    ):
+        entry = held.get(task)
+        if entry is None:
+            entry = held[task] = [[], 0, grp]
+        entry[0].append(row)
+        entry[1] += is_idle
+    return held
+
+
+def _retract_for_gang(core: Core, comm, w: Worker) -> None:
+    """Steal `w`'s prefilled backlog back now that it drains for a gang,
+    so the drain is bounded by its running tasks (sent once a
+    reservation; marked pending, or on_retract_response drops the
+    answers)."""
+    refs = []
+    for tid in sorted(w.prefilled_tasks):
+        victim = core.tasks[tid]
+        if victim.retract_pending:
+            continue  # an earlier retract covers it
+        victim.retract_pending = True
+        refs.append((tid, victim.instance_id))
+    if refs:
+        _RETRACTED_TOTAL.labels("gang-drain").inc(len(refs))
+        comm.send_retract(w.worker_id, refs)
 
 
 def _apply_fused_gangs(
@@ -888,6 +1158,8 @@ def _apply_fused_gangs(
             for w in members:
                 w.mn_task = task_id
                 core.bump_membership(w)
+            # the gang starts: what it reserved (--gang-drain busy) is free
+            _clear_mn_reservations(core, task_id)
             task.mn_workers = tuple(w.worker_id for w in members)
             task.state = TaskState.ASSIGNED
             task.t_assigned = now
@@ -906,7 +1178,7 @@ def _apply_fused_gangs(
 
 
 def _prefill_fill(core: Core, now: float, per_worker_msgs: dict,
-                  leftover_batches, policy_ctx, fused_gang_hold: set):
+                  leftover_batches, policy_ctx, hold_for_gangs: set):
     """Prefill pass 1, proactive filling: push extra top-priority tasks to
     busy workers so short tasks pipeline without a server round-trip per
     task (reference mapping.rs:159 process_proactive_filling, max
@@ -921,7 +1193,7 @@ def _prefill_fill(core: Core, now: float, per_worker_msgs: dict,
         if not w.mn_task
         and not w.mn_reserved
         and not w.draining
-        and w.worker_id not in fused_gang_hold
+        and w.worker_id not in hold_for_gangs
         and (w.assigned_tasks or w.prefilled_tasks)
         and len(w.prefilled_tasks) < PREFILL_MAX
     }
@@ -1394,22 +1666,7 @@ def _tick(core: Core, comm: Comm, model, prefill: bool, phases: dict):
                         newly_reserved = w.mn_reserved != task_id
                         core.reserve_mn(w, task_id)
                         if newly_reserved and w.prefilled_tasks:
-                            # steal the queued backlog back so the drain is
-                            # bounded by the currently-running tasks only (sent
-                            # once per reservation, not per tick); mark pending
-                            # or on_retract_response drops the answers
-                            refs = []
-                            for tid in sorted(w.prefilled_tasks):
-                                victim = core.tasks[tid]
-                                if victim.retract_pending:
-                                    continue  # an earlier retract covers it
-                                victim.retract_pending = True
-                                refs.append((tid, victim.instance_id))
-                            if refs:
-                                _RETRACTED_TOTAL.labels("gang-drain").inc(
-                                    len(refs)
-                                )
-                                comm.send_retract(w.worker_id, refs)
+                            _retract_for_gang(core, comm, w)
                     continue
                 _clear_mn_reservations(core, task_id)
                 for w in chosen:
@@ -1436,40 +1693,9 @@ def _tick(core: Core, comm: Comm, model, prefill: bool, phases: dict):
     if fused_tick and core.mn_queue:
         fused_gang_batches = fused_gang_rows(core, phases)
 
-    # Soft drain for fused gangs: the kernel holds members WITHIN one
-    # solve, but between ticks the prefill phase would keep piling backlog
-    # onto the busy members a waiting gang needs (the host phase used the
-    # mn_reserved drain for this).  Mark each pending gang's best-group
-    # candidate set prefill-exempt instead — no membership change, so the
-    # rows stay in the dense solve for the gang row to take.  Mirrors the
-    # host interleave: a gang outranked by strictly-higher-priority ready
-    # single-node work holds nothing yet.
-    fused_gang_hold: set[int] = set()
-    if fused_gang_batches:
-        top_sn = _top_sn_priority(core)
-        for gb in fused_gang_batches:
-            if top_sn is not None and top_sn[0] > gb.priority[0]:
-                continue
-            req = core.rq_map.get_variants(gb.rq_id).variants[0]
-            groups: dict[str, list[Worker]] = {}
-            for w in core.workers.values():
-                if (
-                    w.mn_task
-                    or w.draining
-                    or w.worker_id in fused_gang_hold
-                    or not _mn_member_eligible(w, req)
-                ):
-                    continue
-                groups.setdefault(w.group, []).append(w)
-            best = max(groups.values(), key=len, default=None)
-            if best is None or len(best) < gb.gang_nodes:
-                continue
-            best.sort(key=lambda w: (
-                not w.is_idle(),
-                len(w.assigned_tasks) + len(w.prefilled_tasks),
-                w.worker_id,
-            ))
-            fused_gang_hold.update(w.worker_id for w in best[:gb.gang_nodes])
+    # the workers the prefill phase spares for waiting gangs under
+    # --gang-drain idle (fused_gang_hold, once the snapshot is synced)
+    hold_for_gangs: set[int] = set()
 
     # --- single-node: dense solve ---
     # Batches are built ONCE per schedule(): run_tick consumes this list,
@@ -1549,11 +1775,22 @@ def _tick(core: Core, comm: Comm, model, prefill: bool, phases: dict):
                 batches = create_batches(core.queues)
                 if run_gangs_fused:
                     batches = batches + fused_gang_batches
-            gang_ok = group_ids = None
+            gang_ok = group_ids = gang_resv = None
             if run_gangs_fused:
                 gang_ok, group_ids = fused_gang_inputs(
                     core, snapshot.worker_ids, phases
                 )
+                # a waiting gang's busy members: reserved across ticks
+                # under --gang-drain busy, else spared by prefill alone
+                gang_resv = fused_gang_reserve(
+                    core, comm, fused_gang_batches, snapshot, gang_ok,
+                    group_ids, batches, phases,
+                )
+                if gang_resv is None:
+                    hold_for_gangs = fused_gang_hold(
+                        core, fused_gang_batches, snapshot, gang_ok,
+                        group_ids, batches,
+                    )
             if core.policy is not None:
                 # weighted objective (--policy-file): resolve this tick's
                 # affinity rows + priority boosts against the tick's worker
@@ -1575,6 +1812,7 @@ def _tick(core: Core, comm: Comm, model, prefill: bool, phases: dict):
                 paranoid_check(
                     core, snapshot, batches, core.rq_map, core.resource_map,
                     gang_ok=gang_ok, group_ids=group_ids, policy=policy_ctx,
+                    gang_resv=gang_resv,
                 )
             pipeline_this_tick = (
                 pipeline
@@ -1607,6 +1845,7 @@ def _tick(core: Core, comm: Comm, model, prefill: bool, phases: dict):
                     decision=decision_info if record_decision else None,
                     pipeline=pipeline_this_tick,
                     gang_ok=gang_ok, group_ids=group_ids, policy=policy_ctx,
+                    gang_resv=gang_resv,
                 )
                 if (
                     pipeline_this_tick is not None
@@ -1712,7 +1951,7 @@ def _tick(core: Core, comm: Comm, model, prefill: bool, phases: dict):
             with TRACER.phase(phases, "prefill/fill"):
                 prefilled, leftover_batches = _prefill_fill(
                     core, now, per_worker_msgs, leftover_batches,
-                    policy_ctx, fused_gang_hold,
+                    policy_ctx, hold_for_gangs,
                 )
             with TRACER.phase(phases, "prefill/displace"):
                 leftover_batches = _prefill_displace(

@@ -67,6 +67,8 @@ def _kernel_shapes(w, put, extras):
         kwargs["gang_nodes"] = s((B,), "rep")
         kwargs["gang_ok"] = s((w,), "w1")
         kwargs["group_onehot"] = s((w, G), "w2")
+    if "resv" in extras:
+        kwargs["gang_resv"] = s((w,), "w1")
     if "pmask" in extras:
         kwargs["policy_mask"] = s((B, w), "cm")
     return args, kwargs
@@ -84,8 +86,8 @@ def _assert_no_reduce_window(compiled):
 
 
 @pytest.mark.parametrize(
-    "extras", [(), ("all",), ("gang",), ("pmask",)],
-    ids=["flat", "all-mask", "gang", "policy-mask"],
+    "extras", [(), ("all",), ("gang",), ("pmask",), ("gang", "resv")],
+    ids=["flat", "all-mask", "gang", "policy-mask", "gang-reserved"],
 )
 def test_single_chip_kernel_compiles_for_v5e(topo, no_cache, extras):
     import jax
@@ -238,7 +240,8 @@ def test_answer_packer_on_four_v5e_adds_no_collective(topo, no_cache):
 
 
 @pytest.mark.parametrize(
-    "extras", [(), ("gang",), ("all",)], ids=["flat", "gang", "all-mask"]
+    "extras", [(), ("gang",), ("all",), ("gang", "resv")],
+    ids=["flat", "gang", "all-mask", "gang-reserved"],
 )
 def test_sharded_kernel_compiles_for_four_v5e(topo, no_cache, extras):
     import re

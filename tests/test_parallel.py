@@ -591,3 +591,73 @@ def test_changed_batch_order_costs_the_sharded_tick_one_put():
     assert after["upload_bytes_total"] - again["upload_bytes_total"] \
         == state_bytes + 4 * table_bytes + class_bytes
     assert after["puts_total"] - again["puts_total"] == 1
+
+
+def _reserved_mesh_case(rng, n_w=32):
+    """32 workers in four groups of 8 shifted by 4, so that every group
+    straddles a shard boundary on a 4-device mesh (8 rows a shard); the
+    first gang row holds five workers of group 1 (rows 4-11: shards 0 and
+    1), the second two of group 2, and a few rows are reserved for a gang
+    no row carries."""
+    from hyperqueue_tpu.ops.assign import RESV_ELSEWHERE
+
+    n_r, n_b, n_v = 2, 6, 1
+    free = (rng.integers(2, 8, size=(n_w, n_r)) * U).astype(np.int32)
+    nt_free = rng.integers(1, 6, size=n_w).astype(np.int32)
+    lifetime = np.full(n_w, INF_TIME, dtype=np.int32)
+    needs = np.zeros((n_b, n_v, n_r), dtype=np.int32)
+    needs[:, 0, 0] = U
+    sizes = np.asarray([1, 1, 9, 9, 9, 9], dtype=np.int32)
+    min_time = np.zeros((n_b, n_v), dtype=np.int32)
+    gang_nodes = np.asarray([5, 3, 0, 0, 0, 0], dtype=np.int32)
+    groups = ((np.arange(n_w) + 4) // 8) % 4
+    group_onehot = np.eye(4, dtype=np.int32)[groups]
+    resv = np.zeros(n_w, dtype=np.int32)
+    resv[[5, 6, 8, 9, 11]] = 1     # group 1, over shards 0 and 1
+    resv[[13, 14]] = 2             # group 2
+    resv[[0, 30]] = RESV_ELSEWHERE
+    gang_ok = (rng.random(n_w) < 0.6).astype(np.int32)
+    gang_ok[[5, 6, 8, 9, 11]] = 1  # the first gang's drain is done
+    return (free, nt_free, lifetime, needs, sizes, min_time, gang_nodes,
+            gang_ok, group_onehot, resv)
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_sharded_reservations_equal_one_chip_across_a_shard_boundary(seed):
+    """Under `--gang-drain busy` the sharded scan on 4 virtual devices gives
+    the one-chip kernel's counts bit for bit when a reserved group
+    straddles a shard boundary: the first gang row takes its own five
+    workers, on two shards; with the codes all none both paths equal the
+    path without them."""
+    rng = np.random.default_rng(seed + 40)
+    (free, nt_free, lifetime, needs, sizes, min_time, gang_nodes, gang_ok,
+     group_onehot, resv) = _reserved_mesh_case(rng)
+    scarcity = np.asarray(
+        scarcity_weights(free.astype(np.int64).sum(axis=0))
+    ).astype(np.float32)
+    class_m, order_ids = host_visit_classes(free, needs, scarcity)
+    mesh = make_worker_mesh(4)
+
+    def both(codes):
+        gang = dict(gang_nodes=gang_nodes, gang_ok=gang_ok,
+                    group_onehot=group_onehot)
+        if codes is not None:
+            gang["gang_resv"] = codes
+        single, _f, _n = greedy_cut_scan(
+            free, nt_free, lifetime, needs, sizes, min_time, class_m,
+            order_ids, **gang)
+        sharded, _f, _n = sharded_cut_scan_donate(
+            mesh, free.copy(), nt_free.copy(), lifetime,
+            pack_batch_table(needs, sizes, min_time, order_ids), class_m,
+            extents=needs.shape, **gang)
+        return np.asarray(single), np.asarray(sharded)
+
+    single, sharded = both(resv)
+    assert np.array_equal(single, sharded)
+    assert np.flatnonzero(single[0, 0]).tolist() == [5, 6, 8, 9, 11]
+    assert single[2:][:, :, resv != 0].sum() == 0
+    none_single, none_sharded = both(np.zeros_like(resv))
+    plain_single, plain_sharded = both(None)
+    assert np.array_equal(none_single, plain_single)
+    assert np.array_equal(none_sharded, plain_sharded)
+    assert np.array_equal(plain_single, plain_sharded)

@@ -333,6 +333,79 @@ def test_gang_rows_numpy_matches_jax_and_hold_invariants(seed):
             assert len({int(gids[w]) for w in members}) == 1
 
 
+def _random_reserved_case(rng):
+    """Gang rows over workers in groups, and reservations: each gang row
+    holds some workers of one group (row b's code is b + 1), a few workers
+    are reserved for a gang no row carries."""
+    from hyperqueue_tpu.ops.assign import RESV_ELSEWHERE
+
+    n_w = int(rng.integers(6, 16))
+    n_r, n_b, n_v = 2, int(rng.integers(3, 8)), 2
+    n_g = int(rng.integers(1, 4))
+    free = rng.integers(0, 8, size=(n_w, n_r)) * U
+    nt_free = rng.integers(1, 10, size=n_w)
+    lifetime = np.full(n_w, INF)
+    needs = rng.integers(0, 3, size=(n_b, n_v, n_r)) * (U // 2)
+    needs[:, 0, 0] = np.maximum(needs[:, 0, 0], U)
+    sizes = rng.integers(0, 12, size=n_b)
+    min_time = np.zeros((n_b, n_v), dtype=np.int64)
+    gang_nodes = np.zeros(n_b, dtype=np.int64)
+    gids = rng.integers(0, n_g, size=n_w)
+    resv = np.zeros(n_w, dtype=np.int64)
+    for b in rng.choice(n_b, size=min(3, n_b), replace=False):
+        gang_nodes[b] = int(rng.integers(2, 4))
+        sizes[b] = 1
+        members = np.flatnonzero((gids == rng.integers(0, n_g)) & (resv == 0))
+        take = members[: int(rng.integers(0, gang_nodes[b] + 1))]
+        resv[take] = b + 1
+    resv[(resv == 0) & (rng.random(n_w) < 0.1)] = RESV_ELSEWHERE
+    gang_ok = (rng.random(n_w) < 0.7).astype(np.int64)
+    group_onehot = (
+        gids[:, None] == np.arange(n_g, dtype=np.int64)[None, :]
+    ).astype(np.int32)
+    args = dict(
+        free=free.astype(np.int32), nt_free=nt_free.astype(np.int32),
+        lifetime=lifetime.astype(np.int32), needs=needs.astype(np.int32),
+        sizes=sizes.astype(np.int32), min_time=min_time.astype(np.int32),
+        gang_nodes=gang_nodes.astype(np.int32),
+        gang_ok=gang_ok.astype(np.int32), group_onehot=group_onehot,
+    )
+    return args, resv.astype(np.int32), gids
+
+
+@pytest.mark.parametrize("seed", range(8))
+def test_gang_rows_with_reservations_numpy_matches_jax(seed):
+    """Reservations (`--gang-drain busy`): the numpy and jitted kernels
+    agree bitwise; a reserved worker takes nothing from any single-node
+    row; a gang row takes no worker reserved for another, and one whose
+    own idle reserved workers number n takes n of them; with the codes all
+    none the counts are bit for bit those of the path without them."""
+    from hyperqueue_tpu.models.greedy import GreedyCutScanModel
+
+    rng = np.random.default_rng(seed + 700)
+    args, resv, gids = _random_reserved_case(rng)
+    jax_counts = np.asarray(
+        GreedyCutScanModel(backend="jax").solve(**args, gang_resv=resv))
+    np_counts = np.asarray(
+        GreedyCutScanModel(backend="numpy").solve(**args, gang_resv=resv))
+    assert (jax_counts == np_counts).all()
+    gang_nodes, gang_ok = args["gang_nodes"], args["gang_ok"]
+    single = gang_nodes == 0
+    assert np_counts[single][:, :, resv != 0].sum() == 0
+    for b in np.flatnonzero(gang_nodes):
+        members = np.flatnonzero(np_counts[b, 0])
+        assert set(resv[members].tolist()) <= {0, b + 1}
+        own = np.flatnonzero((resv == b + 1) & (gang_ok == 1))
+        if len(own) >= gang_nodes[b] and not np.isin(
+                own, np.flatnonzero(np_counts[:b].sum(axis=(0, 1)))).any():
+            assert members.tolist() == own[: gang_nodes[b]].tolist()
+    none = np.zeros_like(resv)
+    for backend in ("numpy", "jax"):
+        model = GreedyCutScanModel(backend=backend)
+        assert (np.asarray(model.solve(**args, gang_resv=none))
+                == np.asarray(model.solve(**args))).all()
+
+
 # -- weighted objective (policy affinity rows; scheduler/policy.py) --------
 
 def _random_weighted_case(rng):

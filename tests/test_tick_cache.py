@@ -252,6 +252,142 @@ def test_randomized_incremental_vs_scratch_golden():
     assert all(seen.values()), seen
 
 
+def _assert_reservation_column_is_walk(core):
+    """The reservation column at the dense rows against the workers'
+    `mn_reserved`, and under `--gang-drain busy` a reserved worker is a
+    dense row."""
+    cache = core.tick_cache
+    snap = cache.sync(core)
+    assert cache.reservations_told(core.workers)
+    if snap is None:
+        return
+    assert cache.reservations().tolist() == [
+        core.workers[w].mn_reserved for w in snap.worker_ids]
+    if core.gang_drain == "busy":
+        rows = set(snap.worker_ids)
+        assert all(
+            w.worker_id in rows for w in core.workers.values()
+            if w.mn_reserved and not w.mn_task and not w.draining)
+
+
+def test_randomized_reservation_column_is_the_walk():
+    """Random histories under `--gang-drain busy` (submits, schedules with
+    and without prefill, finishes, gangs that reserve, start, end and are
+    cancelled, workers that join, leave and drain, a switch to `idle` and
+    back): after every step the told reservation column equals a walk over
+    the workers, and `paranoid_tick=1` holds it to the walk inside every
+    schedule() besides."""
+    for seed in (3, 11):
+        env = TestEnv()
+        env.core.paranoid_tick = 1
+        env.core.fused_solve = True
+        env.core.set_gang_drain("busy")
+        rng = random.Random(seed)
+        running: list[int] = []
+        worker_ids = [env.worker(cpus=rng.choice([2, 4]),
+                                 group=rng.choice("ab")).worker_id
+                      for _ in range(6)]
+        reserved_seen = 0
+        for step in range(160):
+            op = rng.random()
+            if op < 0.3:
+                env.submit(n=rng.randrange(1, 6), priority=(1, 0))
+            elif op < 0.42:
+                env.submit(rqv=env.rqv(n_nodes=rng.choice([2, 3])),
+                           priority=(1, 0))
+            elif op < 0.6 and running:
+                env.finish(running.pop(rng.randrange(len(running))))
+            elif op < 0.66:
+                worker_ids.append(env.worker(
+                    cpus=rng.choice([2, 4]), group=rng.choice("ab")).worker_id)
+            elif op < 0.7 and len(worker_ids) > 3:
+                wid = worker_ids.pop(rng.randrange(len(worker_ids)))
+                worker = env.core.workers[wid]
+                gone = set(worker.assigned_tasks) | {worker.mn_task}
+                env.lose_worker(wid)
+                running = [t for t in running if t not in gone
+                           and env.core.tasks[t].state.value == "running"]
+            elif op < 0.74 and env.core.mn_queue:
+                env.cancel([rng.choice(env.core.mn_queue)])
+            elif op < 0.77 and len(worker_ids) > 4:
+                env.start_drain([rng.choice(worker_ids)])
+            elif op < 0.79:
+                env.core.set_gang_drain(
+                    "idle" if env.core.gang_drain == "busy" else "busy")
+            _assert_reservation_column_is_walk(env.core)
+            if env.core.queues.total_ready() or env.core.mn_queue:
+                env.schedule(prefill=step % 2 == 0)
+                env.start_all_assigned()
+                running = [t for t, task in env.core.tasks.items()
+                           if task.state.value == "running"]
+                reserved_seen += any(
+                    w.mn_reserved for w in env.core.workers.values())
+                _assert_reservation_column_is_walk(env.core)
+        assert reserved_seen > 3
+        assert env.core.tick_cache.gang_reserved > 0
+
+
+def _parents_fused_gang_hold(core, rows) -> set:
+    """The soft drain of `--gang-drain idle` as `reactor._tick` walked it
+    before it read the snapshot's columns."""
+    hold: set = set()
+    top_sn = reactor._top_sn_priority(core)
+    for gb in rows:
+        if top_sn is not None and top_sn[0] > gb.priority[0]:
+            continue
+        req = core.rq_map.get_variants(gb.rq_id).variants[0]
+        groups: dict = {}
+        for w in core.workers.values():
+            if (w.mn_task or w.draining or w.worker_id in hold
+                    or not reactor._mn_member_eligible(w, req)):
+                continue
+            groups.setdefault(w.group, []).append(w)
+        best = max(groups.values(), key=len, default=None)
+        if best is None or len(best) < gb.gang_nodes:
+            continue
+        best.sort(key=lambda w: (
+            not w.is_idle(),
+            len(w.assigned_tasks) + len(w.prefilled_tasks),
+            w.worker_id,
+        ))
+        hold.update(w.worker_id for w in best[:gb.gang_nodes])
+    return hold
+
+
+@pytest.mark.parametrize("seed", [1, 4, 9, 2147483659])
+def test_fused_gang_hold_reads_the_columns_and_equals_the_walk(seed):
+    """Under `--gang-drain idle` the prefill-exempt hold, read from the
+    snapshot's columns, is the set the walk over every worker gave, over
+    random states: groups of several sizes, idle and busy workers with
+    assigned and prefilled tasks, gangs of several sizes and priorities,
+    some outranked, some finding no group."""
+    rng = random.Random(seed)
+    env = TestEnv()
+    env.core.fused_solve = True
+    for _ in range(rng.randrange(6, 20)):
+        env.worker(cpus=rng.choice([1, 2, 4]), group=rng.choice("abc"))
+    for _round in range(6):
+        env.submit(n=rng.randrange(0, 12), priority=(rng.choice([0, 1]), 0))
+        for _ in range(rng.randrange(1, 5)):
+            env.submit(rqv=env.rqv(n_nodes=rng.choice([1, 2, 3, 5])),
+                       priority=(rng.choice([0, 1, 2]), 0))
+        core = env.core
+        rows = reactor.fused_gang_rows(core)
+        snap = core.tick_cache.sync(core)
+        if rows and snap is not None:
+            gang_ok, group_ids = reactor.fused_gang_inputs(
+                core, snap.worker_ids)
+            assert reactor.fused_gang_hold(
+                core, rows, snap, gang_ok, group_ids,
+                create_batches(core.queues) + rows,
+            ) == _parents_fused_gang_hold(core, rows)
+        env.schedule(prefill=True)
+        env.start_all_assigned()
+        for task in list(env.core.tasks.values()):
+            if task.state.value == "running" and rng.random() < 0.3:
+                env.finish(task.task_id)
+
+
 # ---------------------------------------------------------- dirty tracking
 def test_steady_state_zero_full_rebuilds():
     env = TestEnv()

@@ -318,13 +318,14 @@ def test_readback_counters_grow_by_what_each_device_solve_reads_back():
     )
 
 
-# the tick cell, since PR 27 the sharded cell, since PR 31 the `flat-1k`
-# tick cell and the gang cell, and since PR 33 the sharded gang cell, whose
-# drivers pass the same `tick_phases_ms`
+# the cells whose drivers pass the same `tick_phases_ms`: the tick cell, the
+# sharded cell, the `flat-1k` tick cell, the gang cell, the sharded gang cell
+# and the shared cell
 TICK_CELLS = ["hetero-1k.backlog-1m", "shard-16k.backlog",
-              "flat-1k.backlog-1m", "gang-1k.rigid", "gang-16k.campaign"]
+              "flat-1k.backlog-1m", "gang-1k.rigid", "gang-16k.campaign",
+              "shared-1k.reserve"]
 # the cells that submit between ticks (`reactor.on_new_tasks`)
-GANG_CELLS = ["gang-1k.rigid", "gang-16k.campaign"]
+GANG_CELLS = ["gang-1k.rigid", "gang-16k.campaign", "shared-1k.reserve"]
 # metric -> (the key it reads, its cells, the end-to-end metric it moves)
 PHASE_READERS = {
     "upload_ms": ("solve_dispatch/upload", TICK_CELLS, "tick_ms_p50"),
