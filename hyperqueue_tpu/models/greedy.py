@@ -44,11 +44,13 @@ from hyperqueue_tpu.ops.assign import (
     greedy_cut_scan,
     greedy_cut_scan_numpy,
     host_visit_classes,
+    scan_step_kinds,
     scarcity_weights,
 )
 from hyperqueue_tpu.utils.constants import INF_TIME
 from hyperqueue_tpu.utils import clock
 from hyperqueue_tpu.utils.jaxdev import device_block
+from hyperqueue_tpu.utils.metrics import REGISTRY
 from hyperqueue_tpu.utils.trace import TRACER
 
 
@@ -172,6 +174,18 @@ class ResidentParanoidError(AssertionError):
     degrading (like tick_cache.paranoid_check, the paranoid contract is a
     debug tool — masking the divergence behind the fallback would both
     hide the bug and destroy the evidence via resident invalidation)."""
+
+
+# the steps of every device solve that carries gang rows, by what each did
+# (ops/assign.scan_step_kinds): only the gang rows run the selection, only
+# the other live rows the water-fill, and the rows past the last live one
+# nothing
+_SCAN_STEPS_BY_KIND = REGISTRY.counter(
+    "hq_solve_scan_steps_by_kind_total",
+    "scan steps of the device solves with gang rows by kind (gang: the "
+    "selection; fill: the water-fill; idle: a padded row never visited)",
+    labels=("kind",), max_series=4,
+)
 
 
 class _ReadyCounts:
@@ -321,6 +335,9 @@ class GreedyCutScanModel:
         self.paranoid_resident = 0
         self._resident_solves = 0
         self.paranoid_checks = 0
+        # steps of the device solves with gang rows, by what each did
+        # (ops/assign.scan_step_kinds)
+        self.scan_steps = {"gang": 0, "fill": 0, "idle": 0}
 
     # -- backend selection -------------------------------------------------
     def _sticky_host(self) -> bool | None:
@@ -744,6 +761,8 @@ class GreedyCutScanModel:
         if self._res is not None:
             base.update(self._res.stats())
         base["paranoid_checks"] = self.paranoid_checks
+        for kind, steps in self.scan_steps.items():
+            base[f"scan_steps_{kind}"] = steps
         return base
 
     def _device_solve(self, prep) -> _DeviceCounts:
@@ -774,6 +793,11 @@ class GreedyCutScanModel:
         self.last_backend = self._device_backend_name
         self.last_device = device_block(packed)
         self._resident_solves += 1
+        if prep["gang_p"] is not None:
+            for kind, steps in scan_step_kinds(
+                    prep["gang_p"], prep["sizes_p"]).items():
+                self.scan_steps[kind] += steps
+                _SCAN_STEPS_BY_KIND.labels(kind).inc(steps)
         return _DeviceCounts(self, res, packed, counts, layout, prep)
 
     @staticmethod

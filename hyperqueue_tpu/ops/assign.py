@@ -6,8 +6,9 @@ integer program with one variable per (worker, rq-batch, variant) and solves it
 with HiGHS on the CPU; here the same decision — "how many tasks of each request
 class go to each worker this tick" — is computed by a single jit-compiled
 program: a `lax.scan` over priority-ordered batches whose body does only dense
-(W,) / (W,R) integer vector ops, so the whole tick runs on-device with no
-host round-trips and fixed (bucketed) shapes.
+(W,) / (W,R) integer vector ops (with gang rows, loops that run each row's
+own kind of work), so the whole tick runs on-device with no host
+round-trips and fixed (bucketed) shapes.
 
 Semantics preserved from the reference solver:
   * Strict priority dominance with gap relaxation (solver.rs:240-410): batches
@@ -388,7 +389,11 @@ def scan_batches(
     in variant 0; feasible or not, the selected workers are HELD (free/nt
     zeroed) for the rest of the scan — the in-solve equivalent of the host
     `mn_reserved` reservation drain, so lower-priority work cannot steal
-    members while a gang accumulates.
+    members while a gang accumulates.  A solve with gang rows runs each
+    row's own work alone (`scan_step_kinds` counts it): the selection on
+    a gang row and no water-fill, the water-fill on any other row and no
+    selection, and nothing past the last live row; a solve without them
+    is the one `lax.scan` of water-fills over every row.
 
     policy_mask (B, W) int32 0/1, optional: zero marks workers a batch's
     policy weight row excludes (affinity 0 = hard incompatibility per the
@@ -424,44 +429,10 @@ def scan_batches(
             with jax.named_scope(GANG_SELECT_SCOPE):
                 return _gang_select_local(elig, group_onehot, n, mine=mine)
 
-    def batch_body(carry, batch):
-        if has_gang:
-            free, nt_free, gang_avail = carry
-        else:
-            free, nt_free = carry
-            gang_avail = None
-        batch = list(batch)
-        b_needs, b_size, b_min_time, b_onehot = batch[:4]
-        rest = batch[4:]
-        b_all = rest.pop(0) if has_all else None
-        b_gang = rest.pop(0) if has_gang else None
-        b_pmask = rest.pop(0) if has_pmask else None
-        b_code = rest.pop(0) if has_resv else None
-        remaining = b_size
+    def fill(free, nt_free, gang_avail, remaining, b_needs, b_min_time,
+             b_onehot, b_all, b_pmask):
+        """A single-node row: water-fill its size over the variants."""
         counts_v = []
-        emit = None
-        if has_gang:
-            is_gang = (b_gang > 0).astype(jnp.int32)
-            time_ok0 = (b_min_time[0] <= lifetime).astype(jnp.int32)
-            elig = (
-                gang_avail * time_ok0
-                * (nt_free >= 1).astype(jnp.int32)
-            )
-            if has_pmask:
-                elig = elig * b_pmask
-            mine = None
-            if has_resv:
-                mine = (gang_resv == b_code).astype(jnp.int32)
-                elig = elig * jnp.maximum(unreserved, mine)
-            take, any_feas = gang_select(elig, group_onehot, b_gang, mine)
-            take = take * is_gang
-            emit = take * any_feas.astype(jnp.int32)
-            free = free * (1 - take)[:, None]
-            nt_free = nt_free * (1 - take)
-            gang_avail = gang_avail * (1 - take)
-            # a gang row is ONLY its all-or-nothing emit: the ordinary
-            # water-fill below must not also spend its size on stragglers
-            remaining = remaining * (1 - is_gang)
         for v in range(n_variants):  # V is tiny and static: unrolled
             need = b_needs[v]
             time_ok = b_min_time[v] <= lifetime
@@ -485,29 +456,122 @@ def scan_batches(
             if has_gang:
                 gang_avail = gang_avail * (assign == 0).astype(jnp.int32)
             counts_v.append(assign)
-        if has_gang:
-            counts_v[0] = counts_v[0] + emit
-            return (free, nt_free, gang_avail), jnp.stack(counts_v)
-        return (free, nt_free), jnp.stack(counts_v)
+        return free, nt_free, gang_avail, jnp.stack(counts_v)
 
-    xs = (needs, sizes, min_time, onehots)
-    if has_all:
-        xs = xs + (all_mask,)
-    if has_gang:
-        xs = xs + (gang_nodes,)
-    if has_pmask:
-        xs = xs + (policy_mask,)
-    if has_resv:
-        # the code of row b is b + 1 (gang_resv above)
-        xs = xs + (jnp.arange(1, needs.shape[0] + 1, dtype=jnp.int32),)
-    if has_gang:
-        carry0 = (free, nt_free, gang_ok.astype(jnp.int32))
-        (free, nt_free, _), counts = jax.lax.scan(batch_body, carry0, xs)
-    else:
+    if not has_gang:
+        def batch_body(carry, batch):
+            batch = list(batch)
+            b_needs, b_size, b_min_time, b_onehot = batch[:4]
+            rest = batch[4:]
+            b_all = rest.pop(0) if has_all else None
+            b_pmask = rest.pop(0) if has_pmask else None
+            free, nt_free, _, counts = fill(
+                *carry, None, b_size, b_needs, b_min_time, b_onehot, b_all,
+                b_pmask,
+            )
+            return (free, nt_free), counts
+
+        xs = (needs, sizes, min_time, onehots)
+        if has_all:
+            xs = xs + (all_mask,)
+        if has_pmask:
+            xs = xs + (policy_mask,)
         (free, nt_free), counts = jax.lax.scan(
             batch_body, (free, nt_free), xs
         )
+        return counts, free, nt_free
+
+    # With gang rows each row does its own kind's work alone: a gang row's
+    # water-fill would spend a size of 0 and a single-node row's selection
+    # would be masked off, so skipping either leaves every result bit for
+    # bit as it is, and the rows past the last live one do nothing.  No
+    # conditional decides it (one costs 3.1 us a step on a v5e, PERF.md
+    # section 6): a loop over the gang rows in order runs the single-node
+    # rows before each in a loop of their own, then the gang row; a last
+    # loop runs the single-node rows after the last gang row.
+    def row(x, i):
+        return None if x is None else jax.lax.dynamic_index_in_dim(
+            x, i, keepdims=False)
+
+    def put_row(counts, i, counts_i):
+        return jax.lax.dynamic_update_index_in_dim(counts, counts_i, i, 0)
+
+    def fill_row(state):
+        i, (free, nt_free, gang_avail), counts = state
+        free, nt_free, gang_avail, counts_i = fill(
+            free, nt_free, gang_avail, row(sizes, i), row(needs, i),
+            row(min_time, i), row(onehots, i), row(all_mask, i),
+            row(policy_mask, i),
+        )
+        return i + 1, (free, nt_free, gang_avail), put_row(counts, i, counts_i)
+
+    def fill_rows(i, stop, carry, counts):
+        return jax.lax.while_loop(
+            lambda state: state[0] < stop, fill_row, (i, carry, counts))
+
+    def gang_row(i, carry, counts):
+        """A gang row: select its members, emit them if the gang is
+        feasible, and HOLD them either way."""
+        free, nt_free, gang_avail = carry
+        time_ok0 = (row(min_time, i)[0] <= lifetime).astype(jnp.int32)
+        elig = (
+            gang_avail * time_ok0
+            * (nt_free >= 1).astype(jnp.int32)
+        )
+        if has_pmask:
+            elig = elig * row(policy_mask, i)
+        mine = None
+        if has_resv:
+            # the code of row b is b + 1 (gang_resv above)
+            mine = (gang_resv == i + 1).astype(jnp.int32)
+            elig = elig * jnp.maximum(unreserved, mine)
+        take, any_feas = gang_select(
+            elig, group_onehot, row(gang_nodes, i), mine)
+        emit = take * any_feas.astype(jnp.int32)
+        free = free * (1 - take)[:, None]
+        nt_free = nt_free * (1 - take)
+        gang_avail = gang_avail * (1 - take)
+        counts_i = jnp.zeros((n_variants,) + emit.shape, jnp.int32)
+        return (free, nt_free, gang_avail), put_row(
+            counts, i, counts_i.at[0].set(emit))
+
+    n_b = needs.shape[0]
+    rows = jnp.arange(n_b, dtype=jnp.int32)
+    is_gang = gang_nodes > 0
+    n_live = jnp.max(jnp.where(is_gang | (sizes > 0), rows + 1, 0))
+
+    def next_gang_row(i):
+        return jnp.min(jnp.where(is_gang & (rows >= i), rows, n_b))
+
+    def to_gang_row(state):
+        i, g, carry, counts = state
+        i, carry, counts = fill_rows(i, g, carry, counts)
+        carry, counts = gang_row(g, carry, counts)
+        return g + 1, next_gang_row(g + 1), carry, counts
+
+    counts = jnp.zeros((n_b, n_variants, free.shape[0]), jnp.int32)
+    carry = (free, nt_free, gang_ok.astype(jnp.int32))
+    i, _, carry, counts = jax.lax.while_loop(
+        lambda state: state[1] < n_b, to_gang_row,
+        (jnp.int32(0), next_gang_row(0), carry, counts))
+    _, (free, nt_free, _), counts = fill_rows(i, n_live, carry, counts)
     return counts, free, nt_free
+
+
+def scan_step_kinds(gang_nodes, sizes) -> dict:
+    """What `scan_batches` does with each row of a solve that carries gang
+    rows (numpy, host; the padded (B,) inputs): a `gang` row selects and
+    holds its members, a `fill` row (any other row up to the last live
+    one) water-fills its size, an `idle` row (past the last live one) is
+    never visited."""
+    import numpy as np
+
+    is_gang = np.asarray(gang_nodes) > 0
+    live = np.flatnonzero(is_gang | (np.asarray(sizes) > 0))
+    n_live = int(live[-1]) + 1 if live.size else 0
+    n_gang = int(is_gang.sum())
+    return {"gang": n_gang, "fill": n_live - n_gang,
+            "idle": len(is_gang) - n_live}
 
 
 def greedy_cut_scan_impl(

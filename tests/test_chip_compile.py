@@ -105,6 +105,33 @@ def test_single_chip_kernel_compiles_for_v5e(topo, no_cache, extras):
     _assert_no_reduce_window(compiled)
 
 
+def test_shared_cell_kernel_compiles_for_v5e(topo, no_cache):
+    """`shared-1k.reserve`'s solve at its own extents (W 1 024, a bucket
+    of 512 rows, 2 variants, 16 groups, the reservation codes): the loops
+    that run each row's own work compile with no conditional, no
+    `reduce-window`, and fit the chip."""
+    import jax
+    from jax.sharding import SingleDeviceSharding
+
+    from hyperqueue_tpu.ops.assign import greedy_cut_scan_impl
+
+    b, v, r, m, g = 512, 2, 4, 4, 16
+    one_chip = SingleDeviceSharding(topo.devices[0])
+
+    def s(*shape):
+        return jax.ShapeDtypeStruct(shape, np.int32, sharding=one_chip)
+
+    compiled = jax.jit(greedy_cut_scan_impl, donate_argnums=(0, 1)).lower(
+        s(W, r), s(W), s(W), s(b, v, r), s(b), s(b, v), s(m, W), s(b, v),
+        gang_nodes=s(b), gang_ok=s(W), group_onehot=s(W, g),
+        gang_resv=s(W),
+    ).compile()
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < HBM_BYTES
+    _assert_no_reduce_window(compiled)
+    assert " conditional(" not in compiled.as_text()
+
+
 def _unpack_shapes(layout, put):
     """ShapeDtypeStructs of the unpack program's arguments (ops/inputs.py):
     the resident state it consumes (none in the full form) and the

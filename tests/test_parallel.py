@@ -661,3 +661,215 @@ def test_sharded_reservations_equal_one_chip_across_a_shard_boundary(seed):
     assert np.array_equal(none_single, plain_single)
     assert np.array_equal(none_sharded, plain_sharded)
     assert np.array_equal(plain_single, plain_sharded)
+
+
+# -- a solve with gang rows: each row does only its own kind's work ---------
+
+def _straight_scan(free, nt_free, lifetime, needs, sizes, min_time, class_m,
+                   order_ids, total=None, all_mask=None, gang_nodes=None,
+                   gang_ok=None, group_onehot=None, policy_mask=None,
+                   gang_resv=None):
+    """The kernel as one `lax.scan` that runs every part of its work on
+    every row, padded rows too, and masks what the row's kind discards:
+    the gang selection taken only on a gang row, the water-fills spending
+    a gang row's size as 0.  Built from the kernel's own parts, it is what
+    the row-kind loops of `scan_batches` must equal bit for bit."""
+    import jax.numpy as jnp
+
+    from hyperqueue_tpu.ops.assign import (
+        _gang_select_local, _variant_capacity, _water_fill_classed,
+        expand_onehots,
+    )
+
+    n_b, n_v, _n_r = needs.shape
+    onehots = expand_onehots(class_m, order_ids)
+    unreserved = (
+        None if gang_resv is None else (gang_resv == 0).astype(jnp.int32))
+
+    def body(carry, i):
+        free, nt_free, avail = carry
+        is_gang = (gang_nodes[i] > 0).astype(jnp.int32)
+        elig = (avail * (min_time[i, 0] <= lifetime)
+                * (nt_free >= 1)).astype(jnp.int32)
+        if policy_mask is not None:
+            elig = elig * policy_mask[i]
+        mine = None
+        if gang_resv is not None:
+            mine = (gang_resv == i + 1).astype(jnp.int32)
+            elig = elig * jnp.maximum(unreserved, mine)
+        take, feasible = _gang_select_local(
+            elig, group_onehot, gang_nodes[i], mine=mine)
+        take = take * is_gang
+        emit = take * feasible.astype(jnp.int32)
+        free = free * (1 - take)[:, None]
+        nt_free = nt_free * (1 - take)
+        avail = avail * (1 - take)
+        remaining = sizes[i] * (1 - is_gang)
+        rows = []
+        for v in range(n_v):
+            all_r = None if all_mask is None else all_mask[i, v]
+            cap = _variant_capacity(
+                free, nt_free, needs[i, v], min_time[i, v] <= lifetime,
+                total=total, all_r=all_r)
+            cap = jnp.minimum(cap, remaining)
+            if policy_mask is not None:
+                cap = cap * policy_mask[i]
+            if unreserved is not None:
+                cap = cap * unreserved
+            assign, assigned = _water_fill_classed(
+                cap, remaining, onehots[i, v])
+            remaining = remaining - assigned
+            free = free - assign[:, None] * needs[i, v][None, :]
+            if all_r is not None:
+                free = free * (1 - assign[:, None] * all_r[None, :])
+            nt_free = nt_free - assign
+            avail = avail * (assign == 0).astype(jnp.int32)
+            rows.append(assign)
+        rows[0] = rows[0] + emit
+        return (free, nt_free, avail), jnp.stack(rows)
+
+    (free, nt_free, _), counts = jax.lax.scan(
+        body, (free, nt_free, gang_ok), jnp.arange(n_b))
+    return counts, free, nt_free
+
+
+# name: (live rows, gang rows, what else the solve carries); the bucket is
+# 8 rows, so up to the last live one the rest is padding
+GANG_KERNEL_CASES = {
+    "gang-at-head": (6, [0], ()),
+    "gang-in-middle": (6, [3], ()),
+    "gang-at-last-live-row": (6, [5], ()),
+    "live-row-of-size-0": (6, [1, 4], ("size-0",)),
+    "full-bucket": (8, [2, 7], ()),
+    "gangs-only": (4, [0, 1, 2, 3], ()),
+    "reservations": (6, [1, 3], ("resv",)),
+    "policy-mask": (6, [2, 4], ("pmask",)),
+    "all-policy": (6, [1], ("all",)),
+}
+
+
+def _gang_kernel_case(name):
+    """Padded inputs of one solve with gang rows: 32 workers in four
+    groups of 8 shifted by 4 (every group straddles a shard boundary on a
+    4-device mesh), a bucket of 8 rows, 2 variants."""
+    from hyperqueue_tpu.ops.assign import RESV_ELSEWHERE
+
+    live, gangs, extras = GANG_KERNEL_CASES[name]
+    rng = np.random.default_rng(sorted(GANG_KERNEL_CASES).index(name) + 410)
+    n_w, n_r, n_b, n_v = 32, 2, 8, 2
+    free = (rng.integers(2, 8, size=(n_w, n_r)) * U).astype(np.int32)
+    nt_free = rng.integers(1, 6, size=n_w).astype(np.int32)
+    lifetime = np.where(rng.random(n_w) < 0.2, 100, INF_TIME).astype(
+        np.int32)
+    needs = np.zeros((n_b, n_v, n_r), dtype=np.int32)
+    needs[:live, :, 0] = rng.integers(1, 3, size=(live, n_v)) * U
+    needs[:live, :, 1] = rng.integers(0, 3, size=(live, n_v)) * (U // 2)
+    sizes = np.zeros(n_b, dtype=np.int32)
+    sizes[:live] = rng.integers(1, 12, size=live)
+    min_time = np.zeros((n_b, n_v), dtype=np.int32)
+    min_time[:live] = np.where(rng.random((live, n_v)) < 0.2, 3600, 0)
+    gang_nodes = np.zeros(n_b, dtype=np.int32)
+    gang_nodes[gangs] = rng.integers(2, 5, size=len(gangs))
+    sizes[gangs] = 1
+    if "size-0" in extras:
+        sizes[2] = 0
+    groups = ((np.arange(n_w) + 4) // 8) % 4
+    kw = dict(
+        gang_nodes=gang_nodes,
+        gang_ok=(rng.random(n_w) < 0.7).astype(np.int32),
+        group_onehot=np.eye(4, dtype=np.int32)[groups],
+    )
+    all_mask = None
+    if "all" in extras:
+        total = free.copy()
+        total[::2] += U
+        all_mask = np.zeros((n_b, n_v, n_r), dtype=np.int32)
+        all_mask[[0, 2, 3], 0, 1] = 1
+        kw.update(total=total, all_mask=all_mask)
+    if "pmask" in extras:
+        kw["policy_mask"] = (rng.random((n_b, n_w)) < 0.8).astype(np.int32)
+    if "resv" in extras:
+        resv = np.zeros(n_w, dtype=np.int32)
+        resv[[5, 6, 8, 9]] = gangs[0] + 1   # group 1, over shards 0 and 1
+        resv[[13, 14]] = gangs[1] + 1       # group 2
+        resv[[0, 30]] = RESV_ELSEWHERE
+        kw["gang_ok"][[5, 6, 8, 9]] = 1
+        kw["gang_resv"] = resv
+    scarcity = np.asarray(
+        scarcity_weights(free.astype(np.int64).sum(axis=0))
+    ).astype(np.float32)
+    class_m, order_ids = host_visit_classes(
+        free, needs, scarcity, all_mask=all_mask)
+    args = (free, nt_free, lifetime, needs, sizes, min_time, class_m,
+            order_ids)
+    return args, kw
+
+
+@pytest.mark.parametrize("path", ["one-chip", "mesh-4"])
+@pytest.mark.parametrize("name", sorted(GANG_KERNEL_CASES))
+def test_gang_row_kernel_equals_the_straight_scan(name, path):
+    """A solve with gang rows runs each row's own work alone; its counts,
+    free and slots after equal bit for bit those of a scan that runs
+    every part on every row and masks the rest, and the numpy twin's, on
+    one device and through `_sharded_body` on a 4-device mesh."""
+    from hyperqueue_tpu.ops.assign import greedy_cut_scan_numpy
+
+    args, kw = _gang_kernel_case(name)
+    free, nt_free, lifetime, needs, sizes, min_time, class_m, order_ids = (
+        args)
+    straight = jax.jit(_straight_scan)(*args, **kw)
+    host = greedy_cut_scan_numpy(*args, **kw)
+    if path == "one-chip":
+        got = greedy_cut_scan(free.copy(), nt_free.copy(), *args[2:], **kw)
+    else:
+        kw = dict(kw)
+        all_mask = kw.pop("all_mask", None)
+        got = sharded_cut_scan_donate(
+            make_worker_mesh(4), free.copy(), nt_free.copy(), lifetime,
+            pack_batch_table(needs, sizes, min_time, order_ids, all_mask),
+            class_m, extents=needs.shape, has_all=all_mask is not None,
+            **kw)
+    for what, g, s, h in zip(("counts", "free", "nt_free"), got, straight,
+                             host):
+        assert np.array_equal(np.asarray(g), np.asarray(s)), what
+        assert np.array_equal(np.asarray(g), np.asarray(h)), what
+    counts = np.asarray(got[0])
+    assert counts[np.asarray(kw["gang_nodes"]) > 0, 1:].sum() == 0
+    assert counts[int(np.flatnonzero(sizes).max()) + 1:].sum() == 0
+
+
+def _primitive_names(jaxpr):
+    """Every primitive of a jaxpr, those of its sub-jaxprs included."""
+    names = []
+    for eqn in jaxpr.eqns:
+        names.append(eqn.primitive.name)
+        for param in eqn.params.values():
+            for sub in param if isinstance(param, (list, tuple)) else [param]:
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    names += _primitive_names(inner)
+    return names
+
+
+@pytest.mark.parametrize("gang", [False, True], ids=["gangless", "gang-rows"])
+@pytest.mark.parametrize("path", ["one-chip", "mesh-4"])
+def test_only_a_solve_with_gang_rows_leaves_the_one_scan(path, gang):
+    """A solve without gang rows lowers to the one `lax.scan` of
+    water-fills over every row, as it always has: no loop and no
+    conditional besides.  A solve with gang rows has no scan and no
+    conditional either: three loops, over the gang rows, over the
+    single-node rows before each, and over those after the last."""
+    import functools
+
+    from hyperqueue_tpu.ops.assign import greedy_cut_scan_impl
+    from hyperqueue_tpu.parallel.solve import _sharded_cut_scan_impl
+
+    args, kw = _gang_kernel_case("reservations")
+    if not gang:
+        kw = {}
+    impl = (greedy_cut_scan_impl if path == "one-chip" else
+            functools.partial(_sharded_cut_scan_impl, make_worker_mesh(4)))
+    names = _primitive_names(jax.make_jaxpr(impl)(*args, **kw).jaxpr)
+    assert names.count("cond") == 0
+    assert names.count("scan") == (0 if gang else 1)
+    assert names.count("while") == (3 if gang else 0)

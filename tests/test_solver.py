@@ -333,6 +333,43 @@ def test_gang_rows_numpy_matches_jax_and_hold_invariants(seed):
             assert len({int(gids[w]) for w in members}) == 1
 
 
+def test_scan_steps_by_kind_count_a_solve_with_gang_rows():
+    """A device solve of 16 gang rows among 256 single-node rows, in a
+    bucket of 512, adds 16 / 256 / 240 to the steps by kind: in the
+    registry and in `resident_stats()`."""
+    from hyperqueue_tpu.models.greedy import GreedyCutScanModel
+    from hyperqueue_tpu.utils.metrics import REGISTRY
+
+    rng = np.random.default_rng(41)
+    n_w, n_r, n_b = 64, 2, 272
+    gang_nodes = np.zeros(n_b, dtype=np.int32)
+    gang_nodes[np.arange(8, n_b, 17)[:16]] = 2
+    sizes = rng.integers(1, 5, size=n_b).astype(np.int32)
+    args = dict(
+        free=np.full((n_w, n_r), 8 * U, dtype=np.int32),
+        nt_free=np.full(n_w, 8, dtype=np.int32),
+        lifetime=np.full(n_w, INF, dtype=np.int32),
+        needs=np.full((n_b, 1, n_r), U, dtype=np.int32),
+        sizes=sizes, min_time=np.zeros((n_b, 1), dtype=np.int32),
+        gang_nodes=gang_nodes,
+        gang_ok=np.ones(n_w, dtype=np.int32),
+        group_onehot=np.eye(4, dtype=np.int32)[np.arange(n_w) // 16],
+    )
+    counter = REGISTRY.get("hq_solve_scan_steps_by_kind_total")
+    kinds = ("gang", "fill", "idle")
+    before = [counter.labels(kind).value for kind in kinds]
+    model = GreedyCutScanModel(backend="jax")
+    counts = model.solve(**args)
+    assert model.last_backend == "device-jax"
+    assert counts[gang_nodes > 0].sum() > 0
+    after = [counter.labels(kind).value for kind in kinds]
+    assert [a - b for a, b in zip(after, before)] == [16, 256, 240]
+    stats = model.resident_stats()
+    assert [stats[f"scan_steps_{kind}"] for kind in kinds] == [16, 256, 240]
+    model.solve(**dict(args, gang_nodes=np.zeros(n_b, dtype=np.int32)))
+    assert model.resident_stats()["scan_steps_fill"] == 256
+
+
 def _random_reserved_case(rng):
     """Gang rows over workers in groups, and reservations: each gang row
     holds some workers of one group (row b's code is b + 1), a few workers
